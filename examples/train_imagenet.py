@@ -4,20 +4,26 @@ image-classification/train_imagenet.py + benchmark_score.py).
 
 Two modes:
 
-* ``--benchmark 1`` (default when no --data-rec): synthetic data, measures
-  throughput — the reference benchmark_score.py / train_imagenet.py
-  --benchmark flow.  Runs anywhere: real TPU chip, or the virtual CPU
-  mesh (JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8)
-  with --num-devices data-parallel shards.
+* ``--benchmark 1`` (default when no --data-rec): synthetic data, prints
+  a host-clock rate — the reference benchmark_score.py / train_imagenet.py
+  --benchmark flow.
 * ``--data-rec path.rec``: trains from an ImageRecordIter RecordIO file
   (tools/im2rec.py builds one).
 
-TPU shape: the whole train step (fwd+bwd+update) is one XLA program via
-gluon Trainer + hybridize; multi-device runs shard the batch over a Mesh
-through parallel.spmd.TrainStep (dp axis), riding XLA collectives.
+Where it runs: on the host CPU unless told otherwise.  ``--tpus 0``
+trains on that TPU chip (the reference's ``--gpus``; a chip that is not
+there is an error, not a CPU run).  ``--num-devices N`` shards the batch
+over the first N devices of jax's default backend through
+parallel.spmd.TrainStep (dp axis, XLA collectives): the chips of a TPU
+host, or the virtual CPU mesh under JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8.
+
+The whole train step (fwd+bwd+update) is one XLA program via gluon
+Trainer + hybridize.
 
 Examples:
-  python examples/train_imagenet.py --network resnet50_v1 --batch-size 32
+  python examples/train_imagenet.py --network resnet50_v1 --batch-size 32 \\
+      --tpus 0
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
       python examples/train_imagenet.py --network resnet18_v1 \\
       --image-shape 3,32,32 --batch-size 64 --num-devices 8
@@ -54,8 +60,12 @@ def parse_args():
                     help="1 = synthetic data (default without --data-rec)")
     ap.add_argument("--data-rec", default=None,
                     help="RecordIO file for real training")
+    ap.add_argument("--tpus", default="",
+                    help="TPU chip to train on, e.g. 0; empty means the "
+                         "host CPU (parity: reference --gpus)")
     ap.add_argument("--num-devices", type=int, default=1,
-                    help=">1 shards the batch data-parallel over a Mesh")
+                    help=">1 shards the batch data-parallel over a Mesh of "
+                         "the default backend's first N devices")
     ap.add_argument("--kvstore", default="device")
     return ap.parse_args()
 
@@ -75,6 +85,15 @@ def main():
     image_shape = tuple(int(x) for x in args.image_shape.split(","))
 
     import mxnet_tpu as mx
+    ctx = mx.tpu(int(args.tpus.split(",")[0])) if args.tpus else mx.cpu()
+    if args.num_devices == 1:
+        print(f"training on {ctx} ({ctx.jax_device})")
+    with ctx:  # the default context: data, parameters and outputs follow
+        train(args, image_shape, ctx)
+
+
+def train(args, image_shape, ctx):
+    import mxnet_tpu as mx
     from mxnet_tpu import gluon
     from mxnet_tpu.gluon.model_zoo import vision
 
@@ -83,7 +102,7 @@ def main():
         amp.init(target_dtype="bfloat16")
 
     net = vision.get_model(args.network, classes=args.num_classes)
-    net.initialize(mx.initializer.Xavier(magnitude=2.0))
+    net.initialize(mx.initializer.Xavier(magnitude=2.0), ctx=ctx)
     net.hybridize()
 
     if args.data_rec:

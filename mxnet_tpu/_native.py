@@ -24,6 +24,17 @@ _TRIED = False
 _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src")
 _LIB_PATH = os.path.join(_SRC_DIR, "build", "libmxnet_tpu_io.so")
+_LIB_SOURCE = os.path.join(_SRC_DIR, "io_native.cc")
+
+
+def _stale():
+    """No library yet, or one older than its source (``src/build/`` is
+    not under version control: a checkout can carry a library built from
+    another commit's ``io_native.cc``)."""
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_LIB_SOURCE)
+    except OSError:
+        return not os.path.exists(_LIB_PATH)
 
 
 def _build():
@@ -84,7 +95,7 @@ def get_lib():
         from .config import get as _cfg
         if not _cfg("MXNET_NATIVE_IO"):
             return None
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if _stale() and not _build():
             return None
         try:
             _LIB = _bind(ctypes.CDLL(_LIB_PATH))

@@ -4,9 +4,10 @@ Single owner of the compilation lifecycle, four pieces:
 
 * **persistent artifacts** (:mod:`cache`) — jax's persistent compilation
   cache wired under the serving executor cache and the fused/scanned
-  train step, at ``MXNET_COMPILE_CACHE_DIR`` with versioned
-  invalidation: a restarted process deserializes executables instead of
-  recompiling them.
+  train step — at ``JAX_COMPILATION_CACHE_DIR`` untouched when that is
+  set, else ``MXNET_COMPILE_CACHE_DIR`` with versioned invalidation,
+  else ``<checkout>/.jax_cache``: a restarted process deserializes
+  executables instead of recompiling them.
 * **AOT warmup** (:mod:`warmup`) — a model version's full bucket ladder
   is ``lower().compile()``d at publish time (and BEFORE the served-
   version pointer flips on checkpoint hot-reload), so first-request

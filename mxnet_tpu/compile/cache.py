@@ -6,14 +6,18 @@ train step.  jax ships a content-addressed persistent compilation cache
 (keyed by the serialized MLIR module + compile options + backend); this
 module owns its lifecycle for the whole framework:
 
-* **location** — ``MXNET_COMPILE_CACHE_DIR`` (default:
-  ``$XDG_CACHE_HOME/mxnet_tpu/compile``, falling back to
-  ``~/.cache/mxnet_tpu/compile``);
-* **versioned invalidation** — artifacts live under a subdirectory named
-  by a digest of (jax, jaxlib, mxnet_tpu, ``MXNET_COMPILE_CACHE_SALT``),
-  so upgrading any layer of the stack switches to a fresh namespace and
-  stale executables are never even candidates (jax's own content key is
-  the second line of defense); ``prune_stale()`` garbage-collects the
+* **location** — in order: ``JAX_COMPILATION_CACHE_DIR`` (jax's own
+  variable: the directory is used exactly as given — no sub-directory,
+  no marker file, never renamed — and this module never sets another);
+  else ``MXNET_COMPILE_CACHE_DIR`` (the hermetic CPU tests and smokes),
+  versioned as below; else ``<checkout>/.jax_cache``, a fixed path beside
+  the package, because the path is part of jax's cache key and a
+  directory that moves never hits;
+* **versioned invalidation** — under ``MXNET_COMPILE_CACHE_DIR``
+  artifacts live in a subdirectory named by a digest of (jax, jaxlib,
+  mxnet_tpu, ``MXNET_COMPILE_CACHE_SALT``), so upgrading any layer of
+  the stack switches to a fresh namespace (jax's own content key covers
+  the other two locations); ``prune_stale()`` garbage-collects the
   namespaces no live version can use;
 * **activation** — :func:`ensure_persistent_cache` is called lazily from
   the compile-heavy paths (serving executor-cache misses, ladder warmup,
@@ -59,20 +63,35 @@ def version_key():
     return hashlib.sha256(raw.encode()).hexdigest()[:16]
 
 
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def outside_dir():
+    """``JAX_COMPILATION_CACHE_DIR`` when set: a directory placed from
+    outside the program, which jax reads itself."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
 def cache_root():
-    """The un-versioned root directory (knob or XDG default)."""
+    """Home of the framework's own persisted files (ladder plans, kernel
+    winners, versioned artifact namespaces): ``MXNET_COMPILE_CACHE_DIR``,
+    else ``<checkout>/.jax_cache``.  Never the outside directory."""
     from .. import config as _config
-    root = _config.get("MXNET_COMPILE_CACHE_DIR")
-    if not root:
-        xdg = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-            os.path.expanduser("~"), ".cache")
-        root = os.path.join(xdg, "mxnet_tpu", "compile")
-    return root
+    return _config.get("MXNET_COMPILE_CACHE_DIR") or _CHECKOUT_CACHE
 
 
 def cache_dir():
-    """The versioned directory artifacts for THIS stack live in."""
-    return os.path.join(cache_root(), version_key())
+    """The directory compiled executables persist to (see the module
+    docstring for the order)."""
+    from .. import config as _config
+    outside = outside_dir()
+    if outside:
+        return outside
+    if _config.get("MXNET_COMPILE_CACHE_DIR"):
+        return os.path.join(cache_root(), version_key())
+    return _CHECKOUT_CACHE
 
 
 def active_dir():
@@ -88,7 +107,9 @@ def ensure_persistent_cache():
     Idempotent and thread-safe; called from every compile-heavy path so
     a process that serves or trains always resolves the cache before its
     first expensive compile.  Returns the active directory, or None when
-    ``MXNET_COMPILE_CACHE=0``.
+    ``MXNET_COMPILE_CACHE=0``.  With ``JAX_COMPILATION_CACHE_DIR`` set
+    jax has already read the directory and its own thresholds: nothing
+    is configured here.
     """
     global _resolved, _active
     with _lock:
@@ -99,15 +120,20 @@ def ensure_persistent_cache():
             _resolved = True
             return None
         import jax
+        if outside_dir():
+            _resolved = True
+            _active = jax.config.jax_compilation_cache_dir
+            return _active
         target = cache_dir()
         try:
             os.makedirs(target, exist_ok=True)
-            marker = os.path.join(target, _MARKER)
-            if not os.path.exists(marker):
-                tmp = marker + f".tmp.{os.getpid()}"
-                with open(tmp, "w") as f:
-                    f.write(version_key() + "\n")
-                os.replace(tmp, marker)
+            if target != _CHECKOUT_CACHE:
+                marker = os.path.join(target, _MARKER)
+                if not os.path.exists(marker):
+                    tmp = marker + f".tmp.{os.getpid()}"
+                    with open(tmp, "w") as f:
+                        f.write(version_key() + "\n")
+                    os.replace(tmp, marker)
             jax.config.update("jax_enable_compilation_cache", True)
             jax.config.update("jax_compilation_cache_dir", target)
             jax.config.update(
@@ -158,6 +184,16 @@ def prune_stale():
     return removed
 
 
+def _owned(active):
+    """True for the versioned namespace under ``MXNET_COMPILE_CACHE_DIR``
+    — the only directory this module creates, and so the only one it
+    may move."""
+    from .. import config as _config
+    return (active is not None and not outside_dir()
+            and bool(_config.get("MXNET_COMPILE_CACHE_DIR"))
+            and active == cache_dir())
+
+
 def quarantine_active(reason=""):
     """Move the ACTIVE artifact namespace into ``<root>/quarantine/`` and
     detach jax from it (fresh compiles from here on; a process restart
@@ -168,12 +204,15 @@ def quarantine_active(reason=""):
     failed to deserialize, so the whole namespace is quarantined — the
     artifacts survive for offline diagnosis, and nothing in the bad
     namespace is ever looked up again.  Returns the quarantine path, or
-    None when no cache was active.
+    None when no cache was active — or when the directory was placed
+    from outside (``JAX_COMPILATION_CACHE_DIR``, or the fixed
+    ``<checkout>/.jax_cache`` that other processes share): those are
+    never moved or detached, and the caller's error propagates.
     """
     global _active, _resolved
     with _lock:
         active = _active
-        if active is None:
+        if not _owned(active):
             return None
         _active = None
         _resolved = True  # stay detached for the rest of the process
@@ -212,7 +251,8 @@ def guarded_compile(fn, what="compile"):
     """Run ``fn()`` (a trace/compile/first-forward); if it raises while
     the persistent compilation cache is active, quarantine the namespace
     (corrupt/truncated artifacts are the prime suspect) and retry ONCE
-    against fresh compiles.  With no cache active the error propagates
+    against fresh compiles.  With no cache active, or one this module
+    may not move (see :func:`quarantine_active`), the error propagates
     unchanged — there is nothing to heal.
     """
     from ..chaos.failpoints import failpoint
@@ -220,7 +260,7 @@ def guarded_compile(fn, what="compile"):
         failpoint("compile/cache/artifact")
         return fn()
     except Exception as e:
-        if active_dir() is None:
+        if not _owned(active_dir()):
             raise
         log.warning("compile cache: %s failed with the persistent cache "
                     "active (%s: %s) — quarantining and recompiling "

@@ -25,6 +25,9 @@ import tempfile
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("MXNET_WATCHDOG_S", "120")
 os.environ.setdefault("MXNET_COMPILE_CACHE_MIN_COMPILE_S", "0")
+# a CPU smoke of the cache MECHANISM: it makes a fresh directory on
+# purpose, so a directory placed from outside does not apply to it
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 _CACHE_DIR = tempfile.mkdtemp(prefix="mxnet-compile-smoke-")
 os.environ["MXNET_COMPILE_CACHE_DIR"] = _CACHE_DIR
 

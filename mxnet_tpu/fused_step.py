@@ -214,10 +214,12 @@ class FusedTrainStep:
         # subclass passes its parameter ``sharding`` so externally-set
         # buffers (single-device restores, user arg_params) land
         # replicated/sharded on the mesh before their first donation.
-        buf = buf.copy()
-        if sharding is not None and buf.sharding != sharding:
-            buf = jax.device_put(buf, sharding)
-        return buf
+        # The copy is also COMMITTED to its placement: initializers hand
+        # over uncommitted arrays, every output of the step is committed,
+        # and jit keys its executable on that — left alone, the second
+        # step compiled the whole program a second time.
+        return jax.device_put(
+            buf.copy(), self._device if sharding is None else sharding)
 
     def _stage_carry(self, sharding=None):
         """Stage the donated carry: ``(train_vals, aux_vals, states,

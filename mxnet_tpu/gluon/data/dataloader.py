@@ -17,7 +17,9 @@ src/storage/cpu_shared_storage_manager.h).  TPU redesign, same roles:
 """
 from __future__ import annotations
 
+import contextlib
 import multiprocessing
+import os
 import sys
 
 import numpy as np
@@ -104,6 +106,26 @@ def _from_shm(obj):
 
 
 _worker_dataset = None
+
+
+@contextlib.contextmanager
+def _workers_pinned_to_cpu():
+    """``JAX_PLATFORMS=cpu`` in the environment while the pool's
+    processes (and the forkserver they fork from) are started.  A worker
+    that indexes a dataset holding NDArrays, or runs an ``nd`` transform,
+    initialises a jax backend — already while unpickling the dataset,
+    before any initializer runs — and the trainer process holds the
+    chip.  This process read the variable when it imported jax, so its
+    own backend choice does not change."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
 
 
 def _worker_initializer(dataset):
@@ -214,13 +236,13 @@ class DataLoader:
                 # pickle. MXNET_MP_START_METHOD overrides (fork keeps
                 # the old zero-pickle behavior for non-picklable
                 # datasets created before any jax use).
-                import os as _os
-                method = _os.environ.get("MXNET_MP_START_METHOD",
-                                         "forkserver")
+                method = os.environ.get("MXNET_MP_START_METHOD",
+                                        "forkserver")
                 ctx = multiprocessing.get_context(method)
-                self._pool = ctx.Pool(self._num_workers,
-                                      initializer=_worker_initializer,
-                                      initargs=(dataset,))
+                with _workers_pinned_to_cpu():
+                    self._pool = ctx.Pool(self._num_workers,
+                                          initializer=_worker_initializer,
+                                          initargs=(dataset,))
                 self._use_shm = True
 
     def __iter__(self):

@@ -75,19 +75,11 @@ def stage_batch(batch, ctx):
     one-dispatch train step (fused_step.py).  Arrays already on ``ctx``'s
     device pass through untouched; the returned DataBatch keeps
     pad/index/bucket_key/provide_* so it is a drop-in replacement."""
-    import logging
-
     import jax
 
-    try:
-        dev = ctx.jax_device if ctx is not None else None
-    except Exception as e:  # noqa: BLE001 — stage-ahead is best-effort
-        logging.getLogger(__name__).debug(
-            "batch staging skipped: ctx %s has no jax device (%s: %s)",
-            ctx, type(e).__name__, e)
-        dev = None
-    if dev is None:
+    if ctx is None:
         return batch
+    dev = ctx.jax_device
     import time as _time
 
     from . import telemetry as _telemetry
@@ -131,20 +123,10 @@ def stage_batch(batch, ctx):
 
 def make_batch_stager(ctx):
     """A ``batch -> staged batch`` callable for the fit loop's input
-    double-buffer, or None when staging is off (MXNET_FIT_STAGE_NEXT=0)
-    or the context has no jax device to stage onto."""
-    import logging
-
+    double-buffer, or None when staging is off (MXNET_FIT_STAGE_NEXT=0).
+    A context that denotes no device raises at the first staged batch."""
     from . import config as _config
     if ctx is None or not _config.get("MXNET_FIT_STAGE_NEXT"):
-        return None
-    try:
-        if ctx.jax_device is None:
-            return None
-    except Exception as e:  # noqa: BLE001 — staging is an optimization
-        logging.getLogger(__name__).debug(
-            "fit input double-buffer off: ctx %s has no jax device "
-            "(%s: %s)", ctx, type(e).__name__, e)
         return None
     return lambda batch: stage_batch(batch, ctx)
 
@@ -185,15 +167,7 @@ def stage_super_batch(batches, ctx, host=False):
 
     from . import telemetry as _telemetry
 
-    import logging
-
-    try:
-        dev = ctx.jax_device if ctx is not None else None
-    except Exception as e:  # noqa: BLE001 — default placement still works
-        logging.getLogger(__name__).debug(
-            "super-batch staging: ctx %s has no jax device (%s: %s); "
-            "using default placement", ctx, type(e).__name__, e)
-        dev = None
+    dev = ctx.jax_device if ctx is not None else None
     from .chaos.failpoints import failpoint as _failpoint
     _failpoint("io/stage")
     t0 = _time.perf_counter()

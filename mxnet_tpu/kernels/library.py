@@ -13,9 +13,8 @@ from __future__ import annotations
 import numpy as np
 
 from ..ops import pallas_attention, pallas_norm, pallas_softmax_ce
+from ..ops._pallas_rows import ROW_TILES, pick_block_rows
 from .registry import KernelSpec, register_kernel
-
-_ROW_TILES = (256, 128, 64, 32, 16, 8, 4, 2, 1)
 
 
 def _rows(shape):
@@ -38,12 +37,12 @@ def _ln_reference(x, gamma, beta, eps=1e-5):
 
 def _ln_space(shape, dtype):
     n = _rows(shape)
-    cfgs = [{"block_rows": b} for b in _ROW_TILES if b <= n and n % b == 0]
-    return cfgs or [{"block_rows": 1}]
+    cfgs = [{"block_rows": b} for b in ROW_TILES if b <= n and n % b == 0]
+    return cfgs or [_ln_default(shape, dtype)]
 
 
 def _ln_default(shape, dtype):
-    return {"block_rows": pallas_norm._pick_block_rows(_rows(shape))}
+    return {"block_rows": pick_block_rows(_rows(shape), shape[-1], dtype)}
 
 
 def _ln_inputs(shape, dtype, rng):
@@ -92,12 +91,12 @@ def _smce_make(config):
 
 def _smce_space(shape, dtype):
     n = int(shape[0])
-    cfgs = [{"block_rows": b} for b in _ROW_TILES if b <= n and n % b == 0]
-    return cfgs or [{"block_rows": 1}]
+    cfgs = [{"block_rows": b} for b in ROW_TILES if b <= n and n % b == 0]
+    return cfgs or [_smce_default(shape, dtype)]
 
 
 def _smce_default(shape, dtype):
-    return {"block_rows": pallas_softmax_ce._pick_block_rows(int(shape[0]))}
+    return {"block_rows": pick_block_rows(int(shape[0]), shape[1], dtype)}
 
 
 def _smce_inputs(shape, dtype, rng):

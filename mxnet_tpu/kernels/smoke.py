@@ -1,6 +1,6 @@
 """Kernels smoke — the CI phase for the kernel layer.
 
-Relay-proof (CPU, Pallas interpreter) proof obligations:
+CPU-only (Pallas interpreter) proof obligations:
 
 1. every registered kernel passes its interpreter-mode fwd+bwd
    correctness gate vs its jax reference, on every config of a tiny
@@ -69,6 +69,8 @@ def main(argv=None):
         return _child()
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # a CPU smoke of the persistence MECHANISM: fresh directory on purpose
+    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     cache_dir = os.environ.get("MXNET_COMPILE_CACHE_DIR")
     if not cache_dir:
         cache_dir = tempfile.mkdtemp(prefix="mxnet-kernels-smoke-")

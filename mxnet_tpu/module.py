@@ -215,8 +215,8 @@ class BaseModule:
         as ONE scanned XLA computation; metrics, callbacks, watchdog
         beats and timeline accounting happen at window boundaries.
         Batches that don't fill a window (epoch tail, shape-mismatched
-        batches) and windows after a scan-trace failure run through the
-        per-batch path unchanged."""
+        batches) run through the per-batch path unchanged; an error
+        raised by a window propagates to the caller of fit."""
         K, M = plan[0], plan[1]
         W = K * M
         # a healthy window legitimately goes W batch-times between
@@ -320,7 +320,7 @@ class BaseModule:
                     else (len(batches) == W)
                 outs = False
                 wtrace = _telemetry.trace.NULL_TRACE
-                if is_window and not self._scan_disabled:
+                if is_window:
                     # the SIGKILL-mid-scan-window scenario arms a kill
                     # here: deterministically between the last boundary's
                     # host control and the next window's dispatch
@@ -366,19 +366,13 @@ class BaseModule:
                         wtrace.event("nonfinite_halt")
                         wtrace.finish(status="nonfinite")
                         raise
-                    except Exception as e:  # trace failure: fall back
-                        self.logger.warning(
-                            "scanned train window disabled (%s: %s); "
-                            "falling back to per-batch steps%s",
-                            type(e).__name__, e,
-                            " — MXNET_SCAN_ACCUM gradient accumulation "
-                            "is LOST on the fallback path" if M > 1
-                            else "")
-                        self._scan_disabled = True
-                        self._scan = None
-                        # NOTE: self._mesh stays set — it records that
-                        # the mesh path engaged this fit (scenario
-                        # evidence); _scan_disabled prevents re-entry
+                    except Exception:
+                        # tracing, compiling or running the window
+                        # failed: the caller of fit sees it.  Only an
+                        # INELIGIBLE set-up chooses per-batch steps, and
+                        # it does so up front (_scan_plan)
+                        wtrace.finish(status="error")
+                        raise
                     finally:
                         _telemetry.trace.set_current(None)
                 if outs is not False:
@@ -569,11 +563,9 @@ class Module(BaseModule):
         self._monitor = None
         self._fused = None
         self._fused_step_done = False
-        self._fused_disabled = False
         self._scan = None
         self._scan_disabled = False
         self._mesh = None          # DeviceMesh when the mesh path engaged
-        self._mesh_disabled = False
         self._mesh_local_rows = None  # multi-process: this host's batch rows
         self._auto_mesh = None     # cached all-device dp mesh (False = n/a)
         self._batch_outs_ok = {}   # mesh eligibility: outputs carry batch
@@ -801,11 +793,9 @@ class Module(BaseModule):
         self._optimizer = optimizer
         self._updater = opt_mod.get_updater(optimizer)
         self._fused = None  # optimizer changed: invalidate the fused trace
-        self._fused_disabled = False
         self._scan = None
         self._scan_disabled = False
         self._mesh = None
-        self._mesh_disabled = False
         arg_params = {n: self._exec.arg_dict[n] for n in self._param_names}
         kv, update_on_kvstore = _create_kvstore(kvstore, 1, arg_params)
         self._kvstore = kv
@@ -1004,7 +994,7 @@ class Module(BaseModule):
 
     def _fused_eligible(self):
         from . import config as _config
-        if not _config.get("MXNET_FUSED_STEP") or self._fused_disabled:
+        if not _config.get("MXNET_FUSED_STEP"):
             return False
         if not (self.binded and self.params_initialized
                 and self.optimizer_initialized and self.for_training):
@@ -1031,20 +1021,9 @@ class Module(BaseModule):
         if fs is None or fs.stale(self):
             from .fused_step import FusedTrainStep
             fs = self._fused = FusedTrainStep(self)
-        try:
-            ran = fs.step(data_batch)
-        except NonFiniteError:
-            # the numerics halt verdict (MXNET_NUMERICS=halt) must reach
-            # the caller typed — falling back to the per-param loop
-            # would keep training through the poison it just caught
-            raise
-        except Exception as e:  # trace-time failure: fall back for good
-            self.logger.warning(
-                "fused train step disabled (%s: %s); falling back to the "
-                "per-param update loop", type(e).__name__, e)
-            self._fused_disabled = True
-            self._fused = None
-            return False
+        # an error raised by tracing, compiling or running the step
+        # propagates; only a shape-mismatched batch returns False
+        ran = fs.step(data_batch)
         if ran:
             self._fused_step_done = True
         return ran
@@ -1102,7 +1081,7 @@ class Module(BaseModule):
         per-param kvstore push/pull loop.  See docs/parallel.md for the
         full eligibility matrix."""
         from . import config as _config
-        if not _config.get("MXNET_MESH_FUSED_STEP") or self._mesh_disabled:
+        if not _config.get("MXNET_MESH_FUSED_STEP"):
             return False
         kv = getattr(self, "_kvstore", None)
         if kv is None or not getattr(kv, "mesh_fusible", False):

@@ -516,6 +516,13 @@ class NDArray:
     def __setitem__(self, key, value):
         if isinstance(value, NDArray):
             v = value._data
+            if value._ctx != self._ctx and \
+                    not isinstance(v, jax.core.Tracer):
+                # x[:] = y copies INTO x's storage (parity: CopyFromTo
+                # across contexts).  Adopting y's buffer where it lives
+                # left a cpu-context array holding a tpu buffer, and the
+                # next jit over it refused the mixed devices
+                v = jax.device_put(v, self._ctx.jax_device)
         else:
             v = jnp.asarray(value, dtype=self.dtype)
         if key == slice(None):

@@ -303,44 +303,6 @@ def _batch_norm(attrs, x, gamma, beta, moving_mean, moving_var):
     return out, new_mm, new_mv
 
 
-_LN_PROBED = {}
-
-
-def _fused_ln_ok(n_rows, d, x_dtype, g_dtype, b_dtype):
-    """Decide once per tile configuration whether the Pallas LN kernel is
-    safe.  The probe compiles the SAME (block_rows, d) tile and the same
-    input dtypes a real call would use, so a Mosaic rejection (VMEM
-    overflow, unsupported width) is caught here and the op falls back to
-    plain XLA.  MXNET_FUSED_LAYERNORM=0/1 forces the choice; default
-    'auto' probes.
-    """
-    import os
-    flag = os.environ.get("MXNET_FUSED_LAYERNORM", "auto").lower()
-    if flag in ("0", "false", "off"):
-        return False
-    if flag in ("1", "true", "on"):
-        return True
-    from .pallas_norm import _pick_block_rows, fused_layer_norm
-    block_rows = _pick_block_rows(int(n_rows))
-    key = (block_rows, int(d), jnp.dtype(x_dtype).name,
-           jnp.dtype(g_dtype).name, jnp.dtype(b_dtype).name)
-    if key not in _LN_PROBED:
-        try:
-            import numpy as _np
-            probe = fused_layer_norm(jnp.ones((block_rows, d), x_dtype),
-                                     jnp.ones((d,), g_dtype),
-                                     jnp.zeros((d,), b_dtype))
-            _np.asarray(probe)
-            _LN_PROBED[key] = True
-        except Exception as e:  # noqa: BLE001 — Mosaic rejection gates off
-            import logging
-            logging.getLogger("mxnet_tpu.ops").debug(
-                "fused layernorm gated off for tile %s (%s: %s); "
-                "falling back to plain XLA", key, type(e).__name__, e)
-            _LN_PROBED[key] = False
-    return _LN_PROBED[key]
-
-
 @register("LayerNorm", input_names=("data", "gamma", "beta"))
 def _layer_norm(attrs, x, gamma, beta):
     axis = int(attrs.get("axis", -1))
@@ -357,8 +319,8 @@ def _layer_norm(attrs, x, gamma, beta):
         # trailing-axis LN takes the fused Pallas kernel (one HBM
         # read+write per element; pallas_norm.py) — the hot
         # transformer configuration
-        if _fused_ln_ok(int(np.prod(x.shape[:-1])), x.shape[-1],
-                        x.dtype, gamma.dtype, beta.dtype):
+        from ._pallas_rows import kernel_wanted
+        if kernel_wanted("MXNET_FUSED_LAYERNORM", x.shape[-1], x.dtype):
             from .pallas_norm import fused_layer_norm
             return fused_layer_norm(x, gamma, beta, eps=eps)
     return plain_layer_norm(x, gamma, beta, eps=eps, axis=axis)

@@ -18,8 +18,9 @@ Backward: custom_vjp that recomputes attention row-blocks in plain XLA
 (rematerialisation trades FLOPs for HBM, same recipe as jax.checkpoint);
 a dedicated Pallas backward kernel is a later optimisation.
 
-On non-TPU backends the same kernel runs under the Pallas interpreter so
-unit tests exercise the identical code path.
+Where the program is lowered for anything but a tpu the same kernel runs
+under the Pallas interpreter (_pallas_rows.per_platform), so unit tests
+exercise the identical code path.
 """
 from __future__ import annotations
 
@@ -28,14 +29,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu imports fail on CPU-only builds of jaxlib
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover  # graftlint: disable=swallowed-error -- optional-backend probe; any import failure means "no TPU pallas"
-    pltpu = None
-    _HAS_PLTPU = False
-
+from ._pallas_rows import per_platform
 from .registry import register
 
 _NEG_INF = -1e30
@@ -107,9 +103,8 @@ def _round_up(x, m):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
-                                             "block_q", "block_k",
-                                             "interpret"))
-def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, interpret):
+                                             "block_q", "block_k"))
+def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     import math
     b, h, s, d = q.shape
     bq = min(block_q, _round_up(s, 128))
@@ -139,23 +134,28 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, interpret):
 
     q_spec = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
     stat_spec = pl.BlockSpec((1, bq, 128), lambda bh_, qi, ki: (bh_, qi, 0))
-    out, m_out, l_out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-        ],
-        out_specs=(q_spec, stat_spec, stat_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
-            jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
-        ),
-        scratch_shapes=scratch_shapes,
-        interpret=interpret,
-    )(qf, kf, vf)
+
+    def call(interpret, qf, kf, vf):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[
+                q_spec,
+                pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
+            ],
+            out_specs=(q_spec, stat_spec, stat_spec),
+            out_shape=(
+                jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
+                jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
+            ),
+            scratch_shapes=scratch_shapes,
+            interpret=interpret,
+            name="mx_flash_attention_fwd",
+        )(qf, kf, vf)
+
+    out, m_out, l_out = per_platform(call, qf, kf, vf)
     out = out.reshape(b, h, s_pad, d)[:, :, :s, :]
     m_out = m_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
     l_out = l_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
@@ -174,10 +174,6 @@ def _reference_attention(q, k, v, causal, sm_scale):
     p = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
-
-
-def _use_interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _static_sm_scale(sm_scale, head_dim):
@@ -220,8 +216,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     """
     sm_scale = _static_sm_scale(sm_scale, q.shape[-1])
     out, _, _ = _flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_q=block_q, block_k=block_k,
-                           interpret=_use_interpret())
+                           block_q=block_q, block_k=block_k)
     return out
 
 

@@ -7,10 +7,11 @@ in VMEM for exactly one read and one write of HBM per element — the
 bandwidth floor. Rows are processed in (BLOCK_ROWS, D) tiles; statistics
 are computed in f32 regardless of input dtype (bf16-safe).
 
-Forward runs as a Pallas kernel (interpreted off-TPU so tests exercise
-the same path); backward is a custom_vjp in plain XLA using the saved
-per-row mean/rstd — the standard analytic LayerNorm gradient, fused by
-XLA into two row reductions.
+Forward runs as a Pallas kernel — Mosaic where the program is lowered
+for a tpu, the Pallas interpreter elsewhere, so CPU tests exercise the
+same kernel body (_pallas_rows.per_platform); backward is a custom_vjp
+in plain XLA using the saved per-row mean/rstd — the standard analytic
+LayerNorm gradient, fused by XLA into two row reductions.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import _pallas_rows as _rows
 
 
 def _ln_kernel(x_ref, g_ref, b_ref, o_ref, mean_ref, rstd_ref, *, eps):
@@ -29,40 +32,45 @@ def _ln_kernel(x_ref, g_ref, b_ref, o_ref, mean_ref, rstd_ref, *, eps):
     y = (x - mean) * rstd
     o_ref[:] = (y * g_ref[:].astype(jnp.float32)
                 + b_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-    mean_ref[:] = mean[:, 0]
-    rstd_ref[:] = rstd[:, 0]
+    # per-row statistics stay 2-D (rows, 1): Mosaic has no cheap
+    # relayout of a sublane vector into a 1-D lane vector
+    mean_ref[:] = mean
+    rstd_ref[:] = rstd
 
 
-def _use_interpret():
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows",
-                                             "interpret"))
-def _ln_fwd(x2, gamma, beta, *, eps, block_rows, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
+def _ln_fwd(x2, gamma, beta, *, eps, block_rows):
+    """(out (n, d), mean (n, 1), rstd (n, 1)); rows padded to the tile."""
     n, d = x2.shape
-    grid = (n // block_rows,)
-    out, mean, rstd = pl.pallas_call(
-        functools.partial(_ln_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), x2.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2, gamma, beta)
-    return out, mean, rstd
+    xp = _rows.pad_rows(x2, block_rows)
+    n_pad = xp.shape[0]
+
+    def call(interpret, xp, g2, b2):
+        return pl.pallas_call(
+            functools.partial(_ln_kernel, eps=eps),
+            grid=(n_pad // block_rows,),
+            in_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n_pad, d), x2.dtype),
+                jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+                jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+            ],
+            interpret=interpret,
+            name="mx_layernorm_fwd",
+        )(xp, g2, b2)
+
+    out, mean, rstd = _rows.per_platform(
+        call, xp, gamma.reshape(1, d), beta.reshape(1, d))
+    return out[:n], mean[:n], rstd[:n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -71,25 +79,11 @@ def _layer_norm(x2, gamma, beta, eps, block_rows):
     return out
 
 
-def _pick_block_rows(n):
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n % b == 0:
-            return b
-    return 1
-
-
-def _resolve_block_rows(n, block_rows):
-    # a tuned block size only applies when it tiles THIS n exactly (a
-    # shard_map body sees the shard-local row count, not the tuned one)
-    if block_rows and n % block_rows == 0:
-        return block_rows
-    return _pick_block_rows(n)
-
-
 def _ln_core(x2, gamma, beta, eps, block_rows=None):
+    n, d = x2.shape
     return _ln_fwd(x2, gamma, beta, eps=eps,
-                   block_rows=_resolve_block_rows(x2.shape[0], block_rows),
-                   interpret=_use_interpret())
+                   block_rows=_rows.resolve_block_rows(n, d, x2.dtype,
+                                                       block_rows))
 
 
 def _ln_vjp_fwd(x2, gamma, beta, eps, block_rows):
@@ -101,13 +95,13 @@ def _ln_vjp_bwd(eps, block_rows, res, ct):
     x2, gamma, beta, mean, rstd = res
     xf = x2.astype(jnp.float32)
     ctf = ct.astype(jnp.float32)
-    xhat = (xf - mean[:, None]) * rstd[:, None]
+    xhat = (xf - mean) * rstd
     gctf = ctf * gamma.astype(jnp.float32)[None, :]
     d = x2.shape[-1]
     # analytic LN gradient: dx = rstd * (g·ct - mean(g·ct) - xhat*mean(g·ct*xhat))
     m1 = jnp.mean(gctf, axis=-1, keepdims=True)
     m2 = jnp.mean(gctf * xhat, axis=-1, keepdims=True)
-    dx = (gctf - m1 - xhat * m2) * rstd[:, None]
+    dx = (gctf - m1 - xhat * m2) * rstd
     dgamma = jnp.sum(ctf * xhat, axis=0)
     dbeta = jnp.sum(ctf, axis=0)
     return (dx.astype(x2.dtype), dgamma.astype(gamma.dtype),

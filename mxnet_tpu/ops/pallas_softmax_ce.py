@@ -7,24 +7,26 @@ and the softmax probabilities in one pass (one HBM read of the logits),
 with the max-subtraction done in f32 regardless of input dtype
 (bf16-safe) — the classifier-head bandwidth floor.
 
-Forward runs as a Pallas kernel (interpret mode off-TPU so the suite
-exercises the same code path); backward is the analytic
+Forward runs as a Pallas kernel — Mosaic where the program is lowered
+for a tpu, the Pallas interpreter elsewhere, so CPU tests exercise the
+same kernel body (_pallas_rows.per_platform); backward is the analytic
 ``(softmax - onehot) * ct`` in plain XLA from the saved probs (no 1/N —
 the registered op SUMS per-row losses, reference loss_binary_op.cc).
 Out-of-range labels (the -1 ignore/padding convention) contribute zero
 loss and zero gradient, matching the one_hot semantics of the plain
-path. Gated like the LayerNorm kernel: MXNET_FUSED_SOFTMAX_CE=1/true/on
-forces on, 0/false/off forces plain XLA, auto (default) probes once on
-TPU and falls back on Mosaic rejection.
+path.  MXNET_FUSED_SOFTMAX_CE=1/true/on forces the kernel, 0/false/off
+forces plain XLA, auto (default) takes the kernel wherever the shape
+rule (_pallas_rows.row_tile_fits) holds.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import _pallas_rows as _rows
 
 
 def _smce_kernel(x_ref, lab_ref, loss_ref, prob_ref):
@@ -32,50 +34,50 @@ def _smce_kernel(x_ref, lab_ref, loss_ref, prob_ref):
     m = jnp.max(x, axis=-1, keepdims=True)
     e = jnp.exp(x - m)
     s = jnp.sum(e, axis=-1, keepdims=True)
-    logp = x - m - jnp.log(s)
-    prob = e / s
-    lab = lab_ref[:].astype(jnp.int32)            # (B,)
-    # invalid labels (e.g. -1 padding) contribute zero, like one_hot
-    valid = (lab >= 0) & (lab < x.shape[-1])
-    picked = jnp.take_along_axis(
-        logp, jnp.clip(lab, 0, x.shape[-1] - 1)[:, None], axis=-1)[:, 0]
-    loss_ref[:] = jnp.where(valid, -picked, 0.0)
-    prob_ref[:] = prob.astype(prob_ref.dtype)
+    lab = lab_ref[:]                              # (B, 1) int32
+    # pick the label's logit by comparing against a class iota and a
+    # masked sum — Mosaic has no gather.  A label outside [0, D) (e.g.
+    # -1 padding) matches no class and contributes zero, like one_hot
+    cls = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    hit = cls == lab
+    picked = jnp.sum(jnp.where(hit, x - m, 0.0), axis=-1, keepdims=True)
+    valid = jnp.sum(hit.astype(jnp.float32), axis=-1, keepdims=True)
+    loss_ref[:] = (jnp.log(s) - picked) * valid   # (B, 1)
+    prob_ref[:] = (e / s).astype(prob_ref.dtype)
 
 
-def _use_interpret():
-    return jax.default_backend() != "tpu"
-
-
-def _pick_block_rows(n):
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n % b == 0:
-            return b
-    return 1
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _smce_fwd(x2, labels, *, block_rows, interpret):
+@functools.partial(jax.jit, static_argnames=("block_rows",))
+def _smce_fwd(x2, labels, *, block_rows):
+    """(loss (n,) f32, prob (n, d)); rows padded to the tile with label
+    -1, which the kernel scores as zero."""
     n, d = x2.shape
-    grid = (n // block_rows,)
-    loss, prob = pl.pallas_call(
-        _smce_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n, d), x2.dtype),
-        ],
-        interpret=interpret,
-    )(x2, labels)
-    return loss, prob
+    xp = _rows.pad_rows(x2, block_rows)
+    labp = _rows.pad_rows(labels.astype(jnp.int32).reshape(n, 1),
+                          block_rows, value=-1)
+    n_pad = xp.shape[0]
+
+    def call(interpret, xp, labp):
+        return pl.pallas_call(
+            _smce_kernel,
+            grid=(n_pad // block_rows,),
+            in_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+                jax.ShapeDtypeStruct((n_pad, d), x2.dtype),
+            ],
+            interpret=interpret,
+            name="mx_softmax_ce_fwd",
+        )(xp, labp)
+
+    loss, prob = _rows.per_platform(call, xp, labp)
+    return loss[:n, 0], prob[:n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -84,19 +86,11 @@ def _softmax_ce(logits, labels, block_rows):
     return loss
 
 
-def _resolve_block_rows(n, block_rows):
-    # a tuned block size only applies when it tiles THIS n exactly (a
-    # shard_map body sees the shard-local row count, not the tuned one)
-    if block_rows and n % block_rows == 0:
-        return block_rows
-    return _pick_block_rows(n)
-
-
 def _smce_core(logits, labels, block_rows=None):
+    n, d = logits.shape
     return _smce_fwd(logits, labels,
-                     block_rows=_resolve_block_rows(logits.shape[0],
-                                                    block_rows),
-                     interpret=_use_interpret())
+                     block_rows=_rows.resolve_block_rows(
+                         n, d, logits.dtype, block_rows))
 
 
 def _smce_vjp_fwd(logits, labels, block_rows):
@@ -118,40 +112,9 @@ def _smce_vjp_bwd(block_rows, res, ct):
 _softmax_ce.defvjp(_smce_vjp_fwd, _smce_vjp_bwd)
 
 
-_GATE_CACHE = {}
-
-
 def fused_softmax_ce_available(n, d, dtype):
-    """Gate identical in spirit to MXNET_FUSED_LAYERNORM: env override,
-    else probe this exact tile config once on TPU (Mosaic can reject a
-    layout) and remember the answer."""
-    flag = os.environ.get("MXNET_FUSED_SOFTMAX_CE", "auto").lower()
-    if flag in ("1", "true", "on"):
-        return True
-    if flag in ("0", "false", "off"):
-        return False
-    if _use_interpret():
-        return True  # interpret mode always works
-    key = (_pick_block_rows(n), d, str(dtype))
-    hit = _GATE_CACHE.get(key)
-    if hit is None:
-        try:
-            import numpy as _np
-            probe = _smce_fwd(jnp.zeros((key[0], d), dtype),
-                              jnp.zeros((key[0],), jnp.int32),
-                              block_rows=key[0], interpret=False)
-            # materialize: execution-time Mosaic failures must be
-            # caught HERE, not at the first real call
-            _np.asarray(probe[0])
-            hit = True
-        except Exception as e:  # noqa: BLE001 — Mosaic rejection gates off
-            import logging
-            logging.getLogger("mxnet_tpu.ops").debug(
-                "fused softmax-ce gated off for tile %s (%s: %s); "
-                "falling back to plain XLA", key, type(e).__name__, e)
-            hit = False
-        _GATE_CACHE[key] = hit
-    return hit
+    """MXNET_FUSED_SOFTMAX_CE, else the shape rule (see kernel_wanted)."""
+    return _rows.kernel_wanted("MXNET_FUSED_SOFTMAX_CE", d, dtype)
 
 
 def softmax_ce_kernel(logits, labels, block_rows=None):
@@ -162,7 +125,7 @@ def softmax_ce_kernel(logits, labels, block_rows=None):
 
 
 def plain_softmax_ce(logits, labels):
-    """Pure-XLA per-row softmax CE — the gated-off fallback and, verbatim,
+    """Pure-XLA per-row softmax CE — the gated-off path and, verbatim,
     the kernel registry's reference implementation (one definition so
     ``MXNET_KERNELS=reference`` lowers the same jaxpr as kernels-off)."""
     labels = labels.astype(jnp.int32)
@@ -178,7 +141,7 @@ def fused_softmax_ce(logits, labels):
     """Per-row softmax cross-entropy loss, differentiable.
 
     logits: (n, d); labels: (n,) integer class ids. Returns (n,) f32
-    losses. Falls back to plain XLA when the kernel is gated off."""
+    losses.  Plain XLA when the kernel is gated off."""
     labels = labels.astype(jnp.int32)
     n, d = logits.shape
     if n == 0:

@@ -67,6 +67,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .. import profiler as _prof
@@ -78,7 +79,6 @@ from ..fused_step import ScanTrainStep
 from ..gradient_compression import (COLLECTIVE_CODECS, codec_wire_bytes,
                                     decode_2bit_sum, quantize_2bit_flat)
 from ..ndarray import NDArray
-from ._shard_map import shard_map
 from .mesh import DeviceMesh
 
 log = logging.getLogger(__name__)

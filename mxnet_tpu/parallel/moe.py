@@ -32,7 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError

@@ -110,8 +110,7 @@ def init_multihost(coordinator_address=None, num_processes=None,
             "process_id must be given together (DMLC_PS_ROOT_URI[:PORT] "
             "+ DMLC_NUM_WORKER + DMLC_RANK) — or none of them on a TPU "
             "pod, where jax.distributed autodetects")
-    already = getattr(jax.distributed, "is_initialized", None)
-    if already is not None and already():
+    if jax.distributed.is_initialized():
         _initialized = True
         return  # someone else initialized the runtime: honor idempotence
     _enable_cpu_collectives()

@@ -27,8 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from ._shard_map import shard_map
 
 from ..base import MXNetError
 from .mesh import DeviceMesh

@@ -20,11 +20,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from ._shard_map import shard_map
 
 from ..base import MXNetError
-from ..ops.pallas_attention import _flash_fwd, _use_interpret, _NEG_INF
+from ..ops.pallas_attention import _flash_fwd, _NEG_INF
 from .mesh import DeviceMesh
 
 __all__ = ["ring_attention_local", "ring_self_attention"]
@@ -52,8 +52,7 @@ def _ref_attn_stats(q, k, v, causal, sm_scale):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _local_attn_stats(q, k, v, causal, sm_scale):
     return _flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                      block_q=128, block_k=128,
-                      interpret=_use_interpret())
+                      block_q=128, block_k=128)
 
 
 def _local_attn_stats_fwd(q, k, v, causal, sm_scale):

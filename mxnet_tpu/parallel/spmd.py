@@ -406,7 +406,7 @@ class TrainStep:
                     "bucket_mb: blocks with in-place-mutated aux "
                     "(BatchNorm running stats) keep the pjit path — "
                     "per-shard aux would need sync-BN semantics")
-            from ._shard_map import shard_map
+            from jax import shard_map
             from .fused import bucketed_all_reduce, plan_buckets
             t_shapes = [tuple(param_arrays[i].shape)
                         for i in self._train_idx]

@@ -180,7 +180,11 @@ class PallasKernel:
                                             if x.is_ptr))
                 if not a.is_const]
         out_meta = tuple((tuple(t.shape), np.dtype(t.dtype)) for t in outs)
-        interpret = ctx is None or ctx.device_type != "tpu"
+        # Mosaic where the operands live on a tpu device, the Pallas
+        # interpreter anywhere else (a host-resident array in a process
+        # whose default backend is the chip still interprets)
+        interpret = any(d.platform != "tpu"
+                        for t in tensors for d in t._data.devices())
         fn = self._compiled(grid, out_meta, tuple(scalars), interpret)
         results = fn(*[t._data for t in tensors])
         if not isinstance(results, (list, tuple)):

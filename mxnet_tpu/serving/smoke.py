@@ -46,7 +46,9 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 # hermetic compile-cache namespace: the smoke's warm/flip accounting
-# must not depend on what earlier local runs persisted
+# must not depend on what earlier local runs persisted, nor on a
+# directory placed from outside
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 os.environ.setdefault("MXNET_COMPILE_CACHE_DIR",
                       tempfile.mkdtemp(prefix="mx-serve-smoke-cache-"))
 

@@ -206,6 +206,22 @@ def test_fallback_paths(monkeypatch):
                           .asnumpy())
 
 
+def test_fused_step_error_reaches_the_caller(monkeypatch):
+    """An error raised by tracing, compiling or running the fused step
+    propagates — the per-param loop is chosen up front by eligibility,
+    never as a reaction to a failure."""
+    from mxnet_tpu.fused_step import FusedTrainStep
+
+    def boom(self, data_batch):
+        raise RuntimeError("injected step failure")
+
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    monkeypatch.setattr(FusedTrainStep, "step", boom)
+    mod = _make_module("sgd", {"learning_rate": 0.05})
+    with pytest.raises(RuntimeError, match="injected step failure"):
+        mod.forward_backward(_data())
+
+
 def test_lr_schedule_no_recompile(monkeypatch):
     """lr/wd are step arguments, not trace constants: a changing lr
     schedule must not retrace, and the fused path stays <= 3

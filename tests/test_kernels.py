@@ -84,6 +84,35 @@ def test_gate_fwd_bwd_parity(name, shape, dtype):
         f"{name} default config failed its gate on {shape} {jnp.dtype(dtype).name}"
 
 
+@pytest.mark.parametrize("op_name,spec_name,shape,dtype", [
+    ("LayerNorm", "layernorm", (4096, 1024), jnp.bfloat16),
+    ("LayerNorm", "layernorm", (100, 1024), jnp.float32),
+    ("softmax_cross_entropy", "softmax_ce", (128, 1000), jnp.float32),
+    ("softmax_cross_entropy", "softmax_ce", (32, 1000), jnp.float32),
+    ("_contrib_flash_attention", "attention", (2, 8, 1024, 128),
+     jnp.bfloat16),
+])
+def test_ops_lower_to_mosaic_for_tpu_from_the_cpu(op_name, spec_name, shape,
+                                                  dtype):
+    """Lower each kernel, as its op, for the TPU platform from this CPU
+    process (no interpreter: the tpu branch of per_platform) at the
+    shapes chip_smoke.py compiles on the chip.  Lowering is the first
+    gate Mosaic applies — a gather, a 1-D statistic block or a row tile
+    under the sublane packing is refused HERE, without a chip — and the
+    same trace lowered for the CPU must hold no Mosaic call."""
+    from mxnet_tpu.ops import registry as op_registry
+    spec = registry.get_spec(spec_name)
+    args, kwargs = spec.example_inputs(shape, dtype,
+                                       np.random.RandomState(0))
+    fn = op_registry.get(op_name).fcompute
+    traced = jax.jit(lambda *a: fn(dict(kwargs), *a)).trace(
+        *[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args])
+    assert "tpu_custom_call" in traced.lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in traced.lower(
+        lowering_platforms=("cpu",)).as_text()
+
+
 def test_gate_report_full_grid():
     """Every config in each spec's (small-shape) search space is
     classifiable, and all of them pass on these shapes."""

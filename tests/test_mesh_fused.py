@@ -78,7 +78,7 @@ def test_bucketed_all_reduce_op_count_and_bitwise():
     _need_devices(4)
     from jax.sharding import PartitionSpec as P
 
-    from mxnet_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(dp=4)
     rng = np.random.RandomState(0)

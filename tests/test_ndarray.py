@@ -114,6 +114,29 @@ def test_context_placement():
     assert np.allclose(c.asnumpy(), a.asnumpy())
 
 
+def test_full_slice_assignment_copies_across_contexts():
+    """``x[:] = y`` copies INTO x's storage: y's buffer moves to x's
+    device.  Module.set_params / init_params(arg_params=...) rely on it —
+    found on the chip, where a cpu-bound module adopted tpu buffers and
+    the next jit refused the mixed devices."""
+    import jax
+    x = nd.zeros((3,), ctx=mx.cpu(0))
+    y = nd.array([1, 2, 3], ctx=mx.cpu(1))
+    x[:] = y
+    assert x._data.devices() == {jax.devices("cpu")[0]}
+    assert y._data.devices() == {jax.devices("cpu")[1]}
+    assert np.array_equal(x.asnumpy(), [1, 2, 3])
+    # a module bound on one device takes parameters that live on another
+    data = mx.sym.Variable("data")
+    net = mx.sym.FullyConnected(data, num_hidden=2, name="fc")
+    mod = mx.mod.Module(net, context=mx.cpu(0), label_names=None)
+    mod.bind(data_shapes=[("data", (1, 3))], for_training=False)
+    mod.set_params({"fc_weight": nd.ones((2, 3), ctx=mx.cpu(1)),
+                    "fc_bias": nd.zeros((2,), ctx=mx.cpu(1))}, {})
+    mod.forward(mx.io.DataBatch(data=[nd.ones((1, 3))]), is_train=False)
+    assert np.array_equal(mod.get_outputs()[0].asnumpy(), [[3, 3]])
+
+
 def test_concat_stack_split():
     a = nd.ones((2, 3))
     b = nd.zeros((2, 3))

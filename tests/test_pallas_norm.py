@@ -29,6 +29,27 @@ def test_forward_matches_reference():
                                    rtol=1e-4, atol=1e-5)
 
 
+def test_rows_without_a_tile_divisor_are_padded():
+    """n=100 has no power-of-two divisor >= 8: the tile stays at the
+    dtype's sublane packing (Mosaic's block rule) and the rows are
+    padded, not tiled 4 at a time."""
+    from mxnet_tpu.ops._pallas_rows import pick_block_rows
+    assert pick_block_rows(100, 256, jnp.float32) == 8
+    assert pick_block_rows(100, 256, jnp.bfloat16) == 16
+    assert pick_block_rows(4096, 1024, jnp.bfloat16) == 256
+    rng = np.random.RandomState(5)
+    x = rng.randn(100, 256).astype(np.float32)
+    g = (rng.rand(256) + 0.5).astype(np.float32)
+    b = rng.randn(256).astype(np.float32)
+    got = fused_layer_norm(jnp.asarray(x), jnp.asarray(g), jnp.asarray(b))
+    assert got.shape == (100, 256)
+    np.testing.assert_allclose(np.asarray(got), _ref_ln(x, g, b),
+                               rtol=1e-4, atol=1e-5)
+    grads = jax.grad(lambda x_: (fused_layer_norm(
+        x_, jnp.asarray(g), jnp.asarray(b)) ** 2).sum())(jnp.asarray(x))
+    assert grads.shape == (100, 256) and np.isfinite(np.asarray(grads)).all()
+
+
 def test_bf16_input_f32_stats():
     rng = np.random.RandomState(1)
     x = (rng.randn(16, 256) * 3 + 100).astype(np.float32)

@@ -110,6 +110,38 @@ def test_ignore_label_and_zero_batch():
     assert empty.shape == (0,)
 
 
+def test_padded_rows_and_ignored_labels_n100():
+    """n=100 has no tile divisor >= 8: rows are padded (with label -1)
+    up to the tile; -1 labels inside the batch still give zero loss and
+    zero gradient, picked without a gather (compare against an iota)."""
+    n, d = 100, 40
+    x = jnp.asarray(rng.randn(n, d).astype(np.float32) * 2)
+    lab_np = rng.randint(0, d, n).astype(np.int32)
+    lab_np[[0, 17, 99]] = -1
+    lab = jnp.asarray(lab_np)
+    loss = np.asarray(fused_softmax_ce(x, lab))
+    assert loss.shape == (n,)
+    keep = lab_np >= 0
+    assert (loss[~keep] == 0.0).all()
+    ref = np.asarray(_ref(x, jnp.clip(lab, 0, d - 1)))
+    np.testing.assert_allclose(loss[keep], ref[keep], rtol=1e-5, atol=1e-6)
+    g = np.asarray(jax.grad(lambda z: fused_softmax_ce(z, lab).sum())(x))
+    assert g.shape == (n, d) and (g[~keep] == 0.0).all()
+    p = np.exp(np.asarray(x) - np.asarray(x).max(1, keepdims=True))
+    p /= p.sum(1, keepdims=True)
+    p[np.arange(n)[keep], lab_np[keep]] -= 1
+    np.testing.assert_allclose(g[keep], p[keep], rtol=1e-4, atol=1e-6)
+
+
+def test_auto_gate_is_a_rule_on_the_shape(monkeypatch):
+    """auto decides by shape — the class count must fit the VMEM tile
+    budget — never by probing the compiler and swallowing its answer."""
+    monkeypatch.delenv("MXNET_FUSED_SOFTMAX_CE", raising=False)
+    assert fused_softmax_ce_available(128, 1000, jnp.float32) is True
+    assert fused_softmax_ce_available(128, 50304, jnp.float32) is False
+    assert fused_softmax_ce_available(128, 16384, jnp.bfloat16) is True
+
+
 def test_gate_accepts_ln_style_spellings(monkeypatch):
     for off in ("0", "false", "OFF"):
         monkeypatch.setenv("MXNET_FUSED_SOFTMAX_CE", off)

@@ -184,6 +184,21 @@ def test_scan_accum_without_eligibility_warns_and_disables(monkeypatch,
                for r in caplog.records)
 
 
+def test_scan_window_error_reaches_the_caller_of_fit(monkeypatch):
+    """An error raised inside a scanned window (a compile error, an HBM
+    overflow) is not a reason to train per batch instead: it propagates,
+    and nothing is marked disabled behind the caller's back."""
+    from mxnet_tpu.fused_step import ScanTrainStep
+
+    def boom(self, sbatch):
+        raise RuntimeError("injected window failure")
+
+    monkeypatch.setattr(ScanTrainStep, "run_window", boom)
+    x, y = _dataset(64)
+    with pytest.raises(RuntimeError, match="injected window failure"):
+        _fit(monkeypatch, 2, x, y)
+
+
 def test_scan_checkpoint_mid_window_defers_to_boundary(monkeypatch,
                                                        tmp_path):
     """A checkpoint trigger aimed at a mid-window batch runs at the

@@ -47,10 +47,7 @@ def main():
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = len(devs)

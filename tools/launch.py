@@ -8,6 +8,15 @@ each with the DMLC_* rendezvous env the dist kvstore reads
 (mxnet_tpu.parallel over ICI/DCN), not this launcher — this covers the
 reference's `launch.py -n N --launcher local python train.py` workflow.
 
+One process per chip: a TPU chip belongs to the first process that
+initialises jax on it.  The server only aggregates on the host and is
+pinned to the CPU backend here.  The workers inherit the caller's
+environment unchanged, so on a host with C chips N workers that each
+use ``mx.tpu()`` all try to take the same chips and all but the first
+fail or hang: today run N > 1 workers with ``JAX_PLATFORMS=cpu``
+(``--env JAX_PLATFORMS=cpu``), or one worker that owns the chips.
+Giving worker k chip k is not implemented.
+
 Usage:
   python tools/launch.py -n 4 [-p 9091] python train_script.py args...
 """
@@ -62,6 +71,7 @@ def main():
     # server role (parity: DMLC_ROLE=server blocking in RunServer)
     senv = dict(base_env)
     senv["DMLC_ROLE"] = "server"
+    senv["JAX_PLATFORMS"] = "cpu"  # aggregates on the host: takes no chip
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     senv["PYTHONPATH"] = repo + os.pathsep + senv.get("PYTHONPATH", "")
     server = subprocess.Popen(
