@@ -268,9 +268,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
     as a differentiable program), so a second backward yields higher-order
     gradients (parity: test_higher_order_grad.py).
     """
-    import jax.numpy as jnp
-    import numpy as np
-    from .ndarray import NDArray
+    from . import telemetry as _telemetry
 
     heads = _as_list(heads)
     if head_grads is None:
@@ -285,6 +283,18 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
     if tape is None or not tape.nodes:
         raise MXNetError("backward called outside of autograd.record scope "
                          "or nothing was recorded")
+
+    # everything on the host before, between and after the nodes' vjp
+    # programs is this span's self time
+    with _telemetry.span("autograd/backward/walk"):
+        _walk_tape(tape, heads, head_grads, retain_graph)
+
+
+def _walk_tape(tape, heads, head_grads, retain_graph):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from . import telemetry as _telemetry
 
     # cotangent accumulator keyed by id of the produced jax array's NDArray
     grads = {}
@@ -335,10 +345,9 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
         # a SparseCot reaching an interior node's generic vjp must densify
         # (only the leaf write-out / sparse-aware accumulators understand it)
         cots = [c.dense() if isinstance(c, SparseCot) else c for c in cots]
-        if node.n_outputs == 1:
-            in_cots = node.vjp_fn(cots[0])
-        else:
-            in_cots = node.vjp_fn(tuple(cots))
+        with _telemetry.span("autograd/backward/dispatch"):
+            in_cots = node.vjp_fn(cots[0] if node.n_outputs == 1
+                                  else tuple(cots))
         for inp, ic in zip(node.inputs, in_cots):
             if isinstance(ic, SparseCot):
                 add_grad(inp, ic)

@@ -292,73 +292,78 @@ class FusedTrainStep:
             if bound is None or tuple(arr.shape) != tuple(bound.shape):
                 return False
 
-        opt = module._optimizer
-        sig = (opt.fused_static_signature(), self._numerics_sig())
-        if self._jit is None or sig != self._static_sig:
-            self._build_jit()
-            self._static_sig = sig
+        with _telemetry.span("fit/step/prepare"):
+            opt = module._optimizer
+            sig = (opt.fused_static_signature(), self._numerics_sig())
+            if self._jit is None or sig != self._static_sig:
+                self._build_jit()
+                self._static_sig = sig
 
-        # stage the feed: device placement + the same dtype cast the
-        # arg_dict[:]= path applies (no-ops when already staged/typed)
-        dev = self._device
-        feed_bufs = {}
-        for name, arr in feed.items():
-            buf = _as_buf(arr)
-            if dev not in buf.devices():
-                buf = jax.device_put(buf, dev)
-            bound = exec_.arg_dict[name]
-            if buf.dtype != bound._data.dtype:
-                buf = buf.astype(bound._data.dtype)
-            feed_bufs[name] = buf
+            # stage the feed: device placement + the same dtype cast the
+            # arg_dict[:]= path applies (no-ops when already staged/typed)
+            dev = self._device
+            feed_bufs = {}
+            for name, arr in feed.items():
+                buf = _as_buf(arr)
+                if dev not in buf.devices():
+                    buf = jax.device_put(buf, dev)
+                bound = exec_.arg_dict[name]
+                if buf.dtype != bound._data.dtype:
+                    buf = buf.astype(bound._data.dtype)
+                feed_bufs[name] = buf
 
-        train_vals, aux_vals, states, states_nd = self._stage_carry()
-        if self._just_built:
-            # resource observatory (ISSUE 13): a (re)build re-states the
-            # donated carry's device footprint — host shape math only,
-            # never on the steady-state per-step path
-            _telemetry.resources.account_train_step(
-                "fused_step", params=train_vals, opt_state=states,
-                aux=aux_vals)
-        other_vals = tuple(
-            feed_bufs[n] if n in feed_bufs else exec_.arg_dict[n]._data
-            for n in self._other_names)
+            train_vals, aux_vals, states, states_nd = self._stage_carry()
+            if self._just_built:
+                # resource observatory (ISSUE 13): a (re)build re-states the
+                # donated carry's device footprint — host shape math only,
+                # never on the steady-state per-step path
+                _telemetry.resources.account_train_step(
+                    "fused_step", params=train_vals, opt_state=states,
+                    aux=aux_vals)
+            other_vals = tuple(
+                feed_bufs[n] if n in feed_bufs else exec_.arg_dict[n]._data
+                for n in self._other_names)
 
-        # host-side hyperparameter evaluation ONCE per step (satellite:
-        # lr schedules must not bake into the trace): bump the update
-        # counts first, exactly like each per-param update() call does
-        for i in self._opt_indices:
-            opt._update_count(i)
-        lrs, wds = opt.fused_hyperparams(self._opt_indices)
+            # host-side hyperparameter evaluation ONCE per step (satellite:
+            # lr schedules must not bake into the trace): bump the update
+            # counts first, exactly like each per-param update() call does
+            for i in self._opt_indices:
+                opt._update_count(i)
+            lrs, wds = opt.fused_hyperparams(self._opt_indices)
 
-        key = _random.next_key()
-        poison = _numerics.poison_value() if self._num_poison \
-            else np.float32(1.0)
+            key = _random.next_key()
+            poison = _numerics.poison_value() if self._num_poison \
+                else np.float32(1.0)
+            args = (key, train_vals, other_vals, aux_vals, states,
+                    tuple(lrs), tuple(wds), poison)
+            host_args = _telemetry.host_arg_stats(args, {dev}) \
+                if _telemetry.enabled() else None
         with _telemetry.span("fit/step/fused_dispatch"):
+            _telemetry.record_step_host_args("fused", host_args)
             if self._just_built:
                 # first dispatch after a (re)trace: charge its backend
                 # compile to the fused step in the TraceLedger
                 from . import compile as _compile
                 with _compile.LEDGER.attribute("fused_step"):
                     outs, new_aux, new_params, new_states, stats = \
-                        self._jit(key, train_vals, other_vals, aux_vals,
-                                  states, tuple(lrs), tuple(wds), poison)
+                        self._jit(*args)
                 self._just_built = False
             else:
-                outs, new_aux, new_params, new_states, stats = self._jit(
-                    key, train_vals, other_vals, aux_vals, states,
-                    tuple(lrs), tuple(wds), poison)
-        _prof.record_dispatch("fused_step")
+                outs, new_aux, new_params, new_states, stats = \
+                    self._jit(*args)
+        with _telemetry.span("fit/step/writeback"):
+            _prof.record_dispatch("fused_step")
 
-        self._writeback_carry(new_params, new_aux, new_states, states_nd)
-        for name, buf in feed_bufs.items():
-            exec_.arg_dict[name]._set_data(buf)
+            self._writeback_carry(new_params, new_aux, new_states, states_nd)
+            for name, buf in feed_bufs.items():
+                exec_.arg_dict[name]._set_data(buf)
 
-        module._zero_grads()
-        exec_.outputs = [NDArray(o, module._context) for o in outs]
-        exec_._vjp_holder = None
-        exec_._last_is_train = True
-        self.steps += 1
-        _prof.record_counter("train:fused_step_total", self.steps)
+            module._zero_grads()
+            exec_.outputs = [NDArray(o, module._context) for o in outs]
+            exec_._vjp_holder = None
+            exec_._last_is_train = True
+            self.steps += 1
+            _prof.record_counter("train:fused_step_total", self.steps)
         if self._num_mode != "off":
             # boundary check: one tiny host read; halt mode raises typed
             # NonFiniteError here, AFTER the views are consistent
@@ -541,75 +546,78 @@ class ScanTrainStep(FusedTrainStep):
                     tuple(arr.shape) != (W,) + tuple(bound.shape):
                 return False
 
-        opt = module._optimizer
-        sig = (opt.fused_static_signature(), K, M,
-               self._numerics_sig(),
-               tuple(sorted((n, tuple(a.shape), str(a.dtype))
-                            for n, a in feed.items())))
-        if self._scan_jit is None or sig != self._scan_sig:
-            self._feed_order = sorted(feed)
-            self._build_scan_jit()
-            self._scan_sig = sig
+        with _telemetry.span("fit/window/prepare"):
+            opt = module._optimizer
+            sig = (opt.fused_static_signature(), K, M,
+                   self._numerics_sig(),
+                   tuple(sorted((n, tuple(a.shape), str(a.dtype))
+                                for n, a in feed.items())))
+            if self._scan_jit is None or sig != self._scan_sig:
+                self._feed_order = sorted(feed)
+                self._build_scan_jit()
+                self._scan_sig = sig
 
-        # stage the stacked feeds: (K, M, *batch_shape), bound dtype
-        feed_bufs = []
-        for name in self._feed_order:
-            buf = feed[name]
-            bound = exec_.arg_dict[name]
-            if buf.dtype != bound._data.dtype:
-                buf = buf.astype(bound._data.dtype)
-            feed_bufs.append(buf.reshape((K, M) + tuple(bound.shape)))
+            # stage the stacked feeds: (K, M, *batch_shape), bound dtype
+            feed_bufs = []
+            for name in self._feed_order:
+                buf = feed[name]
+                bound = exec_.arg_dict[name]
+                if buf.dtype != bound._data.dtype:
+                    buf = buf.astype(bound._data.dtype)
+                feed_bufs.append(buf.reshape((K, M) + tuple(bound.shape)))
 
-        train_vals, aux_vals, states, states_nd = self._stage_carry()
-        if self._just_built:
-            _telemetry.resources.account_train_step(
-                "scan_step", params=train_vals, opt_state=states,
-                aux=aux_vals)
-        rest_vals = tuple(exec_.arg_dict[n]._data
-                          for n in self._rest_names)
+            train_vals, aux_vals, states, states_nd = self._stage_carry()
+            if self._just_built:
+                _telemetry.resources.account_train_step(
+                    "scan_step", params=train_vals, opt_state=states,
+                    aux=aux_vals)
+            rest_vals = tuple(exec_.arg_dict[n]._data
+                              for n in self._rest_names)
 
-        # host-side hyperparameters for the WHOLE window: K rows of
-        # lr/wd, update counts bumped per step exactly like K sequential
-        # fused steps — schedules advance inside the scan, no retrace
-        lrs, wds = opt.fused_window_hyperparams(self._opt_indices, K)
-        lrs = np.asarray(lrs, np.float32)
-        wds = np.asarray(wds, np.float32)
-        # one key per micro forward, same counter stream as W sequential
-        # steps (bitwise-identical randomness)
-        keys = np.stack([np.asarray(_random.next_key())
-                         for _ in range(W)])
-        keys = keys.reshape((K, M) + keys.shape[1:])
+            # host-side hyperparameters for the WHOLE window: K rows of
+            # lr/wd, update counts bumped per step exactly like K sequential
+            # fused steps — schedules advance inside the scan, no retrace
+            lrs, wds = opt.fused_window_hyperparams(self._opt_indices, K)
+            lrs = np.asarray(lrs, np.float32)
+            wds = np.asarray(wds, np.float32)
+            # one key per micro forward, same counter stream as W sequential
+            # steps (bitwise-identical randomness)
+            keys = np.stack([np.asarray(_random.next_key())
+                             for _ in range(W)])
+            keys = keys.reshape((K, M) + keys.shape[1:])
 
-        poison = _numerics.poison_value() if self._num_poison \
-            else np.float32(1.0)
+            poison = _numerics.poison_value() if self._num_poison \
+                else np.float32(1.0)
+            args = (keys, tuple(feed_bufs), lrs, wds, train_vals,
+                    rest_vals, aux_vals, states, poison)
+            host_args = _telemetry.host_arg_stats(args, {self._device}) \
+                if _telemetry.enabled() else None
         with _telemetry.span("fit/step/scan_dispatch"):
+            _telemetry.record_step_host_args("scan", host_args)
             if self._just_built:
                 from . import compile as _compile
                 with _compile.LEDGER.attribute("scan_step"):
-                    tv, av, st, ys, stats = self._scan_jit(
-                        keys, tuple(feed_bufs), lrs, wds,
-                        train_vals, rest_vals, aux_vals, states, poison)
+                    tv, av, st, ys, stats = self._scan_jit(*args)
                 self._just_built = False
             else:
-                tv, av, st, ys, stats = self._scan_jit(
-                    keys, tuple(feed_bufs), lrs, wds,
-                    train_vals, rest_vals, aux_vals, states, poison)
-        _prof.record_dispatch("scan_window")
+                tv, av, st, ys, stats = self._scan_jit(*args)
+        with _telemetry.span("fit/window/writeback"):
+            _prof.record_dispatch("scan_window")
 
-        self._writeback_carry(tv, av, st, states_nd)
+            self._writeback_carry(tv, av, st, states_nd)
 
-        module._zero_grads()
-        # (K, M, *out) -> (K*M, *out): position j is micro-batch j's
-        # forward outputs, computed with that step's pre-update weights —
-        # the boundary metric sees what W sequential steps produced
-        outs_flat = [y.reshape((W,) + tuple(y.shape[2:])) for y in ys]
-        exec_.outputs = [NDArray(y[W - 1], module._context)
-                         for y in outs_flat]
-        exec_._vjp_holder = None
-        exec_._last_is_train = True
-        self.steps += K
-        self.windows += 1
-        _prof.record_counter("train:fused_step_total", self.steps)
+            module._zero_grads()
+            # (K, M, *out) -> (K*M, *out): position j is micro-batch j's
+            # forward outputs, computed with that step's pre-update weights —
+            # the boundary metric sees what W sequential steps produced
+            outs_flat = [y.reshape((W,) + tuple(y.shape[2:])) for y in ys]
+            exec_.outputs = [NDArray(y[W - 1], module._context)
+                             for y in outs_flat]
+            exec_._vjp_holder = None
+            exec_._last_is_train = True
+            self.steps += K
+            self.windows += 1
+            _prof.record_counter("train:fused_step_total", self.steps)
         if self._num_mode != "off":
             # window-boundary check: the host's only read of the stats
             # (one tiny transfer); halt raises typed NonFiniteError here

@@ -619,36 +619,42 @@ class HybridBlock(Block):
         if not self._active or getattr(_TRACING, "value", False):
             return self._forward_unhybridized(x, *args)
 
-        all_args = (x,) + args
-        flat, fmt, key = self._trace_signature(all_args)
-        entry = self._jit_cache.get(key)
-        if entry is None:
-            # one imperative dry-run finishes any deferred param init
-            needs_dry_run = any(
-                p._data is None for p in self.collect_params().values())
-            if needs_dry_run:
-                with autograd.pause(train_mode=autograd.is_training()):
-                    self._forward_unhybridized(x, *args)
-            params = [p for p in self.collect_params().values()
-                      if p._data is not None]
-            entry = self._build_jit(flat, fmt, params)
-            self._jit_cache[key] = entry
-        (jitted, fwd_vjp_jit, _raw, out_fmt_box, mutated_idx_box, param_list,
-         ctx, arg_is_nd, n_params) = entry
+        from .. import telemetry as _telemetry
+        recording = autograd.is_recording()
+        with _telemetry.span("gluon/cached_op/prepare"):
+            all_args = (x,) + args
+            flat, fmt, key = self._trace_signature(all_args)
+            entry = self._jit_cache.get(key)
+            if entry is None:
+                # one imperative dry-run finishes any deferred param init
+                needs_dry_run = any(
+                    p._data is None
+                    for p in self.collect_params().values())
+                if needs_dry_run:
+                    with autograd.pause(train_mode=autograd.is_training()):
+                        self._forward_unhybridized(x, *args)
+                params = [p for p in self.collect_params().values()
+                          if p._data is not None]
+                entry = self._build_jit(flat, fmt, params)
+                self._jit_cache[key] = entry
+            (jitted, fwd_vjp_jit, _raw, out_fmt_box, mutated_idx_box,
+             param_list, ctx, arg_is_nd, n_params) = entry
 
-        key_arr = _random.next_key()
-        param_arrays = tuple(p.data(ctx)._data for p in param_list)
-        input_arrays = tuple(a._data for a, is_nd in zip(flat, arg_is_nd)
-                             if is_nd)
+            key_arr = _random.next_key()
+            if recording:
+                nd_inputs = [p.data(ctx) for p in param_list] + \
+                    [a for a, is_nd in zip(flat, arg_is_nd) if is_nd]
+                arrays = [i._data for i in nd_inputs]
+            else:
+                param_arrays = tuple(p.data(ctx)._data for p in param_list)
+                input_arrays = tuple(
+                    a._data for a, is_nd in zip(flat, arg_is_nd) if is_nd)
 
-        if autograd.is_recording():
+        if recording:
             # one tape node for the whole block: compiled forward returns the
             # pullback (parity: CachedOp::Backward replays one cached graph)
-            nd_inputs = [p.data(ctx) for p in param_list] + \
-                [a for a, is_nd in zip(flat, arg_is_nd) if is_nd]
-            arrays = [i._data for i in nd_inputs]
-
-            (outs, mutated), vjp_fn = fwd_vjp_jit(key_arr, *arrays)
+            with _telemetry.span("gluon/cached_op/dispatch"):
+                (outs, mutated), vjp_fn = fwd_vjp_jit(key_arr, *arrays)
             results = [NDArray(o, ctx) for o in outs]
             self._apply_mutation(mutated_idx_box, param_list, mutated, ctx)
 
@@ -676,7 +682,8 @@ class HybridBlock(Block):
             if _profiler_running():
                 import time as _time
                 _prof_t0 = _time.perf_counter()
-            outs, mutated = jitted(key_arr, param_arrays, input_arrays)
+            with _telemetry.span("gluon/cached_op/dispatch"):
+                outs, mutated = jitted(key_arr, param_arrays, input_arrays)
             if _prof_t0 is not None:
                 # profile the jit path too (the round-2 profiler missed
                 # it): one record per compiled-forward invocation,

@@ -170,8 +170,14 @@ class Trainer:
             scaler.update_scale(overflow)
             if overflow:
                 return  # skip push + update entirely (reference semantics)
-        self._allreduce_grads()
-        self._update(ignore_stale_grad)
+        from .. import telemetry as _telemetry
+        with _telemetry.span("gluon/trainer/allreduce"):
+            self._allreduce_grads()
+        with _telemetry.span("gluon/trainer/update"):
+            self._update(ignore_stale_grad)
+        # a step ends here: forward, backward and this update of one
+        # batch share the step id of their span records
+        _telemetry.next_step()
 
     def allreduce_grads(self):
         if not self._kv_initialized:
@@ -253,6 +259,7 @@ class Trainer:
                         param.list_grad())):
                 pending[j].append((i, grad, arr))
         agg = getattr(self._optimizer, "aggregate_num", 0)
+        calls = 0
         for j, triples in pending.items():
             upd = self._updaters[j]
             if agg and len(triples) > 1:
@@ -262,9 +269,14 @@ class Trainer:
                     chunk = triples[k:k + agg]
                     upd([t[0] for t in chunk], [t[1] for t in chunk],
                         [t[2] for t in chunk])
+                    calls += 1
             else:
                 for i, grad, arr in triples:
                     upd(i, grad, arr)
+                calls += len(triples)
+        from .. import telemetry as _telemetry
+        if _telemetry.enabled():
+            _telemetry.record_trainer_update_calls(calls)
 
     def save_states(self, fname):
         """Save optimizer/updater states (parity: trainer.py save_states).

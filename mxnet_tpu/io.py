@@ -97,9 +97,9 @@ def stage_batch(batch, ctx):
                 if dev in buf.devices():
                     out.append(a)
                     continue
-                out.append(NDArray(jax.device_put(buf, dev), ctx))
             else:
                 buf = np.asarray(a)
+            with _telemetry.span("io/stage_batch/device_put"):
                 out.append(NDArray(jax.device_put(buf, dev), ctx))
             staged_bytes[0] += int(np.prod(buf.shape or (1,))) * \
                 np.dtype(buf.dtype).itemsize
@@ -108,16 +108,18 @@ def stage_batch(batch, ctx):
     # io staging wait: the host time spent issuing the (async) H2D copies
     # — telemetry's mxnet_io_stage_* lane, the raw material behind the
     # fit loop's h2d_stage breakdown
-    t0 = _time.perf_counter()
-    staged = DataBatch(data=put(batch.data),
-                       label=put(batch.label) if batch.label
-                       else batch.label,
-                       pad=batch.pad, index=batch.index,
-                       bucket_key=batch.bucket_key,
-                       provide_data=batch.provide_data,
-                       provide_label=batch.provide_label)
-    # graftlint: disable=raw-phase-timing -- this IS telemetry's collection point for the io staging wait
-    _telemetry.record_io_stage(_time.perf_counter() - t0, staged_bytes[0])
+    with _telemetry.span("io/stage_batch"):
+        t0 = _time.perf_counter()
+        staged = DataBatch(data=put(batch.data),
+                           label=put(batch.label) if batch.label
+                           else batch.label,
+                           pad=batch.pad, index=batch.index,
+                           bucket_key=batch.bucket_key,
+                           provide_data=batch.provide_data,
+                           provide_label=batch.provide_label)
+        # graftlint: disable=raw-phase-timing -- this IS telemetry's collection point for the io staging wait
+        _telemetry.record_io_stage(_time.perf_counter() - t0,
+                                   staged_bytes[0])
     return staged
 
 
@@ -170,7 +172,6 @@ def stage_super_batch(batches, ctx, host=False):
     dev = ctx.jax_device if ctx is not None else None
     from .chaos.failpoints import failpoint as _failpoint
     _failpoint("io/stage")
-    t0 = _time.perf_counter()
     staged_bytes = [0]
 
     def as_host(a):
@@ -179,25 +180,29 @@ def stage_super_batch(batches, ctx, host=False):
     def stack(position_lists):
         out = []
         for arrs in position_lists:
-            stacked = np.stack([as_host(a) for a in arrs])
+            with _telemetry.span("io/stage_super/host_stack"):
+                stacked = np.stack([as_host(a) for a in arrs])
             staged_bytes[0] += stacked.nbytes
             if host:
                 out.append(stacked)
-            elif dev is not None:
-                out.append(jax.device_put(stacked, dev))
-            else:
-                out.append(jax.device_put(stacked))
+                continue
+            with _telemetry.span("io/stage_super/device_put"):
+                out.append(jax.device_put(stacked, dev) if dev is not None
+                           else jax.device_put(stacked))
         return out
 
-    n_data = len(batches[0].data)
-    data = stack([[b.data[i] for b in batches] for i in range(n_data)])
-    label = []
-    if batches[0].label:
-        n_label = len(batches[0].label)
-        label = stack([[b.label[i] for b in batches]
-                       for i in range(n_label)])
-    # graftlint: disable=raw-phase-timing -- this IS telemetry's collection point for the io staging wait
-    _telemetry.record_io_stage(_time.perf_counter() - t0, staged_bytes[0])
+    with _telemetry.span("io/stage_super"):
+        t0 = _time.perf_counter()
+        n_data = len(batches[0].data)
+        data = stack([[b.data[i] for b in batches] for i in range(n_data)])
+        label = []
+        if batches[0].label:
+            n_label = len(batches[0].label)
+            label = stack([[b.label[i] for b in batches]
+                           for i in range(n_label)])
+        # graftlint: disable=raw-phase-timing -- this IS telemetry's collection point for the io staging wait
+        _telemetry.record_io_stage(_time.perf_counter() - t0,
+                                   staged_bytes[0])
     return SuperBatch(data, label, len(batches))
 
 

@@ -442,13 +442,28 @@ class TrainStep:
 
     def __call__(self, x, y):
         """Run one step; returns scalar loss (host float on .item())."""
-        key = _random.next_key()
-        xs = shard_batch(self.mesh, x) if not isinstance(x, jax.Array) else x
-        ys = shard_batch(self.mesh, y) if not isinstance(y, jax.Array) else y
-        with self.mesh.jax_mesh:
-            (self._train_params, self._aux_params, self.opt_state,
-             loss) = self._step(key, self._train_params, self._aux_params,
-                                self.opt_state, xs, ys)
+        from .. import telemetry as _telemetry
+        _telemetry.next_step()   # the spans below share this step's id
+        with _telemetry.span("spmd/step"):
+            with _telemetry.span("spmd/step/shard_batch"):
+                xs, ys = (a if isinstance(a, jax.Array)
+                          else shard_batch(self.mesh, a) for a in (x, y))
+                _telemetry.record_io_stage_bytes(sum(
+                    s.nbytes for a, s in ((x, xs), (y, ys)) if s is not a))
+            with _telemetry.span("spmd/step/prepare"):
+                args = (_random.next_key(), self._train_params,
+                        self._aux_params, self.opt_state, xs, ys)
+                host_args = _telemetry.host_arg_stats(
+                    args, set(self.mesh.jax_mesh.devices.flat)) \
+                    if _telemetry.enabled() else None
+            with _telemetry.span("spmd/step/dispatch"):
+                _telemetry.record_step_host_args("spmd", host_args)
+                with self.mesh.jax_mesh:
+                    (self._train_params, self._aux_params, self.opt_state,
+                     loss) = self._step(*args)
+            # the donated parameters and state die here, inside the span,
+            # not at the return: a few hundred buffers' worth of time
+            del args
         return loss
 
     def sync_to_block(self):

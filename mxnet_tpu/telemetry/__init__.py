@@ -41,7 +41,9 @@ from . import trace
 from . import watchdog
 from .exporter import exporter_port, start_exporter, stop_exporter
 from .registry import MetricsRegistry, exponential_buckets
-from .spans import current_span, disable, enable, enabled, span, span_stack
+from .spans import (count_in_span, current_span, current_step, disable,
+                    enable, enabled, host_arg_stats, next_step,
+                    reset_span_records, span, span_records, span_stack)
 from .steps import (LANES, current_step_timer, reset_step_stats,
                     step_breakdown, step_timer)
 
@@ -81,6 +83,20 @@ _IO_STAGE = REGISTRY.histogram(
     "host time spent staging a DataBatch host->device (io.stage_batch)")
 _IO_STAGE_BYTES = REGISTRY.counter(
     "mxnet_io_stage_bytes_total", "bytes staged host->device by io")
+_STEP_HOST_ARG_LEAVES = REGISTRY.counter(
+    "mxnet_step_host_arg_leaves",
+    "leaves of a train step call's arguments that were not arrays "
+    "already on the step's device or mesh (Python and numpy scalars, "
+    "numpy arrays, host-CPU arrays): each is copied inside the call; by "
+    "step (fused/scan/spmd); counted only while telemetry is enabled")
+_STEP_HOST_ARG_BYTES = REGISTRY.counter(
+    "mxnet_step_host_arg_bytes",
+    "bytes of the leaves mxnet_step_host_arg_leaves counts, by step")
+_TRAINER_UPDATE_CALLS = REGISTRY.counter(
+    "mxnet_trainer_update_calls_total",
+    "updater calls made by gluon.Trainer._update (one optimizer program "
+    "each, or one per aggregate_num tensors); counted only while "
+    "telemetry is enabled")
 _DATA_WAIT = REGISTRY.histogram(
     "mxnet_data_wait_seconds",
     "train-thread time blocked waiting on the streaming data plane "
@@ -146,8 +162,30 @@ def record_collective(kind, nbytes, seconds=0.0, n=1):
 def record_io_stage(seconds, nbytes=0):
     """Account one io.stage_batch call (the input-feed staging wait)."""
     _IO_STAGE.observe(seconds)
+    record_io_stage_bytes(nbytes)
+
+
+def record_io_stage_bytes(nbytes):
+    """Bytes handed to ``jax.device_put`` by input staging; they also land
+    in the record of the span open around the copy."""
     if nbytes:
-        _IO_STAGE_BYTES.inc(int(nbytes))
+        count_in_span(_IO_STAGE_BYTES, int(nbytes))
+
+
+def record_step_host_args(step, stats):
+    """Account one train step call's host arguments from inside its
+    dispatch span: ``stats`` is what ``host_arg_stats`` gave, or None
+    where telemetry was off and nothing was counted; ``step`` is fused,
+    scan or spmd."""
+    if stats is not None:
+        labels = {"step": step}
+        count_in_span(_STEP_HOST_ARG_LEAVES, stats[0], labels)
+        count_in_span(_STEP_HOST_ARG_BYTES, stats[1], labels)
+
+
+def record_trainer_update_calls(n):
+    """Account the updater calls of one ``gluon.Trainer._update``."""
+    count_in_span(_TRAINER_UPDATE_CALLS, n)
 
 
 def record_scan_window(steps):

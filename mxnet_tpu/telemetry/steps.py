@@ -26,6 +26,10 @@ fit loop's timer through a thread-local (``current_step_timer()``), so
 the attribution needs no plumbing through the Module API.  When
 telemetry is disabled the fit loop gets the shared ``_NULL_TIMER`` whose
 lanes are no-op context managers.
+
+A lane holds no clock of its own: ``StepTimer.lane(name)`` opens the
+span ``fit/lane/<name>`` (spans.py), and ``begin_step``/``end_step``
+advance the thread's step id that every span record carries.
 """
 from __future__ import annotations
 
@@ -86,22 +90,6 @@ class _NullStepTimer:
 _NULL_TIMER = _NullStepTimer()
 
 
-class _Lane:
-    __slots__ = ("_timer", "_name", "_t0")
-
-    def __init__(self, timer, name):
-        self._timer = timer
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._timer.add(self._name, time.perf_counter() - self._t0)
-        return False
-
-
 class StepTimer:
     """Accumulates one fit loop's lane times; folds them into the global
     breakdown (and the registry histograms) at every ``end_step``."""
@@ -115,7 +103,10 @@ class StepTimer:
         _tls.timer = self
 
     def lane(self, name):
-        return _Lane(self, name)
+        """A span named ``fit/lane/<name>`` whose duration is added to
+        the lane when it closes: a lane is a span, on the same clock, in
+        the same records and the same trace."""
+        return _spans._Span("fit/lane/" + name, lane=(self, name))
 
     def add(self, name, seconds):
         self._cur[name] = self._cur.get(name, 0.0) + seconds
@@ -125,6 +116,7 @@ class StepTimer:
         (e.g. an epoch-end checkpoint) stays and folds into the next
         step rather than being dropped."""
         self._step_start = time.perf_counter()
+        _spans.next_step()
 
     def end_step(self, steps=1):
         """Close out a timed unit covering ``steps`` train steps (1 for
@@ -134,6 +126,7 @@ class StepTimer:
         StepTimeline output and the step-seconds distribution keep
         meaning \"one train step\" at any window size."""
         now = time.perf_counter()
+        _spans.next_step()
         n = max(1, int(steps))
         if self._step_start is None:
             self._step_start = now

@@ -94,17 +94,20 @@ def test_disabled_trace_records_nothing(ring):
 # -- stage decomposition + exemplars -----------------------------------------
 def test_trace_stage_decomposition(traced):
     tr = trace.start("serving", "m")
-    with tr.stage("submit"):
-        time.sleep(0.01)
-    t0 = time.perf_counter()
-    time.sleep(0.01)
-    tr.add_stage("queue_wait", t0, time.perf_counter())
+    # explicit intervals over a start moved one second back: coverage is
+    # 1 / (1 + the few microseconds until finish()), arithmetic and not
+    # how six test workers happen to be scheduled around two sleeps
+    tr.t0 -= 1.0
+    tr.add_stage("submit", tr.t0, tr.t0 + 0.4)
+    tr.add_stage("queue_wait", tr.t0 + 0.4, tr.t0 + 1.0)
     tr.event("route", replica=0, hop=0)
     tr.finish()
     doc = trace.exemplars()["serving"]["last"]
     assert doc["status"] == "ok"
     assert [s["stage"] for s in doc["stages"]] == ["submit", "queue_wait"]
-    assert doc["coverage"] >= 0.9
+    assert [s["dur_ms"] for s in doc["stages"]] == [400.0, 600.0]
+    assert doc["stage_total_ms"] == 1000.0
+    assert 0.9 <= doc["coverage"] <= 1.0
     assert doc["events"][0]["event"] == "route"
     # stage durations fanned out to the registry histogram
     hist = telemetry.REGISTRY.get("mxnet_trace_stage_seconds")
