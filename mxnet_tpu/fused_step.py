@@ -26,8 +26,10 @@ Contracts kept:
   monitors-off checkpointing and ``get_optimizer_states`` work unchanged.
 * **No recompiles across lr schedules**: lr/wd (and Adam's bias
   correction) are evaluated host-side once per step by
-  ``Optimizer.fused_hyperparams`` and passed as weak-typed scalar
-  arguments.
+  ``Optimizer.fused_hyperparams`` and handed to the jitted step as two
+  host ``float32`` arrays (``host_hyperparams``), one element per
+  trainable tensor: two argument leaves whatever the model, indexed per
+  parameter inside the trace (``hyper_scalars``).
 * **Donation safety**: buffers that were not produced by this step's own
   jit output (externally set params, freshly restored optimizer state)
   are defensively copied before being donated, so arrays the user still
@@ -59,6 +61,48 @@ log = logging.getLogger(__name__)
 
 def _as_buf(x):
     return x._data if isinstance(x, NDArray) else x
+
+
+def host_hyperparams(opt, indices, steps=None):
+    """The learning rates and weight decays of the next step as two host
+    ``float32`` arrays of shape ``(len(indices),)``, or of the next
+    ``steps`` steps of a scanned window as ``(steps, len(indices))``.
+
+    Two leaves of the jitted call however many tensors the model has: a
+    Python float per tensor is a host-to-device transfer each, made
+    inside the call with the device idle (ResNet-50: 322 of them, 60 ms
+    a step).  The update counts are bumped before the hyperparameters are
+    read, once a step, like each per-param ``update()`` does
+    (``fused_window_hyperparams`` does the same row by row)."""
+    if steps is None:
+        for i in indices:
+            opt._update_count(i)
+        lrs, wds = opt.fused_hyperparams(indices)
+    else:
+        lrs, wds = opt.fused_window_hyperparams(indices, steps)
+    return np.asarray(lrs, np.float32), np.asarray(wds, np.float32)
+
+
+def hyper_scalars(lrs, wds, params, states):
+    """Inside the trace: one row of each ``host_hyperparams`` array as
+    the per-parameter ``(lr_t, wd_t)`` lists ``Optimizer.fused_update``
+    takes.
+
+    An element of a ``float32`` array is strongly typed, where the
+    Python float it replaces was weak: against a ``float16``/``bfloat16``
+    weight it would promote the whole update to ``float32``, and the new
+    weight would no longer alias its donated input (nor fit a scan
+    carry).  So each scalar is cast to the dtype its update computes in,
+    the widest of the weight and its optimizer state (``float32`` under
+    multi-precision, through the master copy) — the value a weak scalar
+    is converted to.  ``float32`` weights take no cast."""
+    lr_t, wd_t = [], []
+    for i, (w, s) in enumerate(zip(params, states)):
+        dtype = jnp.result_type(w, *jax.tree_util.tree_leaves(s))
+        for row, out in ((lrs, lr_t), (wds, wd_t)):
+            x = row[i]
+            out.append(x if x.dtype == dtype else x.astype(dtype))
+    return lr_t, wd_t
 
 
 class FusedTrainStep:
@@ -184,7 +228,7 @@ class FusedTrainStep:
                 grads = [g * poison.astype(g.dtype) for g in grads]
             new_params, new_states = opt.fused_update(
                 list(train_vals), grads, list(states),
-                list(lrs), list(wds))
+                *hyper_scalars(lrs, wds, train_vals, states))
             if num_mode != "off":
                 # numerics observatory (ISSUE 14): health stats ride the
                 # same donated dispatch; skip mode gates the poisoned
@@ -324,18 +368,15 @@ class FusedTrainStep:
                 feed_bufs[n] if n in feed_bufs else exec_.arg_dict[n]._data
                 for n in self._other_names)
 
-            # host-side hyperparameter evaluation ONCE per step (satellite:
-            # lr schedules must not bake into the trace): bump the update
-            # counts first, exactly like each per-param update() call does
-            for i in self._opt_indices:
-                opt._update_count(i)
-            lrs, wds = opt.fused_hyperparams(self._opt_indices)
+            # host-side hyperparameter evaluation ONCE per step (lr
+            # schedules must not bake into the trace)
+            lrs, wds = host_hyperparams(opt, self._opt_indices)
 
             key = _random.next_key()
             poison = _numerics.poison_value() if self._num_poison \
                 else np.float32(1.0)
             args = (key, train_vals, other_vals, aux_vals, states,
-                    tuple(lrs), tuple(wds), poison)
+                    lrs, wds, poison)
             host_args = _telemetry.host_arg_stats(args, {dev}) \
                 if _telemetry.enabled() else None
         with _telemetry.span("fit/step/fused_dispatch"):
@@ -422,7 +463,6 @@ class ScanTrainStep(FusedTrainStep):
         fn = module._exec._build_fn(True)
         opt = module._optimizer
         n_args = len(self._arg_names)
-        n_train = len(self._train_names)
         train_slots = tuple(self._train_slots)
         feed_slots = tuple(self._arg_names.index(n)
                            for n in self._feed_order)
@@ -487,8 +527,7 @@ class ScanTrainStep(FusedTrainStep):
                         tuple(grads_sum)))
                 new_params, new_states = opt.fused_update(
                     list(tv), grads_sum, list(st),
-                    [lr_s[i] for i in range(n_train)],
-                    [wd_s[i] for i in range(n_train)])
+                    *hyper_scalars(lr_s, wd_s, tv, st))
                 ys = tuple(jnp.stack([o[i] for o in outs_micro])
                            for i in range(len(outs_micro[0])))
                 if num_mode != "off":
@@ -577,9 +616,7 @@ class ScanTrainStep(FusedTrainStep):
             # host-side hyperparameters for the WHOLE window: K rows of
             # lr/wd, update counts bumped per step exactly like K sequential
             # fused steps — schedules advance inside the scan, no retrace
-            lrs, wds = opt.fused_window_hyperparams(self._opt_indices, K)
-            lrs = np.asarray(lrs, np.float32)
-            wds = np.asarray(wds, np.float32)
+            lrs, wds = host_hyperparams(opt, self._opt_indices, K)
             # one key per micro forward, same counter stream as W sequential
             # steps (bitwise-identical randomness)
             keys = np.stack([np.asarray(_random.next_key())

@@ -105,9 +105,12 @@ class Optimizer:
         """Host-side per-step dynamic scalars for ``fused_update``:
         ``(lr_t, wd_t)`` python-float lists, evaluated ONCE per step
         AFTER ``_update_count`` so lr schedules/bias corrections see the
-        same step count as the per-param loop.  They are passed into the
-        jitted step as weak-typed scalar ARGUMENTS (never baked into the
-        trace), so a changing lr schedule does not recompile."""
+        same step count as the per-param loop.  The train step packs each
+        list into one host ``float32`` array (``fused_step.
+        host_hyperparams``) and passes the two as ARGUMENTS of the jitted
+        step (never baked into the trace), so a changing lr schedule does
+        not recompile and the call's host arguments do not grow with the
+        number of tensors."""
         return ([float(self._get_lr(i)) for i in indices],
                 [float(self._get_wd(i)) for i in indices])
 
@@ -300,9 +303,11 @@ class SGD(Optimizer):
         Mirrors ``sgd_update``/``sgd_mom_update``/``mp_sgd_*``
         (ops/_op_optimizer.py) bit for bit — same op order, same python-
         float constants for rescale/clip/momentum — with lr/wd arriving
-        as traced weak-typed scalars (no recompile across schedules; the
-        mesh-fused fsdp layout passes per-element lr/wd VECTORS instead,
-        which the same elementwise expressions broadcast through).
+        as traced scalars in the dtype the update computes in, elements
+        of the step's two hyperparameter arrays (``fused_step.
+        hyper_scalars``; no recompile across schedules; the mesh-fused
+        fsdp layout passes per-element lr/wd VECTORS instead, which the
+        same elementwise expressions broadcast through).
         The multi-precision branch is chosen per param from the state
         STRUCTURE, exactly like ``update_multi_precision``."""
         import jax.numpy as jnp

@@ -75,7 +75,7 @@ from .. import random as _random
 from .. import telemetry as _telemetry
 from ..base import MXNetError
 from ..telemetry import numerics as _numerics
-from ..fused_step import ScanTrainStep
+from ..fused_step import ScanTrainStep, host_hyperparams, hyper_scalars
 from ..gradient_compression import (COLLECTIVE_CODECS, codec_wire_bytes,
                                     decode_2bit_sum, quantize_2bit_flat)
 from ..ndarray import NDArray
@@ -398,7 +398,6 @@ class MeshFusedTrainStep(ScanTrainStep):
         fn = module._exec._build_fn(True)
         opt = module._optimizer
         n_args = len(self._arg_names)
-        n_train = len(self._train_names)
         train_slots = tuple(self._train_slots)
         feed_slots = tuple(self._arg_names.index(n)
                            for n in self._feed_order)
@@ -473,8 +472,7 @@ class MeshFusedTrainStep(ScanTrainStep):
                     outs_micro.append(outs)
                     grads_sum = grads if grads_sum is None else \
                         [a + b for a, b in zip(grads_sum, grads)]
-                lr_row = [lr_s[i] for i in range(n_train)]
-                wd_row = [wd_s[i] for i in range(n_train)]
+                lr_row, wd_row = hyper_scalars(lr_s, wd_s, tv, st)
                 if comm_on and layout == "fsdp":
                     new_params, new_states = fsdp_bucket_update(
                         opt, list(tv), grads_sum, list(st),
@@ -703,9 +701,7 @@ class MeshFusedTrainStep(ScanTrainStep):
 
         rest_vals = tuple(self._place_rest(n, exec_.arg_dict[n]._data)
                           for n in self._rest_names)
-        lrs, wds = opt.fused_window_hyperparams(self._opt_indices, K)
-        lrs = np.asarray(lrs, np.float32)
-        wds = np.asarray(wds, np.float32)
+        lrs, wds = host_hyperparams(opt, self._opt_indices, K)
         # one key per (micro forward, mesh rank): rank r consumes the
         # same counter stream as the r-th simulated device of the
         # sequential kvstore loop — bitwise-identical randomness

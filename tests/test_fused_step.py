@@ -14,7 +14,13 @@ Covers the contracts from the dispatch-overhead PR (docs/perf_notes.md):
 * no recompiles across lr-schedule changes (trace counter stays at 1);
 * checkpoint save/restore round-trips through a fused-step Module;
 * MXNET_METRIC_SYNC_INTERVAL batching + Speedometer flush;
-* the batched grad zeroing (no per-param dispatch, grads read as zeros).
+* the batched grad zeroing (no per-param dispatch, grads read as zeros);
+* lr/wd reach the jitted step as two host float32 arrays (ISSUE 26): the
+  call's host leaves do not grow with the model, the step's outputs are
+  bit for bit what a Python float per tensor gave, per-parameter
+  multipliers and schedules reach the right tensor, the scanned window
+  and the fused step share the helpers, and a weight narrower than
+  float32 keeps its dtype.
 """
 import os
 
@@ -62,6 +68,23 @@ def _make_module(optimizer="sgd", opt_params=None, fixed=None):
     return mod
 
 
+def _bufs(tree):
+    """NDArray leaves of an optimizer-state tree as their jax buffers."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda x: x._data if isinstance(x, mx.nd.NDArray) else x, tree)
+
+
+def _opt_state_leaves(mod):
+    """Every optimizer-state array of ``mod`` as numpy, by index."""
+    import pickle
+    states = pickle.loads(mod.get_optimizer_states())
+    return {i: [x.asnumpy()
+                for x in (s if isinstance(s, tuple) else (s,))
+                if x is not None]
+            for i, s in states.items()}
+
+
 def _run_steps(mod, batch, steps):
     mx.random.seed(0)
     outs = []
@@ -95,17 +118,10 @@ def test_fused_parity_bitwise(monkeypatch, optimizer, opt_params):
     for a, b in zip(of, ol):
         assert np.array_equal(a, b), "outputs diverged"
     # optimizer state (momenta / adam moments) must match too
-    import pickle
-    sf = pickle.loads(mf.get_optimizer_states())
-    sl = pickle.loads(ml.get_optimizer_states())
+    sf, sl = _opt_state_leaves(mf), _opt_state_leaves(ml)
     for i in sf:
-        leaves_f = [x for x in (sf[i] if isinstance(sf[i], tuple)
-                                else (sf[i],)) if x is not None]
-        leaves_l = [x for x in (sl[i] if isinstance(sl[i], tuple)
-                                else (sl[i],)) if x is not None]
-        for a, b in zip(leaves_f, leaves_l):
-            assert np.array_equal(a.asnumpy(), b.asnumpy()), \
-                f"optimizer state {i} diverged"
+        for a, b in zip(sf[i], sl[i]):
+            assert np.array_equal(a, b), f"optimizer state {i} diverged"
 
 
 def test_fused_parity_multi_precision():
@@ -132,14 +148,10 @@ def test_fused_parity_multi_precision():
     states_f = [opt_f.create_state_multi_precision(i, w)
                 for i, w in enumerate(weights_f)]
 
-    def leaves(tree):
-        return jax.tree_util.tree_map(
-            lambda x: x._data if isinstance(x, mx.nd.NDArray) else x, tree)
-
     fused = jax.jit(lambda p, g, s, lrs, wds:
                     opt_f.fused_update(p, g, s, lrs, wds))
     bufs = [w._data for w in weights_f]
-    sbufs = leaves(states_f)
+    sbufs = _bufs(states_f)
     for gs in grads:
         for i, (w, g) in enumerate(zip(weights_l, gs)):
             upd(i, g, w)
@@ -362,3 +374,221 @@ def test_stage_batch_and_partial_batch_fit(monkeypatch):
             initializer=mx.initializer.Xavier())
     params, _ = mod.get_params()
     assert all(np.isfinite(v.asnumpy()).all() for v in params.values())
+
+
+# -- lr/wd as two host arrays (ISSUE 26) ----------------------------------
+
+def _deep_mlp(layers):
+    h = mx.sym.Variable("data")
+    for i in range(layers - 1):
+        h = mx.sym.FullyConnected(h, num_hidden=8, name=f"fc{i}")
+        h = mx.sym.Activation(h, act_type="relu")
+    h = mx.sym.FullyConnected(h, num_hidden=10, name=f"fc{layers - 1}")
+    return mx.sym.SoftmaxOutput(h, name="softmax")
+
+
+def test_host_leaves_do_not_grow_with_the_model(monkeypatch):
+    """What the fused step's call is handed that is not on the device:
+    an lr vector, a wd vector and the poison scalar, for 4 parameter
+    tensors as for 40 (read where the benchmark's ``step_host_args``
+    reads it)."""
+    from mxnet_tpu import telemetry
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    telemetry.enable()
+    try:
+        leaves = {}
+        for layers in (2, 20):
+            telemetry.reset_span_records()
+            mod = mx.mod.Module(_deep_mlp(layers), context=mx.cpu())
+            mod.bind(data_shapes=[("data", (16, 20))],
+                     label_shapes=[("softmax_label", (16,))])
+            mod.init_params(mx.initializer.Xavier())
+            mod.init_optimizer(kvstore=None, optimizer="sgd",
+                               optimizer_params={"learning_rate": 0.05,
+                                                 "momentum": 0.9})
+            for _ in range(2):
+                mod.forward_backward(_data())
+                mod.update()
+            assert len(mod._fused._train_names) == 2 * layers
+            counts = {r["counts"]["mxnet_step_host_arg_leaves"]
+                      for r in telemetry.span_records()
+                      if r["name"] == "fit/step/fused_dispatch"}
+            assert len(counts) == 1, counts
+            leaves[layers] = counts.pop()
+    finally:
+        telemetry.disable()
+        telemetry.reset_span_records()
+    assert leaves[2] == leaves[20] <= 4, leaves
+
+
+def _float_tuples(opt, indices, steps=None):
+    """What the fused step was handed before ISSUE 26: a Python float per
+    tensor, each a weak ``float32`` scalar argument of the jitted step."""
+    assert steps is None
+    for i in indices:
+        opt._update_count(i)
+    lrs, wds = opt.fused_hyperparams(indices)
+    assert all(type(v) is float for v in lrs + wds)
+    return tuple(lrs), tuple(wds)
+
+
+@pytest.mark.parametrize("optimizer,opt_params", [
+    ("sgd", {"learning_rate": 0.05}),
+    ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}),
+    ("adam", {"learning_rate": 0.01, "wd": 1e-4}),
+])
+def test_arrays_match_python_floats_bitwise(monkeypatch, optimizer,
+                                            opt_params):
+    """The traced step fed the two arrays returns bit for bit what it
+    returns fed tuples of Python floats (float32: no cast is traced for
+    either, so the tuples run the program the parent ran)."""
+    from mxnet_tpu import fused_step
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    batch = _data()
+
+    def run():
+        sched = mx.lr_scheduler.FactorScheduler(step=2, factor=0.7)
+        mod = _make_module(optimizer, dict(opt_params, lr_scheduler=sched))
+        return (mod,) + _run_steps(mod, batch, 6)
+
+    ma, pa, oa = run()
+    monkeypatch.setattr(fused_step, "host_hyperparams", _float_tuples)
+    mt, pt, ot = run()
+    assert ma._fused._trace_count == mt._fused._trace_count == 1
+    for k in pa:
+        assert np.array_equal(pa[k], pt[k]), f"param {k} diverged"
+    for a, b in zip(oa, ot):
+        assert np.array_equal(a, b), "outputs diverged"
+    sa, st = _opt_state_leaves(ma), _opt_state_leaves(mt)
+    for i in sa:
+        for a, b in zip(sa[i], st[i]):
+            assert np.array_equal(a, b), f"optimizer state {i} diverged"
+
+
+def test_multipliers_and_schedule_reach_their_tensor(monkeypatch):
+    """Element i of the arrays is parameter i's: a zero ``lr_mult``
+    freezes exactly its tensor, a large ``wd_mult`` shrinks exactly its
+    tensor, an ``lr_scheduler`` advances, and none of it retraces.  The
+    per-param loop, which never sees the arrays, is the reference."""
+    batch = _data()
+
+    def run(fused):
+        monkeypatch.setenv("MXNET_FUSED_STEP", "1" if fused else "0")
+        sched = mx.lr_scheduler.FactorScheduler(step=1, factor=0.8)
+        mod = _make_module("sgd", {"learning_rate": 0.1, "wd": 1e-2,
+                                   "lr_scheduler": sched})
+        mod._optimizer.set_lr_mult({"fc2_bias": 0.0, "fc1_bias": 3.0})
+        mod._optimizer.set_wd_mult({"fc2_weight": 80.0})
+        params, _ = _run_steps(mod, batch, 6)
+        return mod, params
+
+    mf, pf = run(True)
+    assert mf._fused is not None and mf._fused._trace_count == 1
+    assert mf._optimizer.learning_rate < 0.1
+    ml, pl = run(False)
+    init = {k: v.asnumpy() for k, v in _init_params().items()}
+    assert np.array_equal(pf["fc2_bias"], init["fc2_bias"])
+    for k in ("fc1_weight", "fc1_bias", "fc2_weight"):
+        assert not np.array_equal(pf[k], init[k]), f"{k} did not move"
+    # 6 steps of lr * 80 * wd shrink fc2_weight and nothing else
+    assert np.abs(pf["fc2_weight"]).sum() < \
+        0.8 * np.abs(init["fc2_weight"]).sum()
+    assert np.abs(pf["fc1_weight"]).sum() > \
+        0.9 * np.abs(init["fc1_weight"]).sum()
+    for k in pf:
+        np.testing.assert_allclose(pf[k], pl[k], rtol=2e-5, atol=1e-6,
+                                   err_msg=k)
+
+
+def test_k1_window_matches_fused_step(monkeypatch):
+    """A K=1 scanned window and a fused step from the same state give the
+    same parameters and momenta: ``host_hyperparams`` and
+    ``hyper_scalars`` serve both, one as a row, one as a 1-row window."""
+    from mxnet_tpu.fused_step import ScanTrainStep
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    batch = _data()
+
+    def make():
+        sched = mx.lr_scheduler.FactorScheduler(step=1, factor=0.8)
+        mod = _make_module("sgd", {"learning_rate": 0.1, "momentum": 0.9,
+                                   "wd": 1e-3, "lr_scheduler": sched})
+        mod._optimizer.set_lr_mult({"fc1_bias": 3.0, "fc2_weight": 0.5})
+        mod._optimizer.set_wd_mult({"fc2_weight": 7.0})
+        return mod
+
+    mf = make()
+    pf, _ = _run_steps(mf, batch, 3)
+    ms = make()
+    scan = ScanTrainStep(ms, 1)
+    mx.random.seed(0)
+    for _ in range(3):
+        outs = scan.run_window(mxio.stage_super_batch([batch], mx.cpu()))
+        assert outs is not False
+    assert scan._scan_trace_count == 1
+    assert ms._optimizer.num_update == mf._optimizer.num_update == 3
+    ps, _ = ms.get_params()
+    for k in pf:
+        assert np.array_equal(pf[k], ps[k].asnumpy()), f"param {k}"
+    sf, ss = _opt_state_leaves(mf), _opt_state_leaves(ms)
+    for i in sf:
+        for a, b in zip(sf[i], ss[i]):
+            assert np.array_equal(a, b), f"momentum {i} diverged"
+
+
+@pytest.mark.parametrize("dtype,multi_precision", [
+    ("float16", False), ("bfloat16", False), ("float16", True)])
+def test_narrow_weights_keep_their_dtype(dtype, multi_precision):
+    """A strong float32 scalar would promote a float16/bfloat16 update to
+    float32; ``hyper_scalars`` casts it to what the weak Python float was
+    converted to, so the new weight has the weight's dtype (donation still
+    aliases it, a scan carries it) and the same bits, on the fused step's
+    path and the scanned window's alike.  Under multi-precision the
+    scalar stays float32, like the master copy."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import optimizer as opt_mod
+    from mxnet_tpu.fused_step import host_hyperparams, hyper_scalars
+
+    rng = np.random.RandomState(1)
+    shapes = [(8, 4), (8,), (3, 8)]
+    opt = opt_mod.SGD(learning_rate=0.1, momentum=0.9, wd=1e-2,
+                      multi_precision=multi_precision,
+                      param_idx2name={0: "a_weight", 1: "a_bias",
+                                      2: "b_weight"})
+    opt.set_lr_mult({"a_bias": 2.0})
+    weights = [mx.nd.array(rng.randn(*s) * 0.5).astype(dtype)
+               for s in shapes]
+    states = [opt.create_state_multi_precision(i, w)
+              for i, w in enumerate(weights)]
+    params, states = _bufs(weights), _bufs(states)
+    grads = [jnp.asarray(rng.randn(*s), dtype) for s in shapes]
+
+    def update(p, s, lrs, wds):
+        return opt.fused_update(p, grads, s,
+                                *hyper_scalars(lrs, wds, p, s))
+
+    idx = list(range(len(shapes)))
+    lrs, wds = host_hyperparams(opt, idx)
+    assert lrs.dtype == wds.dtype == np.float32 and lrs.shape == (3,)
+    assert opt.num_update == 1
+    new_p, new_s = jax.jit(update)(params, states, lrs, wds)
+    ref_p, ref_s = jax.jit(update)(
+        params, states, tuple(float(v) for v in lrs),
+        tuple(float(v) for v in wds))
+
+    def window(p, s, lrs, wds):
+        return jax.lax.scan(
+            lambda c, xs: (tuple(map(tuple, update(
+                list(c[0]), list(c[1]), *xs))), ()),
+            (tuple(p), tuple(s)), (lrs, wds))[0]
+
+    win_p, win_s = jax.jit(window)(params, states, lrs[None], wds[None])
+    for w, a, b, c in zip(params, new_p, ref_p, win_p):
+        assert a.dtype == b.dtype == c.dtype == w.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(np.asarray(a), np.asarray(c))
+    for a, b, c in zip(*map(jax.tree_util.tree_leaves,
+                            (new_s, ref_s, win_s))):
+        assert a.dtype == b.dtype == c.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert np.array_equal(np.asarray(a), np.asarray(c))
