@@ -210,6 +210,11 @@ inline std::vector<NDArray> Proposal(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("Proposal", inputs, attrs).InvokeMulti();
 }
+// operator RMSNorm — inputs: data, gamma
+inline NDArray RMSNorm(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("RMSNorm", inputs, attrs).Invoke();
+}
 // operator RNN
 inline std::vector<NDArray> RNN(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
@@ -390,6 +395,11 @@ inline std::vector<NDArray> _contrib_box_nms(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("_contrib_box_nms", inputs, attrs).InvokeMulti();
 }
+// operator _contrib_causal_conv1d — inputs: data, weight, bias
+inline NDArray _contrib_causal_conv1d(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_causal_conv1d", inputs, attrs).Invoke();
+}
 // operator _contrib_count_sketch
 inline NDArray _contrib_count_sketch(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
@@ -514,6 +524,16 @@ inline std::vector<NDArray> _contrib_quantized_pooling(const std::vector<NDArray
 inline std::vector<NDArray> _contrib_requantize(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("_contrib_requantize", inputs, attrs).InvokeMulti();
+}
+// operator _contrib_rms_norm — inputs: data, gamma
+inline NDArray _contrib_rms_norm(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_rms_norm", inputs, attrs).Invoke();
+}
+// operator _contrib_ssd_scan — inputs: data, dt, A, B, C, D
+inline NDArray _contrib_ssd_scan(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_ssd_scan", inputs, attrs).Invoke();
 }
 // operator _copy
 inline NDArray _copy(const std::vector<NDArray>& inputs,
@@ -1404,6 +1424,11 @@ inline NDArray cast(const std::vector<NDArray>& inputs,
 inline NDArray cast_storage(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("cast_storage", inputs, attrs).Invoke();
+}
+// operator causal_conv1d — inputs: data, weight, bias
+inline NDArray causal_conv1d(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("causal_conv1d", inputs, attrs).Invoke();
 }
 // operator cbrt
 inline NDArray cbrt(const std::vector<NDArray>& inputs,
@@ -2339,6 +2364,11 @@ inline NDArray square_sum(const std::vector<NDArray>& inputs,
 inline NDArray squeeze(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("squeeze", inputs, attrs).Invoke();
+}
+// operator ssd_scan — inputs: data, dt, A, B, C, D
+inline NDArray ssd_scan(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("ssd_scan", inputs, attrs).Invoke();
 }
 // operator stack
 inline NDArray stack(const std::vector<NDArray>& inputs,

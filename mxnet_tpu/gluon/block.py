@@ -12,6 +12,7 @@ role CachedOp::StaticForward plays in the reference (cached_op.cc:742).
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import re
 import threading
@@ -38,6 +39,75 @@ _TRACING = threading.local()
 # leaves are the residual arrays, so one jit covers every (block, signature)
 # with the same residual structure
 _BWD_EXEC = jax.jit(lambda vjp_fn, cts: vjp_fn(cts))
+
+# thread-local: the layers a trace is to checkpoint one by one (remat_scope)
+_REMAT = threading.local()
+
+
+class _RematScope:
+    """The layers to checkpoint while a forward is traced, and how many
+    of them were (``boundaries``)."""
+
+    def __init__(self, layers):
+        self._layers = {id(b) for b in layers}
+        self.boundaries = 0
+
+    def __contains__(self, block):
+        return id(block) in self._layers
+
+    def call(self, layer, args):
+        """``layer(*args)`` under ``jax.checkpoint``: the layer's inputs
+        and parameters are all that the backward keeps of it; everything
+        inside is computed again there.  The parameters go in as
+        arguments, swapped into the layer for the call as ``_build_jit``
+        does for the whole block."""
+        flat, fmt = _flatten(args, "input")
+        ctx = flat[0].ctx
+        holders = [p.data(ctx) for p in layer.collect_params().values()
+                   if p._data is not None]
+        out_fmt = []
+
+        def pure(param_arrays, input_arrays):
+            saved = [d._data for d in holders]
+            try:
+                for d, a in zip(holders, param_arrays):
+                    d._data = a
+                args_re, _ = _regroup(
+                    [NDArray(a, ctx) for a in input_arrays], fmt)
+                flat_out, ofmt = _flatten(
+                    layer._forward_unhybridized(*args_re), "output")
+                if any(d._data is not a
+                       for d, a in zip(holders, param_arrays)):
+                    raise MXNetError(
+                        f"remat: layer {layer.name} updates a parameter in "
+                        "place (BatchNorm statistics); such a layer cannot "
+                        "be a rematerialisation boundary")
+                out_fmt[:] = [ofmt]
+                return tuple(o._data for o in flat_out)
+            finally:
+                for d, a in zip(holders, saved):
+                    d._data = a
+
+        outs = jax.checkpoint(pure)(tuple(d._data for d in holders),
+                                    tuple(a._data for a in flat))
+        self.boundaries += 1
+        return _regroup([NDArray(o, ctx) for o in outs], out_fmt[0])[0]
+
+
+@contextlib.contextmanager
+def remat_scope(layers):
+    """While a forward is traced inside this scope, each call of one of
+    ``layers`` is a rematerialisation boundary (``jax.checkpoint`` with
+    nothing saveable inside).  Yields the scope; its ``boundaries`` counts
+    the calls that were wrapped.  ``parallel.spmd.TrainStep(remat=True)``
+    opens it over the ``remat_layers`` a block declares."""
+    prev = getattr(_REMAT, "scope", None)
+    _REMAT.scope = scope = _RematScope(layers)
+    try:
+        yield scope
+    finally:
+        _REMAT.scope = prev
+
 
 _CachedEntry = __import__("collections").namedtuple(
     "_CachedEntry",
@@ -617,6 +687,9 @@ class HybridBlock(Block):
         if isinstance(x, _Sym):
             return self._forward_symbolic(x, *args)
         if not self._active or getattr(_TRACING, "value", False):
+            scope = getattr(_REMAT, "scope", None)
+            if scope is not None and self in scope:
+                return scope.call(self, (x,) + args)
             return self._forward_unhybridized(x, *args)
 
         from .. import telemetry as _telemetry

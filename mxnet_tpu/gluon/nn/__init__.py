@@ -3,7 +3,8 @@ from .activations import (Activation, ELU, GELU, LeakyReLU, PReLU, SELU,
                           Swish)
 from .basic_layers import (BatchNorm, Dense, Dropout, Embedding, Flatten,
                            GroupNorm, HybridLambda, HybridSequential,
-                           InstanceNorm, Lambda, LayerNorm, Sequential)
+                           InstanceNorm, Lambda, LayerNorm, RMSNorm,
+                           Sequential)
 from .conv_layers import (AvgPool1D, AvgPool2D, AvgPool3D, Conv1D,
                           Conv1DTranspose, Conv2D, Conv2DTranspose, Conv3D,
                           Conv3DTranspose, GlobalAvgPool1D, GlobalAvgPool2D,

@@ -333,6 +333,29 @@ class LayerNorm(HybridBlock):
                 + f", in_channels={in_channels})")
 
 
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalization over the trailing axis with a
+    learned scale (Zhang & Sennrich arXiv:1910.07467; op ``RMSNorm``).
+    Called with a second input it is Mamba-2's gated norm:
+    ``RMSNorm(x * silu(gate))``."""
+
+    def __init__(self, in_channels, epsilon=1e-5, gamma_initializer="ones",
+                 prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                         init=gamma_initializer)
+
+    def hybrid_forward(self, F, x, gate=None, *, gamma):
+        gates = [] if gate is None else [gate]
+        return F.RMSNorm(x, gamma, *gates, eps=self._epsilon)
+
+    def __repr__(self):
+        return (f"{self.__class__.__name__}(eps={self._epsilon}, "
+                f"in_channels={self.gamma.shape[0]})")
+
+
 class GroupNorm(HybridBlock):
     """Group normalization (parity: nn/basic_layers.py GroupNorm)."""
 

@@ -17,4 +17,5 @@ from . import _op_contrib  # noqa: F401
 from . import _op_quantization  # noqa: F401
 from . import _op_image  # noqa: F401
 from . import _op_spatial  # noqa: F401
+from . import _op_ssm  # noqa: F401
 from . import pallas_attention  # noqa: F401

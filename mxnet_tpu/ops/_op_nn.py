@@ -385,7 +385,7 @@ def _activation(attrs, x):
     return {
         "relu": jax.nn.relu, "sigmoid": jax.nn.sigmoid, "tanh": jnp.tanh,
         "softrelu": jax.nn.softplus, "softsign": jax.nn.soft_sign,
-        "log_sigmoid": jax.nn.log_sigmoid,
+        "log_sigmoid": jax.nn.log_sigmoid, "silu": jax.nn.silu,
     }[act](x)
 
 

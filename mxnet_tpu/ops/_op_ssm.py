@@ -1,0 +1,124 @@
+"""State-space sequence ops: the chunked selective scan of Mamba-2 (state
+space duality, Dao & Gu arXiv:2405.21060 §6), the causal depthwise 1-D
+convolution in front of it, and RMSNorm (plain and SiLU-gated).
+
+No reference analog: MXNet 1.x has no state-space layer.  The scan is
+written in the chunked form so that XLA places its work on the MXU:
+inside a chunk the recurrence is the masked matrix product
+``(L ⊙ C Bᵀ) X``, between chunks a P×N state is carried.  Every decay is
+``exp`` of a DIFFERENCE of one cumulative sum of ``Δ·a ≤ 0``, masked to
+the causal half before it is exponentiated, so nothing positive ever is.
+Gradients are ``jax.vjp`` through this form (registry default); a Pallas
+kernel for it is a later optimisation.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register
+from ..base import MXNetError
+
+
+# --- RMSNorm ----------------------------------------------------------------
+@register("RMSNorm", alias=("_contrib_rms_norm",),
+          input_names=("data", "gamma"))
+def _rms_norm(attrs, x, gamma, *maybe_gate):
+    """``x · rsqrt(mean(x², -1) + eps) · gamma`` over the trailing axis,
+    statistics in float32.  With a third input ``gate`` the input is first
+    multiplied by ``silu(gate)`` (Mamba-2's gated norm)."""
+    eps = float(attrs.get("eps", 1e-5))
+    h = x.astype(jnp.float32)
+    if maybe_gate:
+        h = h * jax.nn.silu(maybe_gate[0].astype(jnp.float32))
+    h = h * lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
+    return (h * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+# --- causal depthwise convolution over time -----------------------------------
+@register("_contrib_causal_conv1d", alias=("causal_conv1d",),
+          input_names=("data", "weight", "bias"))
+def _causal_conv1d(attrs, x, weight, bias):
+    """``y[b, t, c] = bias[c] + Σ_k weight[c, k] · x[b, t − (K−1) + k, c]``
+    with zeros before the sequence: ``x`` (batch, time, channels),
+    ``weight`` (channels, K) as a depthwise ``Conv1d`` stores it (its last
+    tap multiplies the current step)."""
+    k = weight.shape[1]
+    t = x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
+    y = bias.astype(x.dtype)
+    for j in range(k):
+        y = y + xp[:, j:j + t, :] * weight[:, j].astype(x.dtype)
+    return y
+
+
+# --- the chunked scan ---------------------------------------------------------
+def ssd_scan(x, dt, a, b, c, d, chunk_size):
+    """Selective state-space scan, per head ``h`` with a P×N state:
+
+        S_t = exp(Δ_t a_h) S_{t-1} + Δ_t x_t B_tᵀ,   y_t = S_t C_t + D_h x_t
+
+    x (batch, T, H, P); dt (batch, T, H), Δ > 0; a (H,), a < 0;
+    b, c (batch, T, G, N) with H a multiple of G; d (H,).  Returns y like
+    x.  T need not be a multiple of ``chunk_size``: the tail is padded
+    with Δ = 0 steps, which neither decay nor feed the state.
+    """
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    q = int(chunk_size)
+    if h % g or q < 1:
+        raise MXNetError(f"ssd_scan: {h} heads over {g} groups, chunk {q}")
+    f32 = jnp.float32
+    pad = -t % q
+    if pad:
+        x, dt, b, c = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // q
+    xc = x.astype(f32).reshape(bsz, nc, q, h, p)
+    dtc = dt.astype(f32).reshape(bsz, nc, q, h)
+    # heads share their group's B and C: the group axis is kept and the
+    # heads of a group ride along as their own axis
+    r = h // g
+    bc = b.astype(f32).reshape(bsz, nc, q, g, n)
+    cc = c.astype(f32).reshape(bsz, nc, q, g, n)
+
+    cs = jnp.cumsum(dtc * a.astype(f32), axis=2)          # (b, nc, q, h) ≤ 0
+    xdt = (xc * dtc[..., None]).reshape(bsz, nc, q, g, r, p)
+    csg = cs.reshape(bsz, nc, q, g, r)
+
+    # inside a chunk: y_t += Σ_{s≤t} exp(cs_t − cs_s) (C_t·B_s) Δ_s x_s
+    seg = csg[:, :, :, None] - csg[:, :, None, :]          # (b, nc, t, s, g, r)
+    causal = jnp.tril(jnp.ones((q, q), bool))[None, None, :, :, None, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    cb = jnp.einsum("bctgn,bcsgn->bctsg", cc, bc)
+    y = jnp.einsum("bctsgr,bcsgrp->bctgrp", decay * cb[..., None], xdt)
+
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(csg[:, :, -1:] - csg)                 # (b, nc, q, g, r)
+    states = jnp.einsum("bcsgn,bcsgrp->bcgrpn", bc, xdt * to_end[..., None])
+
+    # between chunks: carry the state, decayed over the whole chunk
+    chunk_decay = jnp.exp(csg[:, :, -1])                   # (b, nc, g, r)
+
+    def carry(s, inp):
+        own, dec = inp
+        return s * dec[..., None, None] + own, s
+
+    _, entering = lax.scan(
+        carry, jnp.zeros((bsz, g, r, p, n), f32),
+        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    entering = jnp.moveaxis(entering, 0, 1)                # (b, nc, g, r, p, n)
+
+    # what the state entering the chunk still gives at step t
+    y = y + jnp.einsum("bctgn,bcgrpn->bctgrp", cc, entering) \
+        * jnp.exp(csg)[..., None]
+    y = y.reshape(bsz, nc * q, h, p)[:, :t]
+    y = y + x.astype(f32)[:, :t] * d.astype(f32)[:, None]
+    return y.astype(x.dtype)
+
+
+@register("_contrib_ssd_scan", alias=("ssd_scan",),
+          input_names=("data", "dt", "A", "B", "C", "D"))
+def _ssd_scan(attrs, x, dt, a, b, c, d):
+    return ssd_scan(x, dt, a, b, c, d, int(attrs.get("chunk_size", 256)))

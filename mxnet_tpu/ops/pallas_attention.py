@@ -14,9 +14,17 @@ Design (canonical TPU flash pattern):
   at the last k block.  Causal masking compares global q/k indices from
   broadcasted_iota; fully-masked k blocks are skipped with @pl.when.
 
-Backward: custom_vjp that recomputes attention row-blocks in plain XLA
-(rematerialisation trades FLOPs for HBM, same recipe as jax.checkpoint);
-a dedicated Pallas backward kernel is a later optimisation.
+Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q`` (a
+divisor of its head count); query head ``i`` reads key/value head
+``i // (h_q / h_kv)``.  The forward kernel does that in the key/value
+blocks' index maps, so no head is ever repeated in HBM.
+
+Backward: custom_vjp that recomputes attention in plain XLA one block of
+``BWD_BLOCK_Q`` query rows at a time (``lax.scan`` over the blocks, dk
+and dv accumulated in the carry): the scores alive at once are
+(heads, BWD_BLOCK_Q, S), never (heads, S, S).  Rematerialisation trades
+FLOPs for HBM, same recipe as jax.checkpoint; a dedicated Pallas backward
+kernel is a later optimisation.
 
 Where the program is lowered for anything but a tpu the same kernel runs
 under the Pallas interpreter (_pallas_rows.per_platform), so unit tests
@@ -107,6 +115,7 @@ def _round_up(x, m):
 def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     import math
     b, h, s, d = q.shape
+    group = h // k.shape[1]       # query heads per key/value head
     bq = min(block_q, _round_up(s, 128))
     bk = min(block_k, _round_up(s, 128))
     # pad to a common multiple of BOTH block sizes — a floor-divided grid
@@ -119,8 +128,8 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
         v = jnp.pad(v, pad)
     bh = b * h
     qf = q.reshape(bh, s_pad, d)
-    kf = k.reshape(bh, s_pad, d)
-    vf = v.reshape(bh, s_pad, d)
+    kf = k.reshape(bh // group, s_pad, d)
+    vf = v.reshape(bh // group, s_pad, d)
 
     kernel = functools.partial(
         _attn_kernel, block_q=bq, block_k=bk, s_actual=s,
@@ -133,17 +142,16 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     ]
 
     q_spec = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
+    # flat q index = batch * h + head, so // group is batch * h_kv + kv head
+    kv_spec = pl.BlockSpec((1, bk, d),
+                           lambda bh_, qi, ki: (bh_ // group, ki, 0))
     stat_spec = pl.BlockSpec((1, bq, 128), lambda bh_, qi, ki: (bh_, qi, 0))
 
     def call(interpret, qf, kf, vf):
         return pl.pallas_call(
             kernel,
             grid=grid,
-            in_specs=[
-                q_spec,
-                pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-                pl.BlockSpec((1, bk, d), lambda bh_, qi, ki: (bh_, ki, 0)),
-            ],
+            in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=(q_spec, stat_spec, stat_spec),
             out_shape=(
                 jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
@@ -162,18 +170,32 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     return out, m_out, l_out
 
 
-def _reference_attention(q, k, v, causal, sm_scale):
-    """Plain XLA attention (used by the recompute backward)."""
-    s = q.shape[2]
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+def _check_heads(q, k, v):
+    if k.shape != v.shape or q.shape[1] % k.shape[1] or \
+            (q.shape[0], q.shape[2], q.shape[3]) != \
+            (k.shape[0], k.shape[2], k.shape[3]):
+        raise ValueError(
+            f"flash_attention: q {q.shape} against k {k.shape}, v {v.shape}: "
+            "batch, length and head size must agree and the key/value head "
+            "count must divide the query head count")
+
+
+def _reference_attention(q, k, v, causal, sm_scale, q_start=0):
+    """Plain XLA attention of the query rows ``q`` (global positions
+    ``q_start`` onward) over all keys; used by the recompute backward.
+    k, v: (batch, h_kv, S, d) with h_kv dividing q's head count."""
+    b, h, rows, d = q.shape
+    h_kv, s = k.shape[1], k.shape[2]
+    qg = q.astype(jnp.float32).reshape(b, h_kv, h // h_kv, rows, d)
+    logits = jnp.einsum("bkgqd,bksd->bkgqs", qg,
                         k.astype(jnp.float32)) * sm_scale
     if causal:
-        qi = jnp.arange(s)[:, None]
+        qi = q_start + jnp.arange(rows)[:, None]
         ki = jnp.arange(s)[None, :]
         logits = jnp.where(ki <= qi, logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+    out = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
+    return out.reshape(b, h, rows, d).astype(q.dtype)
 
 
 def _static_sm_scale(sm_scale, head_dim):
@@ -197,6 +219,7 @@ def _static_sm_scale(sm_scale, head_dim):
 def reference_attention(q, k, v, causal=False, sm_scale=None):
     """Public plain-XLA attention with flash_attention's signature —
     the kernel registry's reference implementation."""
+    _check_heads(q, k, v)
     return _reference_attention(q, k, v, causal,
                                 _static_sm_scale(sm_scale, q.shape[-1]))
 
@@ -206,8 +229,9 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                     block_k=128):
     """softmax(q kᵀ / √d) v with O(S·D) memory.
 
-    q, k, v: (batch, heads, seq, head_dim).  sm_scale defaults to
-    1/sqrt(head_dim).
+    q: (batch, heads, seq, head_dim); k, v the same or with fewer heads
+    (grouped queries: a divisor of q's head count).  sm_scale defaults
+    to 1/sqrt(head_dim).
 
     ``sm_scale`` is a STATIC kernel parameter (baked into the pallas
     grid function), so it must be a python scalar, never a traced
@@ -215,6 +239,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     concretize a tracer inside jit/shard_map bodies.
     """
     sm_scale = _static_sm_scale(sm_scale, q.shape[-1])
+    _check_heads(q, k, v)
     out, _, _ = _flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
                            block_q=block_q, block_k=block_k)
     return out
@@ -225,15 +250,36 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
     return out, (q, k, v)
 
 
+# query rows recomputed at once in the backward: the scores alive are
+# (batch, heads, BWD_BLOCK_Q, S) float32 — 268 MB at 32 heads × 4096 keys
+BWD_BLOCK_Q = 512
+
+
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, g):
     q, k, v = res
     sm_scale = _static_sm_scale(sm_scale, q.shape[-1])
+    s = q.shape[2]
+    rows = min(BWD_BLOCK_Q, s)
+    pad = -s % rows
+    # rows padded past the sequence carry a zero cotangent: they add
+    # nothing to dk and dv, and their dq is cut off again
+    padding = [(0, 0), (0, 0), (0, pad), (0, 0)]
+    blocks = [jnp.moveaxis(jnp.pad(a, padding).reshape(
+        a.shape[0], a.shape[1], -1, rows, a.shape[3]), 2, 0) for a in (q, g)]
 
-    def ref(q_, k_, v_):
-        return _reference_attention(q_, k_, v_, causal, sm_scale)
+    def one_block(acc, inp):
+        q_i, g_i, start = inp
+        _, vjp = jax.vjp(
+            lambda q_, k_, v_: _reference_attention(
+                q_, k_, v_, causal, sm_scale, q_start=start), q_i, k, v)
+        dq_i, dk_i, dv_i = vjp(g_i)
+        return (acc[0] + dk_i, acc[1] + dv_i), dq_i
 
-    _, vjp = jax.vjp(ref, q, k, v)
-    return vjp(g)
+    (dk, dv), dq = jax.lax.scan(
+        one_block, (jnp.zeros_like(k), jnp.zeros_like(v)),
+        (blocks[0], blocks[1], jnp.arange(0, s + pad, rows)))
+    dq = jnp.moveaxis(dq, 0, 2).reshape(q.shape[:2] + (s + pad, q.shape[3]))
+    return dq[:, :, :s], dk, dv
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

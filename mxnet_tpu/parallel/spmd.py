@@ -257,7 +257,12 @@ class TrainStep:
         src/nnvm/gradient.cc mirror fn). None reads the env var; True
         wraps the forward in jax.checkpoint with a policy keeping matmul
         AND conv outputs (elementwise recomputed) — the standard recipe
-        for large-batch training that would otherwise spill HBM.
+        for large-batch training that would otherwise spill HBM.  A block
+        that declares ``remat_layers`` (a sequence model's decoder
+        layers) gets a boundary per layer instead: only each layer's
+        input is kept and the layer is computed again in its backward.
+        ``remat_boundaries`` holds how many boundaries the step program
+        was traced with (0 until its first trace, and without remat).
 
         bucket_mb: when set, the step compiles as an EXPLICIT shard_map
         program whose gradient reduction is one psum per bucket_mb-sized
@@ -273,6 +278,7 @@ class TrainStep:
             from ..config import get as _cfg
             remat = bool(_cfg("MXNET_BACKWARD_DO_MIRROR"))
         self.remat = bool(remat)
+        self.remat_boundaries = 0
 
         if not isinstance(mesh, DeviceMesh):
             raise MXNetError("mesh must be a parallel.DeviceMesh")
@@ -341,17 +347,26 @@ class TrainStep:
         train_idx = list(self._train_idx)
         aux_idx = list(self._aux_idx)
 
-        use_remat = self.remat
+        from .. import telemetry as _telemetry
+        from ..gluon.block import remat_scope
+        # the layers the block declares as rematerialisation boundaries;
+        # a block that declares none has its whole forward wrapped
+        remat_layers = tuple(getattr(block, "remat_layers", ())) \
+            if self.remat else ()
+        whole_remat = self.remat and not remat_layers
 
         def make_step(grad_sync):
             def step(key, train_params, aux_params, opt_state, x, y):
                 def fwd(tps, x_):
                     ps = merge_params(train_idx, aux_idx, tps, aux_params)
-                    with _ag.train_mode():
+                    with _ag.train_mode(), remat_scope(remat_layers) as sc:
                         outs, mutated = apply_fn(key, ps, (x_,))
+                    # a fact about the program: taken as it is traced
+                    self.remat_boundaries = sc.boundaries or int(whole_remat)
+                    _telemetry.record_remat_boundaries(self.remat_boundaries)
                     return outs[0], mutated
 
-                if use_remat:
+                if whole_remat:
                     fwd = remat_wrap(fwd)
 
                 def compute_loss(tps):

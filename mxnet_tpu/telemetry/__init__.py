@@ -121,6 +121,11 @@ _SCAN_WINDOW = REGISTRY.gauge(
     "train steps per scanned fit-window dispatch (MXNET_SCAN_STEPS; "
     "1 = one dispatch per step)")
 _SCAN_WINDOW.set(1)
+_REMAT_BOUNDARIES = REGISTRY.gauge(
+    "mxnet_step_remat_boundaries",
+    "rematerialisation boundaries (jax.checkpoint regions) the last "
+    "traced parallel.spmd.TrainStep program holds: one per declared "
+    "layer, 1 for a whole-forward wrap, 0 without remat")
 _COLLECTIVE_BYTES = REGISTRY.counter(
     "mxnet_collective_bytes_total",
     "logical payload bytes moved by gradient-synchronization "
@@ -191,6 +196,12 @@ def record_trainer_update_calls(n):
 def record_scan_window(steps):
     """Record the active scanned-window size (Module._fit_epoch_scan)."""
     _SCAN_WINDOW.set(int(steps))
+
+
+def record_remat_boundaries(n):
+    """Record how many rematerialisation boundaries a train step program
+    was traced with (parallel.spmd.TrainStep)."""
+    _REMAT_BOUNDARIES.set(int(n))
 
 
 def record_data_wait(seconds):

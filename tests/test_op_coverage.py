@@ -213,6 +213,20 @@ CASES = {
     "multi_all_finite": C([A34, B34], lambda a, b: np.array([1.0]),
                           attrs={"num_arrays": 2}, grad=False),
     "softmin": C([A34], lambda x: _np_softmax(-x), grad=True),
+    "RMSNorm": C(
+        [A234, P34[0]],
+        lambda x, g: x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-5) * g,
+        attrs={"eps": 1e-5}, grad=True),
+    # depthwise over time, zeros before the sequence, tap K-1 on the
+    # current step
+    "_contrib_causal_conv1d": C(
+        # fixed taps, not _u(): a draw here would shift every later case
+        [A234, np.linspace(-1, 1, 12, dtype=np.float32).reshape(4, 3),
+         np.linspace(-.5, .5, 4, dtype=np.float32)],
+        lambda x, w, b: b + sum(
+            np.pad(x, [(0, 0), (2, 0), (0, 0)])[:, j:j + 3] * w[:, j]
+            for j in range(3)),
+        grad=True),
     "softmax_cross_entropy": C(
         [A34, np.array([0, 1, 2], np.float32)],
         lambda x, y: np.array(
@@ -621,6 +635,7 @@ EXEMPT = {
     "_contrib_Proposal": "test_contrib_ops.py",
     "ROIPooling": "test_contrib_ops.py",
     "_contrib_flash_attention": "test_tp_ring.py",
+    "_contrib_ssd_scan": "test_granite_hybrid.py",
     "_contrib_boolean_mask": "test_op_gap_r4.py",
     "_contrib_arange_like": "test_contrib_ops2.py",
     "Crop": "test_spatial_ops.py",
