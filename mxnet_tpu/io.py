@@ -10,6 +10,7 @@ dmlc ThreadedIter's double buffering).
 from __future__ import annotations
 
 import collections
+import functools
 import queue as _queue
 import threading
 
@@ -134,11 +135,13 @@ def make_batch_stager(ctx):
 
 
 class SuperBatch:
-    """A window of K*M DataBatches staged as ONE stacked device array per
-    data/label position (leading dim = number of batches).  Consumed by
-    the scanned train step (fused_step.ScanTrainStep); the stacked
-    label/output arrays also feed the boundary metric flush — stable
-    device data, so buffer-reusing iterators can't clobber a deferred
+    """A window of K*M DataBatches staged as ONE stacked array per
+    data/label position (leading dim = number of batches): a device
+    array stacked on the device from the K*M batches' own copies, or a
+    numpy stack where the window was staged with ``host=True``.
+    Consumed by the scanned train step (fused_step.ScanTrainStep); the
+    stacked label/output arrays also feed the boundary metric flush —
+    stable data, so buffer-reusing iterators can't clobber a deferred
     metric read."""
 
     __slots__ = ("data", "label", "count")
@@ -149,20 +152,46 @@ class SuperBatch:
         self.count = count
 
 
+@functools.cache
+def _device_stack():
+    """The program that stacks same-shape device arrays to
+    ``(len(xs), *shape)``; jit caches it by count, shape and dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    def stage_super_stack(*xs):
+        return jnp.stack(xs)
+    return jax.jit(stage_super_stack)
+
+
 def stage_super_batch(batches, ctx, host=False):
-    """Stack a window of DataBatches host-side and ``jax.device_put``
-    each data/label position ONCE as a ``(len(batches), *shape)`` array.
+    """Stage a window of DataBatches as ONE ``(len(batches), *shape)``
+    array per data/label position.
 
     This is the window-granular sibling of :func:`stage_batch`: while a
     K-step scan is in flight the fit loop stages the NEXT super-batch
-    with a single H2D transfer per input tensor position (PyGraph's
-    whole-iteration-capture argument applied to the input feed).
+    (PyGraph's whole-iteration-capture argument applied to the input
+    feed).  Each batch's array goes to ``jax.device_put`` as it is — a
+    numpy array or a zero-copy numpy view of an ``NDArray`` on the CPU
+    backend; one already on ``ctx``'s device passes through untouched,
+    as in :func:`stage_batch` — and the copies are stacked ON THE DEVICE
+    by one cached program: no stacked copy is made on the host.
 
-    ``host=True`` stops after the stack: the SuperBatch holds numpy
-    arrays.  The mesh fused window wants this — its ``run_window``
-    re-places the stacked feeds itself (``DeviceMesh.put_batch`` shards
-    the batch axis across the mesh), so a device placement here would
-    just be copied straight back out."""
+    ``host=True`` stacks with numpy and stops there: the SuperBatch
+    holds numpy arrays.  The mesh fused window wants this — its
+    ``run_window`` re-places the stacked feeds itself
+    (``DeviceMesh.put_batch`` shards the batch axis across the mesh), so
+    a device placement here would just be copied straight back out.
+
+    Counted in the span ``io/stage_super``: every array's bytes once in
+    ``mxnet_io_stage_bytes_total``, and the window in
+    ``mxnet_io_stage_windows_total{when="at_need"}`` (the fit loop and
+    the window feed, which stage while the previous window's scan runs,
+    count theirs under ``when="ahead"``)."""
+    return _stage_window(batches, ctx, host, "at_need")
+
+
+def _stage_window(batches, ctx, host, when):
     import time as _time
 
     import jax
@@ -172,37 +201,43 @@ def stage_super_batch(batches, ctx, host=False):
     dev = ctx.jax_device if ctx is not None else None
     from .chaos.failpoints import failpoint as _failpoint
     _failpoint("io/stage")
-    staged_bytes = [0]
 
     def as_host(a):
         return a.asnumpy() if isinstance(a, NDArray) else np.asarray(a)
 
-    def stack(position_lists):
-        out = []
-        for arrs in position_lists:
-            with _telemetry.span("io/stage_super/host_stack"):
-                stacked = np.stack([as_host(a) for a in arrs])
-            staged_bytes[0] += stacked.nbytes
-            if host:
-                out.append(stacked)
-                continue
-            with _telemetry.span("io/stage_super/device_put"):
-                out.append(jax.device_put(stacked, dev) if dev is not None
-                           else jax.device_put(stacked))
-        return out
+    def ready(a):
+        # what device_put takes with no copy made here: the array
+        # already on the device, else numpy (a view, for a CPU-backed
+        # NDArray: taking it waits for whatever still writes that
+        # array, such as the iterator's own asynchronous host copy)
+        if isinstance(a, NDArray) and dev in a._data.devices():
+            return a._data
+        return as_host(a)
 
+    def stack_on_host(arrs):
+        with _telemetry.span("io/stage_super/host_stack"):
+            return np.stack([as_host(a) for a in arrs])
+
+    def stack_on_device(arrs):
+        with _telemetry.span("io/stage_super/host_stack"):
+            bufs = [ready(a) for a in arrs]
+        with _telemetry.span("io/stage_super/device_put"):
+            return _device_stack()(
+                *[b if isinstance(b, jax.Array) else jax.device_put(b, dev)
+                  for b in bufs])
+
+    stack = stack_on_host if host else stack_on_device
     with _telemetry.span("io/stage_super"):
         t0 = _time.perf_counter()
-        n_data = len(batches[0].data)
-        data = stack([[b.data[i] for b in batches] for i in range(n_data)])
-        label = []
-        if batches[0].label:
-            n_label = len(batches[0].label)
-            label = stack([[b.label[i] for b in batches]
-                           for i in range(n_label)])
+        first = batches[0]
+        data = [stack([b.data[i] for b in batches])
+                for i in range(len(first.data))]
+        label = [stack([b.label[i] for b in batches])
+                 for i in range(len(first.label or ()))]
         # graftlint: disable=raw-phase-timing -- this IS telemetry's collection point for the io staging wait
         _telemetry.record_io_stage(_time.perf_counter() - t0,
-                                   staged_bytes[0])
+                                   sum(a.nbytes for a in data + label))
+        _telemetry.record_io_stage_window(when)
     return SuperBatch(data, label, len(batches))
 
 

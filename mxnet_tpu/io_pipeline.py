@@ -628,8 +628,14 @@ class WindowFeed:
     Pulls batches from ``data_iter`` (any iterator — a
     :class:`DataPipeline` assembler or a plain DataIter), groups them
     into W-batch windows exactly like ``Module._fit_epoch_scan_inner``
-    .collect(), and runs ``io.stage_super_batch`` OFF the train
-    thread.  A 2-deep bounded queue double-buffers: window N+1 is
+    .collect(), and runs ``io.stage_super_batch``'s staging OFF the
+    train thread: the same as the fit loop's own (each batch put on
+    the device as it is and the window stacked there; a numpy stack
+    under ``host``), counted in ``mxnet_io_stage_windows_total`` as
+    staged ``ahead``.  Without the feed the fit loop stages window N+1
+    itself, on the train thread, between window N's dispatch and its
+    boundary; the feed takes collecting and staging off that thread
+    altogether.  A 2-deep bounded queue double-buffers: window N+1 is
     collected and staged while window N's scan executes.  Items:
 
     * ``("window", batches, sbatch, (t0, t1))`` — a full staged window
@@ -673,8 +679,8 @@ class WindowFeed:
                         break
                 span = (t0, time.perf_counter())
                 if len(batches) == self._window and full:
-                    sbatch = mx_io.stage_super_batch(batches, self._ctx,
-                                                     host=self._host)
+                    sbatch = mx_io._stage_window(batches, self._ctx,
+                                                 self._host, "ahead")
                     _telemetry.record_data_queue_depth(
                         self._q.qsize() + 1, role="feed")
                     self._put(("window", batches, sbatch, span))

@@ -214,9 +214,19 @@ class BaseModule:
         same-shape batches is staged as one super-batch and dispatched
         as ONE scanned XLA computation; metrics, callbacks, watchdog
         beats and timeline accounting happen at window boundaries.
+        Once window N is dispatched the loop collects AND stages window
+        N+1 (``io.stage_super_batch``'s staging: the batches go to the
+        device one by one and are stacked there) while N's scan runs,
+        and only then runs N's boundary, whose metric read waits for
+        the device: the next top of the loop finds its window staged
+        and goes straight to the dispatch.  Nothing of window N+1 is
+        dispatched before N's boundary.  Only the first window of an
+        epoch, and the first after a per-batch fallback, is staged at
+        need (``mxnet_io_stage_windows_total`` counts both kinds).
         Batches that don't fill a window (epoch tail, shape-mismatched
-        batches) run through the per-batch path unchanged; an error
-        raised by a window propagates to the caller of fit."""
+        batches) run through the per-batch path unchanged, in arrival
+        order, and are never staged as a window; an error raised by a
+        window or by its staging propagates to the caller of fit."""
         K, M = plan[0], plan[1]
         W = K * M
         # a healthy window legitimately goes W batch-times between
@@ -311,13 +321,33 @@ class BaseModule:
             timeline.end_step()
             wdog.beat("train/fit")
 
+        def stage(batches, when):
+            # one window's staging on this thread.  Like "collect", the
+            # interval is claimed as its "stage" stage by the trace of
+            # the window it feeds, not the one in flight while it ran
+            t_s0 = time.perf_counter()
+            with timeline.lane("h2d_stage"):
+                sbatch = mx_io._stage_window(batches, ctx, stage_host, when)
+            state["stage"] = (t_s0, time.perf_counter())
+            return sbatch
+
+        def stage_ahead(pending):
+            # a full window just collected while the dispatched scan
+            # runs: put it on the device now, so that the copy lies
+            # under the scan and not between two scans.  The window
+            # feed has staged its own; a short group stays unstaged
+            batches, tail, staged = pending
+            if feed is None and len(batches) == W:
+                staged = stage(batches, "ahead")
+            return batches, tail, staged
+
         pending = collect()
         timeline.begin_step()
         try:
             while True:
                 batches, tail, staged = pending
-                is_window = (staged is not None) if feed is not None \
-                    else (len(batches) == W)
+                is_window = staged is not None or \
+                    (feed is None and len(batches) == W)
                 outs = False
                 wtrace = _telemetry.trace.NULL_TRACE
                 if is_window:
@@ -333,16 +363,13 @@ class BaseModule:
                     wtrace.add_stage(
                         "collect", *(state.get("collect")
                                      or (wtrace.t0, wtrace.t0)))
-                    if staged is not None:
-                        # the window feed already collected AND staged
-                        # this super-batch off-thread — zero train-thread
-                        # staging time (that is the point)
-                        sbatch = staged
-                    else:
-                        with timeline.lane("h2d_stage"), \
-                                wtrace.stage("stage"):
-                            sbatch = mx_io.stage_super_batch(
-                                batches, ctx, host=stage_host)
+                    # staged while the previous window's scan ran (by
+                    # stage_ahead, or off-thread by the window feed:
+                    # zero train-thread staging time), else at need
+                    sbatch = staged if staged is not None \
+                        else stage(batches, "at_need")
+                    if state.get("stage"):
+                        wtrace.add_stage("stage", *state.pop("stage"))
                     _telemetry.trace.set_current(wtrace)
                     try:
                         with timeline.lane("step_dispatch"), \
@@ -376,9 +403,10 @@ class BaseModule:
                     finally:
                         _telemetry.trace.set_current(None)
                 if outs is not False:
-                    # prefetch: collect the next window while this scan
-                    # is still in flight on device (dispatch was async)
-                    pending = collect()
+                    # prefetch: collect and stage the next window while
+                    # this scan is still in flight on device (dispatch
+                    # was async)
+                    pending = stage_ahead(collect())
                     # window boundary: the only host-control point —
                     # metric updates (stacked, one sync), batch
                     # callbacks, timeline, watchdog beat

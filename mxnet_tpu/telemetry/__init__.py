@@ -83,6 +83,13 @@ _IO_STAGE = REGISTRY.histogram(
     "host time spent staging a DataBatch host->device (io.stage_batch)")
 _IO_STAGE_BYTES = REGISTRY.counter(
     "mxnet_io_stage_bytes_total", "bytes staged host->device by io")
+_IO_STAGE_WINDOWS = REGISTRY.counter(
+    "mxnet_io_stage_windows_total",
+    "scanned-fit windows staged by io.stage_super_batch, by when: "
+    "ahead (while the previous window's scan was in flight: the fit "
+    "loop after a dispatch, or the WindowFeed thread) or at_need (on "
+    "the train thread with nothing dispatched: the first window of an "
+    "epoch, the first after a per-batch fallback)")
 _STEP_HOST_ARG_LEAVES = REGISTRY.counter(
     "mxnet_step_host_arg_leaves",
     "leaves of a train step call's arguments that were not arrays "
@@ -175,6 +182,12 @@ def record_io_stage_bytes(nbytes):
     in the record of the span open around the copy."""
     if nbytes:
         count_in_span(_IO_STAGE_BYTES, int(nbytes))
+
+
+def record_io_stage_window(when):
+    """Account one staged scanned window from inside ``io/stage_super``:
+    ``when`` is ``ahead`` or ``at_need``."""
+    count_in_span(_IO_STAGE_WINDOWS, 1, {"when": when})
 
 
 def record_step_host_args(step, stats):

@@ -199,6 +199,41 @@ def test_fit_parity_pipeline_on_off(monkeypatch, optimizer, opt_params):
     assert d_on == d_off, "the feed changed the dispatch count"
 
 
+@pytest.mark.parametrize("host", [False, True])
+def test_window_feed_stages_what_the_train_thread_would(host):
+    """The feed thread's staged window is bitwise the in-thread one:
+    stacked on the module's device, or numpy stacks under ``host``
+    (the mesh window), and counted as staged ahead."""
+    import jax
+    from mxnet_tpu import telemetry
+    W, ctx = 4, mx.cpu(1)
+    x, y = _dataset(16 * W)
+    batches = [mxio.DataBatch(data=[mx.nd.array(x[i:i + 16])],
+                              label=[mx.nd.array(y[i:i + 16])], pad=0)
+               for i in range(0, len(x), 16)]
+    ahead = telemetry.REGISTRY.get("mxnet_io_stage_windows_total")
+    before = ahead.value({"when": "ahead"})
+    feed = mxpipe.WindowFeed(iter(batches), W, ctx, lambda b: True,
+                             host=host)
+    try:
+        kind, got, fed, _span = feed.get()
+    finally:
+        feed.close()
+    assert kind == "window" and got == batches
+    assert ahead.value({"when": "ahead"}) - before == 1
+    here = mxio.stage_super_batch(batches, ctx, host=host)
+    assert fed.count == here.count == W
+    for a, b, want in zip(fed.data + fed.label, here.data + here.label,
+                          (x.reshape(W, 16, -1), y.reshape(W, 16))):
+        if host:
+            assert type(a) is type(b) is np.ndarray
+        else:
+            assert isinstance(a, jax.Array) and isinstance(b, jax.Array)
+            assert a.devices() == b.devices() == {ctx.jax_device}
+        assert np.array_equal(np.asarray(a), want)
+        assert np.array_equal(np.asarray(b), want)
+
+
 def test_fit_parity_mesh_window(monkeypatch):
     """Same gate on the dp=2 x tp=2 mesh window path (host-staged
     super-batches): feed on == feed off, weights AND updater state."""

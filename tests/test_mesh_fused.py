@@ -209,6 +209,32 @@ def test_fsdp_layout_reduce_scatter_update():
             np.testing.assert_allclose(a, b, rtol=2e-6, atol=2e-7)
 
 
+@pytest.mark.parametrize("backing", ["ndarray", "numpy"])
+def test_mesh_window_staging_stays_on_the_host(backing):
+    """``stage_super_batch(host=True)``, the mesh window's staging, still
+    returns numpy stacks (``DeviceMesh.put_batch`` shards them itself),
+    whatever backs the batches; the device staging of the same window
+    holds the same values."""
+    K, BS = 4, 16
+    x, y = _data(K, BS)
+
+    def wrap(a):
+        return mx.nd.array(a) if backing == "ndarray" else a.copy()
+
+    batches = [mxio.DataBatch(data=[wrap(x[j * BS:(j + 1) * BS])],
+                              label=[wrap(y[j * BS:(j + 1) * BS])])
+               for j in range(K)]
+    staged = mxio.stage_super_batch(batches, mx.cpu(), host=True)
+    on_dev = mxio.stage_super_batch(batches, mx.cpu())
+    assert staged.count == on_dev.count == K
+    for got, dev, want in zip(staged.data + staged.label,
+                              on_dev.data + on_dev.label,
+                              (x.reshape(K, BS, -1), y.reshape(K, BS))):
+        assert type(got) is np.ndarray and got.flags.owndata
+        assert isinstance(dev, jax.Array)
+        assert np.array_equal(got, want) and np.array_equal(dev, want)
+
+
 def test_fsdp_rejects_non_elementwise_optimizer():
     _need_devices(4)
     build, init, _rng = F._mesh_models()

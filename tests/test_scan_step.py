@@ -365,3 +365,212 @@ def test_scan_default_off_keeps_per_batch_path(monkeypatch):
             initializer=mx.initializer.Xavier())
     assert mod._scan is None
     assert mod._scan_plan() is None
+
+
+# -- staging the next window while the current one runs ----------------------
+class _ListIter(mxio.DataIter):
+    """Prepared DataBatches in order; logs every ``next()``."""
+
+    def __init__(self, batches, batch_size=16, feat=20, log=None):
+        super().__init__(batch_size)
+        self.batches = batches
+        self.log = log if log is not None else []
+        self.provide_data = [mxio.DataDesc("data", (batch_size, feat))]
+        self.provide_label = [mxio.DataDesc("softmax_label", (batch_size,))]
+        self.cur = 0
+
+    def reset(self):
+        self.cur = 0
+
+    def next(self):
+        if self.cur == len(self.batches):
+            raise StopIteration
+        self.log.append(("next", self.cur))
+        self.cur += 1
+        return self.batches[self.cur - 1]
+
+
+def _batches(x, y, kind="ndarray", ctx=None, batch_size=16):
+    """``x``/``y`` cut into DataBatches whose arrays are numpy
+    (``numpy``) or NDArrays on ``ctx`` (default: the first CPU device)."""
+    def wrap(a):
+        return a.copy() if kind == "numpy" else mx.nd.array(a, ctx=ctx)
+    return [mxio.DataBatch(data=[wrap(x[i:i + batch_size])],
+                           label=[wrap(y[i:i + batch_size])], pad=0)
+            for i in range(0, len(x), batch_size)]
+
+
+def _fit_iter(monkeypatch, scan_steps, it, context=None, eval_metric="acc",
+              batch_end_callback=None):
+    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
+    monkeypatch.setenv("MXNET_SCAN_STEPS", str(scan_steps))
+    monkeypatch.setenv("MXNET_SCAN_ACCUM", "1")
+    mx.random.seed(0)
+    mod = mx.mod.Module(_mlp(), context=context or mx.cpu())
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
+            arg_params={k: v.copy() for k, v in _init_params().items()},
+            eval_metric=eval_metric, batch_end_callback=batch_end_callback)
+    params, _ = mod.get_params()
+    return mod, {k: v.asnumpy() for k, v in params.items()}
+
+
+def _log_staging_and_dispatch(monkeypatch, log):
+    """Log ``("stage", when, n batches)`` at every window staging and
+    ``("dispatch", rows of the window's first column)`` at every scanned
+    dispatch."""
+    from mxnet_tpu.fused_step import ScanTrainStep
+    stage, run = mxio._stage_window, ScanTrainStep.run_window
+
+    def logged_stage(batches, ctx, host, when):
+        log.append(("stage", when, len(batches)))
+        return stage(batches, ctx, host, when)
+
+    def logged_run(self, sbatch):
+        log.append(("dispatch",
+                    [float(v) for v in np.asarray(sbatch.data[0])[:, 0, 0]]))
+        return run(self, sbatch)
+
+    monkeypatch.setattr(mxio, "_stage_window", logged_stage)
+    monkeypatch.setattr(ScanTrainStep, "run_window", logged_run)
+
+
+def test_scan_stages_next_window_before_the_boundary(monkeypatch):
+    """Window N+1 is collected and staged while window N's scan runs,
+    before N's first metric update; nothing of N+1 is dispatched before
+    N's boundary callbacks ran."""
+    K = 4
+    x, y = _dataset(16 * 3 * K)
+    log = []
+
+    class LoggedAcc(mx.metric.Accuracy):
+        def update(self, labels, preds):
+            log.append(("update",))
+            super().update(labels, preds)
+
+    _log_staging_and_dispatch(monkeypatch, log)
+    it = _ListIter(_batches(x, y), log=log)
+    mod, _ = _fit_iter(
+        monkeypatch, K, it, eval_metric=LoggedAcc(),
+        batch_end_callback=lambda p: log.append(("callback", p.nbatch)))
+    assert mod._scan.windows == 3
+    kinds = [(e[0], e[1]) if e[0] == "stage" else e[0] for e in log]
+    nexts, boundary = ["next"] * K, ["update"] * K + ["callback"] * K
+    assert kinds == (
+        nexts + [("stage", "at_need"), "dispatch"]
+        + nexts + [("stage", "ahead")] + boundary + ["dispatch"]
+        + nexts + [("stage", "ahead")] + boundary + ["dispatch"]
+        + boundary), kinds
+    assert [e[1] for e in log if e[0] == "callback"] == list(range(3 * K))
+
+
+@pytest.mark.parametrize("kind,batch_dev,module_dev", [
+    ("numpy", None, 0),          # numpy arrays handed to device_put
+    ("ndarray", 0, 1),           # CPU-backed NDArrays elsewhere: views
+    ("ndarray", 1, 1),           # already on the module's device
+])
+def test_scan_staging_parity_bitwise(monkeypatch, kind, batch_dev,
+                                     module_dev):
+    """A scanned epoch fed numpy-backed, CPU-NDArray or already-on-device
+    batches == the sequential fused loop, bit for bit."""
+    K = 4
+    x, y = _dataset(16 * 2 * K)
+    batches = _batches(x, y, kind,
+                       mx.cpu(batch_dev) if batch_dev is not None else None)
+    ms, ps = _fit_iter(monkeypatch, K, _ListIter(batches),
+                       context=mx.cpu(module_dev))
+    assert ms._scan is not None and ms._scan.windows == 2
+    _, pq = _fit(monkeypatch, 1, x, y,
+                 opt_params={"learning_rate": 0.05, "momentum": 0.9})
+    for k in ps:
+        assert np.array_equal(ps[k], pq[k]), f"param {k} diverged"
+
+
+def test_scan_short_groups_are_never_staged(monkeypatch):
+    """n % K != 0 and a shape-mismatched batch mid-epoch: every batch
+    trains exactly once, in arrival order, only full windows are staged,
+    and the parameters are the sequential loop's."""
+    K = 4
+    x, y = _dataset(16 * 13)
+    x[:, 0] = np.repeat(np.arange(13), 16)     # a batch's number, in it
+    batches = _batches(x, y)
+    short = batches[6]                         # half a batch, mid-epoch
+    batches[6] = mxio.DataBatch(data=[short.data[0][:8]],
+                                label=[short.label[0][:8]], pad=0)
+    log = []
+    _log_staging_and_dispatch(monkeypatch, log)
+    per_batch = mx.mod.Module.forward_backward
+
+    def logged_forward_backward(self, data_batch):
+        log.append(("step", float(data_batch.data[0].asnumpy()[0, 0])))
+        return per_batch(self, data_batch)
+
+    monkeypatch.setattr(mx.mod.Module, "forward_backward",
+                        logged_forward_backward)
+    _, ps = _fit_iter(monkeypatch, K, _ListIter(batches))
+    trained = []
+    for e in log:
+        if e[0] == "dispatch":
+            trained += e[1]
+        elif e[0] == "step":
+            trained.append(e[1])
+    assert trained == [float(i) for i in range(13)], trained
+    assert {e[2] for e in log if e[0] == "stage"} == {K}
+    # (the mismatched batch rebinds the executor, so the second window
+    # runs in a new ScanTrainStep: count the dispatches, not .windows)
+    assert sum(e[0] == "dispatch" for e in log) == \
+        sum(e[0] == "stage" for e in log) == 2
+    log.clear()
+    _, pq = _fit_iter(monkeypatch, 1, _ListIter(batches))
+    assert [e[1] for e in log if e[0] == "step"] == trained
+    for k in ps:
+        assert np.array_equal(ps[k], pq[k]), f"param {k} diverged"
+
+
+@pytest.mark.parametrize("hits,when", [(1, "at_need"), (2, "ahead")])
+def test_scan_staging_error_reaches_the_caller_of_fit(monkeypatch, hits,
+                                                      when):
+    """An error raised while a window is staged, at need or ahead (the
+    failpoint ``io/stage``), propagates out of fit."""
+    from mxnet_tpu.chaos import failpoints as fp
+    x, y = _dataset(16 * 8)
+    log = []
+    _log_staging_and_dispatch(monkeypatch, log)
+    fp.arm("io/stage", "raise", hits=hits, count=1)
+    try:
+        with pytest.raises(fp.ChaosInjectedError):
+            _fit_iter(monkeypatch, 4, _ListIter(_batches(x, y)))
+    finally:
+        fp.reset()
+    assert [e[1] for e in log if e[0] == "stage"][-1] == when
+    assert sum(e[0] == "dispatch" for e in log) == hits - 1
+
+
+def test_scan_stage_counters(monkeypatch):
+    """An epoch of full windows counts one window staged at need and the
+    rest ahead, and every batch's bytes once — in the registry and in
+    the records of ``io/stage_super``."""
+    from mxnet_tpu import telemetry
+    K, windows = 4, 3
+    x, y = _dataset(16 * K * windows)
+    staged = telemetry.REGISTRY.get("mxnet_io_stage_windows_total")
+    nbytes = telemetry.REGISTRY.get("mxnet_io_stage_bytes_total")
+    before = (staged.value({"when": "at_need"}),
+              staged.value({"when": "ahead"}), nbytes.value())
+    telemetry.enable()
+    telemetry.reset_span_records()
+    try:
+        _fit_iter(monkeypatch, K, _ListIter(_batches(x, y)))
+        records = [r for r in telemetry.span_records()
+                   if r["name"] == "io/stage_super"]
+    finally:
+        telemetry.disable()
+    assert staged.value({"when": "at_need"}) - before[0] == 1
+    assert staged.value({"when": "ahead"}) - before[1] == windows - 1
+    assert nbytes.value() - before[2] == x.nbytes + y.nbytes
+    assert [r["counts"] for r in records] == [
+        {"mxnet_io_stage_bytes_total": (x.nbytes + y.nbytes) // windows,
+         "mxnet_io_stage_windows_total": 1}] * windows
+    # one staging per step id from the second window on: the first
+    # holds its own, at need, and the second's, ahead
+    assert [r["step"] - records[0]["step"] for r in records] == [0, 0, 1]
