@@ -31,11 +31,6 @@ import os
 import sys
 import time
 
-# Peak dense bf16 FLOP/s of one chip, keyed by ``device.device_kind``.
-# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).  A
-# device that is not in the table is an error, not a default.
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12, "TPU v5e": 197e12}
-
 # |tpu - cpu| logits, relative to max|cpu logit|.  Reason: the TPU's
 # default matmul/conv precision multiplies float32 operands as bfloat16
 # (8-bit mantissa, ~2e-3 per product, float32 accumulation) and the
@@ -439,10 +434,15 @@ def main(argv=None):
         print(f"chip_smoke: --chips {args.chips} but jax found {len(devs)}",
               file=sys.stderr)
         return 1
-    if dev.device_kind not in PEAK_BF16_FLOPS:
-        print(f"chip_smoke: no table peak for device_kind "
-              f"{dev.device_kind!r}; add it to PEAK_BF16_FLOPS with its "
-              "source", file=sys.stderr)
+    # the one table of peaks is the benchmark's (peaks.json); the import
+    # is one-way, benchmark/ imports nothing from here
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "benchmark", "harness"))
+    import benchcore
+    try:
+        peak_flops = benchcore.peak_flops(dev.device_kind)
+    except benchcore.BenchFailure as e:
+        print(f"chip_smoke: {e}", file=sys.stderr)
         return 1
 
     import mxnet_tpu as mx
@@ -467,7 +467,7 @@ def main(argv=None):
         leg_agree(ctx, resnet50, fused["arg_params"], fused["aux_params"],
                   (8, 3, 224, 224))
         say("[clock] chained matmul against the table peak")
-        leg_clock(dev, PEAK_BF16_FLOPS[dev.device_kind])
+        leg_clock(dev, peak_flops)
         say("[kernels] LayerNorm, softmax_cross_entropy, flash attention")
         leg_kernels(ctx)
     else:

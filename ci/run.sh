@@ -44,7 +44,7 @@ echo "== fused + scanned train step smoke (dispatch budget, parity) =="
 # the fused path must issue at most 3 XLA dispatches per train step and
 # stay bit-identical to the per-param update loop; the K=8 scanned
 # window must issue <= (1+eps)/K dispatches per step and stay
-# bit-identical to the sequential fused loop (docs/perf_notes.md)
+# bit-identical to the sequential fused loop (mxnet_tpu/fused_step.py)
 JAX_PLATFORMS=cpu python -m mxnet_tpu.fused_step
 
 echo "== streaming data plane smoke (shard-order determinism, dead-reader exactly-once, backpressure) =="

@@ -20,7 +20,7 @@ summaries.  This rule fires only when:
 
 * the function lives in a threaded subsystem (same
   ``THREADED_PREFIXES`` gate as lock-order-cycle — single-threaded
-  tools/bench code can't deadlock), and
+  tools code can't deadlock), and
 * the held sets DIFFER (symmetric difference non-empty).
 
 Near-misses that stay silent: both sites lock-free, both sites under

@@ -26,8 +26,8 @@ module owns its lifecycle for the whole framework:
 
 Entries below ``MXNET_COMPILE_CACHE_MIN_COMPILE_S`` of backend compile
 time are not persisted (jax's own default policy): tiny programs are
-cheaper to recompile than to hash + stat.  Tests, the CI compile smoke
-and the cold-start bench set it to 0 so toy models persist too.
+cheaper to recompile than to hash + stat.  Tests and the CI compile
+smoke set it to 0 so toy models persist too.
 """
 from __future__ import annotations
 

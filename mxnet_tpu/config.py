@@ -150,7 +150,7 @@ _register("MXNET_FUSED_STEP", bool, True,
           "Module train steps: trace forward+backward+optimizer update "
           "into ONE donated jax.jit computation (1 dispatch/step) when "
           "the optimizer exposes fused_update; 0 restores the per-param "
-          "dispatch loop (docs/perf_notes.md dispatch overhead)")
+          "dispatch loop (fused_step.py)")
 _register("MXNET_METRIC_SYNC_INTERVAL", int, 1,
           "Module.update_metric: flush buffered (label, output) pairs "
           "into the metric every N batches instead of forcing a "
@@ -163,7 +163,7 @@ _register("MXNET_SCAN_STEPS", int, 1,
           "jax.lax.scan dispatch (a K-step window); host control "
           "(metrics, callbacks, watchdog beats) happens at window "
           "boundaries only. 1 = one dispatch per step (PR-4 behaviour); "
-          "requires the fused-step eligibility (docs/perf_notes.md)")
+          "requires the fused-step eligibility (fused_step.py)")
 _register("MXNET_SCAN_ACCUM", int, 1,
           "in-scan gradient accumulation: each scanned train step "
           "consumes this many micro-batches and applies ONE optimizer "
@@ -186,8 +186,7 @@ _register("MXNET_COLLECTIVE_BUCKET_MB", float, 4.0,
 _register("MXNET_COLLECTIVE_MODE", str, "bucketed",
           "mesh fused step collective formulation: 'bucketed' (default) "
           "or 'off' (skip gradient collectives entirely — WRONG results, "
-          "bench/debug only: the differential against 'bucketed' is how "
-          "multichip_comm_blocking_pct isolates communication time)")
+          "debug only)")
 _register("MXNET_COLLECTIVE_COMPRESSION", str, "none",
           "mesh fused step per-bucket gradient codec: 'none' (exact "
           "dense psum), 'fp16' (halved wire bytes, ~1e-3 relative "
@@ -229,11 +228,6 @@ _register("MXNET_MULTIHOST_BARRIER_TIMEOUT_S", float, 60.0,
 _register("MXNET_MULTIHOST_MAX_RESTARTS", int, 3,
           "elastic launcher: maximum world restarts (preemption "
           "recoveries/resizes) before the job fails typed")
-_register("MXNET_FIT_STAGE_NEXT", bool, True,
-          "fit loop: stage the NEXT DataBatch host->device "
-          "(jax.device_put) while the current step is still in flight, "
-          "overlapping input feed with compute; 0 feeds batches "
-          "synchronously at forward time")
 # -- streaming data plane (io_pipeline.py) -----------------------------------
 _register("MXNET_DATA_WORKERS", int, 0,
           "streaming data plane: reader worker threads per "
@@ -476,7 +470,7 @@ _register("MXNET_COMPILE_CACHE_DIR", str, "",
 _register("MXNET_COMPILE_CACHE_MIN_COMPILE_S", float, 1.0,
           "only persist programs whose backend compile took at least "
           "this long (tiny programs recompile cheaper than they "
-          "hash+stat); tests/smoke/bench set 0 so toy models persist")
+          "hash+stat); tests and smokes set 0 so toy models persist")
 _register("MXNET_COMPILE_CACHE_SALT", str, "",
           "extra salt mixed into the artifact version key (forces a "
           "fresh cache namespace without touching the directory; tests "
@@ -588,173 +582,6 @@ _register("MXNET_CKPT_WATCH_INTERVAL_S", float, 1.0,
 _register("MXNET_CKPT_COMMIT_TIMEOUT_S", float, 60.0,
           "multi-host commit: how long host 0 waits for every host's "
           "shard manifest before failing the save")
-# -- driver / bench ---------------------------------------------------------
+# -- driver ------------------------------------------------------------------
 _register("MX_DRYRUN_TIMEOUT", float, 900.0,
           "subprocess timeout for __graft_entry__.dryrun_multichip")
-_register("BENCH_TIME_BUDGET", float, 1200.0, "bench.py wall budget (s)")
-_register("BENCH_BATCH", int, 32, "bench.py primary batch size")
-_register("BENCH_BATCH2", int, 128,
-          "bench.py second MFU point (0 disables)")
-_register("BENCH_BATCH3", int, 256,
-          "bench.py third MFU point (0 disables)")
-_register("BENCH_ITERS", int, 20, "bench.py timed iterations")
-_register("BENCH_WARMUP", int, 2, "bench.py warmup iterations")
-_register("BENCH_K", int, 8,
-          "bench.py steps chained per timed dispatch")
-_register("BENCH_DTYPE", str, "bfloat16", "bench.py compute dtype")
-_register("BENCH_LOSS", str, "fused",
-          "bench.py loss path: 'fused' (Pallas softmax-ce) or 'plain'")
-_register("BENCH_REMAT_FROM_BS", int, 64,
-          "bench.py: rematerialize the train step at batch >= this "
-          "(0 disables); see MXNET_BACKWARD_DO_MIRROR")
-_register("BENCH_CALIB_N", str, "4096,8192",
-          "bench.py peak-calibration matmul dimensions "
-          "(comma-separated sweep)")
-_register("BENCH_CALIB_REPS", int, 40,
-          "bench.py peak-calibration chain length per size "
-          "(one fori_loop dispatch)")
-_register("BENCH_REC_IMAGES", int, 512,
-          "tools/bench_pipeline.py synthetic .rec image count")
-_register("BENCH_WORKERS", int, 4,
-          "tools/bench_pipeline.py DataLoader worker count")
-_register("BENCH_B", int, 4,
-          "tools/bench_attention.py batch size")
-_register("BENCH_SEQS", str, "512,1024,2048",
-          "tools/bench_attention.py sequence lengths "
-          "(comma-separated sweep)")
-_register("BENCH_SERVE", bool, True,
-          "bench.py: also measure serving throughput (resnet18 via the "
-          "DynamicBatcher under Poisson arrivals)")
-_register("BENCH_SERVE_SECONDS", float, 8.0,
-          "bench.py serving phase: Poisson measurement window (s)")
-_register("BENCH_SERVE_RATE", float, 0.0,
-          "bench.py serving phase: Poisson arrival rate (req/s); 0 = "
-          "auto (1.2x the closed-loop probe throughput)")
-_register("BENCH_SERVE_BATCH", int, 32,
-          "bench.py serving phase: DynamicBatcher max_batch_size")
-_register("BENCH_SERVE_LATENCY_MS", float, 10.0,
-          "bench.py serving phase: DynamicBatcher max_latency_ms")
-_register("BENCH_SERVE_SPIKE", bool, True,
-          "bench.py: also measure the replica-pool phases "
-          "serve_sustained_img_per_sec (pool >= 2x single-batcher "
-          "throughput) and serve_spike_p99_ms (p99 under a 10x Poisson "
-          "spike <= 3x steady, excess shed typed); pure-host runner, "
-          "no device")
-_register("BENCH_SERVE_SPIKE_SECONDS", float, 2.0,
-          "bench.py spike phase: steady-state window length (s); the "
-          "spike window runs half as long at BENCH_SERVE_SPIKE_X the "
-          "arrival rate")
-_register("BENCH_SERVE_SPIKE_X", float, 10.0,
-          "bench.py spike phase: spike arrival-rate multiplier over "
-          "the steady-state Poisson rate")
-_register("BENCH_SERVE_SPIKE_REPLICAS", int, 4,
-          "bench.py spike phase: ReplicaPool size (the >= 2x-vs-single "
-          "throughput gate scales with this)")
-_register("BENCH_GENERATE", bool, True,
-          "bench.py: also measure the generation phases "
-          "generate_tokens_per_sec / generate_p99_intertoken_ms "
-          "(Poisson session arrivals through a pure-host per-token-"
-          "cost engine, CPU-only) plus the shared-prefix "
-          "prefix-cache hit-rate gate")
-_register("BENCH_GENERATE_SECONDS", float, 2.0,
-          "bench.py generation phase: Poisson session-arrival window "
-          "(s)")
-_register("BENCH_GENERATE_RATE", float, 0.0,
-          "bench.py generation phase: Poisson session arrival rate "
-          "(sessions/s); 0 = auto-sized from the per-token host cost")
-_register("BENCH_GENERATE_TOKENS", int, 32,
-          "bench.py generation phase: max_new_tokens per session")
-_register("BENCH_KERNELS", bool, True,
-          "bench.py: measure the kernel_tuner phases (tuner overhead "
-          "seconds) and tuned-vs-reference LayerNorm latency, in-process "
-          "on the chip")
-_register("BENCH_FLEET", bool, True,
-          "bench.py: run the fleet-scale observability simulator "
-          "(telemetry.fleet_sim) at rank=100 and rank=1000 in "
-          "subprocesses and gate merge p99 / rollup CPU / summary "
-          "scrape size / alert lag / sublinearity (CPU-only, pure "
-          "host CPU)")
-_register("BENCH_DISPATCH", bool, True,
-          "bench.py: measure fused-train-step dispatch phases on the CPU "
-          "backend (resnet50_step_dispatches / train_step_ms_bs32): "
-          "counts and host wall time, not device metrics")
-_register("BENCH_DISPATCH_STEPS", int, 20,
-          "bench.py dispatch phase: timed Module steps for "
-          "train_step_ms_bs32")
-_register("BENCH_DISPATCH_IMAGE", int, 32,
-          "bench.py dispatch phase: ResNet-50 image edge for the "
-          "dispatch count (count is shape-independent; small keeps CPU "
-          "convs cheap)")
-_register("BENCH_DISPATCH_BATCH", int, 4,
-          "bench.py dispatch phase: ResNet-50 batch for the dispatch "
-          "count")
-_register("BENCH_SCAN", bool, True,
-          "bench.py: also measure the K-step scanned train window on the "
-          "CPU backend (train_step_ms_scan_k<K> / "
-          "scan_dispatches_per_step): counts and host wall time")
-_register("BENCH_SCAN_K", int, 8,
-          "bench.py scan phase: MXNET_SCAN_STEPS window size")
-_register("BENCH_DATA", bool, True,
-          "bench.py: also measure the streaming data plane — a K=8 "
-          "scan-window fit on a compute-representative model with the "
-          "multi-worker pipeline on (data_wait_pct, gated < 5% of "
-          "step wall) vs the serial in-thread loop "
-          "(data_wait_serial_ratio); pure-host phase, no device")
-_register("BENCH_TELEMETRY", bool, True,
-          "bench.py: also measure the disabled-path cost of "
-          "telemetry.span (telemetry_disabled_span_ns; the <1us budget "
-          "that lets hot loops stay annotated unconditionally)")
-_register("BENCH_TRACE", bool, True,
-          "bench.py: also measure the disabled-path cost of one "
-          "end-to-end trace hook + one flight-recorder record "
-          "(trace_disabled_overhead_ns; the <1us budget that lets the "
-          "request/window tracing and the event ring stay wired into "
-          "hot paths unconditionally)")
-_register("BENCH_ALERTS", bool, True,
-          "bench.py: also measure the alert/resource observatory "
-          "overheads — one evaluation pass over the default rule pack "
-          "(alert_tick_overhead_us) and one host resource sample "
-          "(resource_sample_overhead_us), both gated < 1 ms, plus the "
-          "engine-disabled tick gated < 1 us like span/trace/failpoint")
-_register("BENCH_LINT", bool, True,
-          "bench.py: also measure graftlint_full_tree_s — one "
-          "whole-tree run of the two-phase lint engine (lexical walk + "
-          "summary collection + call-graph flow rules) in a fresh "
-          "subprocess, gated under the ci/run.sh 15 s wall budget with "
-          "the slowest rules named from --timings")
-_register("BENCH_NUMERICS", bool, True,
-          "bench.py: also measure the numerics observatory — armed "
-          "K=8 scanned-window overhead vs off (< 5% step wall, "
-          "dispatches/step unchanged) and the disabled boundary-check "
-          "path (< 1 us, the span/trace/failpoint bar)")
-_register("BENCH_COLD_START", bool, True,
-          "bench.py: also measure cold_start_first_request_ms — warm "
-          "restart (persistent compile cache) vs cold cache dir, in "
-          "fresh subprocesses on the CPU backend")
-_register("BENCH_CHAOS", bool, True,
-          "bench.py: also measure degraded_p99_ms — serving p99 with "
-          "one wedged batcher worker vs healthy (gate: <= 3x healthy "
-          "p99 while shedding); pure-host phase, no device")
-_register("BENCH_MULTICHIP", bool, True,
-          "bench.py: also measure the mesh fused distributed step in a "
-          "subprocess forced to an 8-fake-device CPU mesh "
-          "(multichip_dispatches_per_step / multichip_comm_blocking_pct; "
-          "CPU-only like the other host phases)")
-_register("BENCH_MULTICHIP_K", int, 8,
-          "bench.py multichip phase: MXNET_SCAN_STEPS window size on the "
-          "dp=2,tp=2 mesh (the <=(1+eps)/K dispatch gate)")
-_register("BENCH_MULTIHOST", bool, True,
-          "bench.py: also measure the elastic multi-host runtime — "
-          "2 worker processes x 4 fake CPU devices each under the "
-          "elastic launcher (multihost_dispatches_per_step, "
-          "multihost_recovery_s, collective-compression byte ratio); "
-          "CPU-only like the other host phases")
-_register("BENCH_MULTIHOST_K", int, 8,
-          "bench.py multihost phase: MXNET_SCAN_STEPS window size for "
-          "the 2-process mesh (the <=(1+eps)/K per-process dispatch "
-          "gate)")
-_register("BENCH_CKPT", bool, True,
-          "bench.py: also measure checkpoint save-blocking time and "
-          "restore latency (ckpt_save_blocking_ms / ckpt_restore_s)")
-_register("BENCH_CKPT_MB", int, 64,
-          "bench.py checkpoint phase: synthetic state size in MB")

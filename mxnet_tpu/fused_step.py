@@ -13,11 +13,19 @@ in place.
 
 Contracts kept:
 
-* **Bit parity** with the per-param loop for every optimizer exposing
+* **Parity** with the per-param loop for every optimizer exposing
   ``fused_update`` (SGD/momentum/multi-precision, Adam): the trace mirrors
   the executor's ``fwd_vjp`` formulation (same cotangents, same grad
   dtype casts) and the per-op update math, and consumes ONE
-  ``random.next_key()`` per step like ``Executor.forward``.
+  ``random.next_key()`` per step like ``Executor.forward``.  The update
+  math is bit for bit the per-op math.  A whole step agrees with the
+  loop's bit for bit in its outputs and in the last layer only: the
+  loop compiles forward and backward as two programs, this step as one,
+  and XLA then sums the backward products of the layers below the last
+  in another order (a few float32 spacings a step on the CPU;
+  ``tests/test_fused_step.py::test_fused_parity_with_the_loop`` states
+  the bounds).  The scanned window IS bit-equal to K of these steps, and
+  the mesh window to the per-param kvstore loop (their tests).
 * **Views stay consistent**: after a step the module's ``arg_dict`` /
   ``aux_dict`` NDArrays hold the new buffers, ``grad_dict`` reads as
   zeros (write-mode semantics, served from cached zero buffers — no
@@ -434,7 +442,17 @@ class ScanTrainStep(FusedTrainStep):
 
     Host control (metric flush, callbacks, checkpoint triggers, watchdog
     beats) happens only at window boundaries — the fit loop owns that
-    contract (module._fit_epoch_scan)."""
+    contract (module._fit_epoch_scan).  What keeps the window equal to K
+    sequential steps: ``Optimizer.fused_window_hyperparams`` bumps the
+    update counts and evaluates lr/wd for all K steps up front, and they
+    enter as ``(K, P)`` scanned inputs, never trace constants, so a
+    schedule advances inside the window and never retraces; a
+    checkpoint trigger aimed at a mid-window batch runs at the boundary
+    with the boundary's ``num_update``; ``MXNET_METRIC_SYNC_INTERVAL``
+    rounds up to boundaries; with ``accum`` the module's
+    ``rescale_grad`` divides by the effective batch; and, because up to
+    K*M batches are held before they are staged, the iterator must hand
+    out fresh arrays per batch (``NDArrayIter`` does)."""
 
     def __init__(self, module, scan_steps, accum=1):
         super().__init__(module)

@@ -126,10 +126,9 @@ def stage_batch(batch, ctx):
 
 def make_batch_stager(ctx):
     """A ``batch -> staged batch`` callable for the fit loop's input
-    double-buffer, or None when staging is off (MXNET_FIT_STAGE_NEXT=0).
+    double-buffer, or None for a module type that has no context.
     A context that denotes no device raises at the first staged batch."""
-    from . import config as _config
-    if ctx is None or not _config.get("MXNET_FIT_STAGE_NEXT"):
+    if ctx is None:
         return None
     return lambda batch: stage_batch(batch, ctx)
 

@@ -290,10 +290,9 @@ class DataPipeline(DataIter):
     """Multi-worker streaming iterator over a :class:`ShardSource`.
 
     ``workers=0`` reads the same seeded shard order serially on the
-    calling thread — the bitwise-identical baseline (and the bench
-    phase's serial-loop comparator).  ``workers>0`` runs the reader
-    pool described in the module docstring; the delivered sequence is
-    identical in both modes."""
+    calling thread — the bitwise-identical baseline.  ``workers>0`` runs
+    the reader pool described in the module docstring; the delivered
+    sequence is identical in both modes."""
 
     def __init__(self, source, workers=None, queue_depth=None, seed=None,
                  num_parts=1, part_index=0, max_inflight=None):

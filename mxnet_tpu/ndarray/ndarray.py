@@ -638,7 +638,7 @@ def invoke(op, inputs, attrs, out=None):
     outs = (outs,) if single else tuple(outs)
 
     if not isinstance(outs[0], jax.core.Tracer):
-        # dispatches-per-step lane (docs/perf_notes.md): one eager op =
+        # dispatches-per-step lane (profiler.record_dispatch): one eager op =
         # one XLA computation launch; traced calls are someone else's
         from .. import profiler as _prof
         _prof.record_dispatch("op")

@@ -33,8 +33,7 @@ final weights of a run that *planned* to shrink dp/2 at that boundary.
 
 ``python -m mxnet_tpu.parallel.elastic`` is the CI smoke (2 subprocess
 hosts × 4 fake CPU devices each, kill-and-recover + parity + dispatch
-budget); ``--bench-json`` emits the ``multihost_dispatches_per_step`` /
-``multihost_recovery_s`` / compression-ratio phases for bench.py.
+budget).
 """
 from __future__ import annotations
 
@@ -228,7 +227,6 @@ class ElasticLauncher:
                  max_restarts=None, respawn="survivors",
                  peer_timeout_s=2.0, env_extra=None, rank_env=None,
                  gen_timeout_s=300.0, exit_deadline_s=None,
-                 sigterm_rank=None, sigterm_at_step=0,
                  postmortem_dir=None):
         from .. import config as _config
         from ..kvstore_server import KVServer
@@ -275,11 +273,6 @@ class ElasticLauncher:
         self.postmortems = []   # bundle paths, in generation order
         if postmortem_dir:
             os.makedirs(postmortem_dir, exist_ok=True)
-        # optional preemption injection: SIGTERM `sigterm_rank` of
-        # generation 0 once training progress reaches sigterm_at_step
-        self.sigterm_rank = sigterm_rank
-        self.sigterm_at_step = int(sigterm_at_step)
-        self._sigterm_time = None
 
     # -- child management ---------------------------------------------------
     def _child_env(self, generation, world, rank, coord_port):
@@ -346,16 +339,6 @@ class ElasticLauncher:
         deadline = time.monotonic() + self.gen_timeout_s
         fault_at = None
         while time.monotonic() < deadline:
-            if (generation == 0 and self.sigterm_rank is not None
-                    and self._sigterm_time is None
-                    and self._max_progress() >= self.sigterm_at_step):
-                victim = procs[self.sigterm_rank]
-                if victim.poll() is None:
-                    log.warning("elastic: delivering SIGTERM to rank "
-                                "%d (pid %d)", self.sigterm_rank,
-                                victim.pid)
-                    victim.terminate()
-                self._sigterm_time = time.monotonic()
             codes = [p.poll() for p in procs]
             if all(c is not None for c in codes):
                 return codes, fault_at
@@ -539,9 +522,7 @@ class ElasticLauncher:
                     f"{self.history}")
             restores.inc(labels={"role": "launcher"})
             mark = self._max_progress()
-            t0 = (self._sigterm_time if self._sigterm_time is not None
-                  else fault_at if fault_at is not None
-                  else time.monotonic())
+            t0 = fault_at if fault_at is not None else time.monotonic()
             pending_recovery = (t0, mark)
             world = self._next_world(codes)
             generation += 1
@@ -554,7 +535,7 @@ class ElasticLauncher:
         self.server._stop.set()
 
 
-# -- worker main + smoke/bench -----------------------------------------------
+# -- worker main + smoke -----------------------------------------------------
 # The worker trains the same seeded MLP as the chaos mesh scenarios:
 # deterministic data, boundary checkpoints every window, resumable from
 # the latest committed step — the elastic continuation is bit-comparable
@@ -691,9 +672,7 @@ def _worker_main(argv):
 
 
 def _launch(workdir, world, n_batches, batch, K, rank_env=None,
-            env_extra=None, leave_at=0, peer_timeout_s=2.0,
-            respawn="survivors", devices_per_proc=4,
-            sigterm_rank=None, sigterm_at_step=0):
+            leave_at=0):
     """One elastic training job; returns (summary, per-rank payloads of
     the FINAL generation, launcher)."""
     os.makedirs(workdir, exist_ok=True)
@@ -707,13 +686,10 @@ def _launch(workdir, world, n_batches, batch, K, rank_env=None,
             a.append(str(leave_at))
         return a
 
-    env = {"MXNET_SCAN_STEPS": str(K), "MXNET_MESH_FUSED_STEP": "1"}
-    env.update(env_extra or {})
     launcher = ElasticLauncher(
-        argv, world, devices_per_proc=devices_per_proc,
-        rank_env=rank_env or {}, env_extra=env,
-        peer_timeout_s=peer_timeout_s, respawn=respawn,
-        sigterm_rank=sigterm_rank, sigterm_at_step=sigterm_at_step,
+        argv, world, rank_env=rank_env or {},
+        env_extra={"MXNET_SCAN_STEPS": str(K),
+                   "MXNET_MESH_FUSED_STEP": "1"},
         gen_timeout_s=120.0,
         postmortem_dir=os.path.join(workdir, "postmortem"))
     try:
@@ -849,69 +825,8 @@ def _smoke():
         shutil.rmtree(base, ignore_errors=True)
 
 
-def _bench_json():
-    """CPU-only bench phases (one JSON line on stdout):
-
-    * ``multihost_dispatches_per_step`` — a clean 2-process × 4-device
-      elastic run at K=BENCH_MULTIHOST_K: per-process dispatches/step
-      gate <= (1+eps)/K.
-    * ``multihost_recovery_s`` — SIGTERM one host mid-run; wall time
-      from the preemption notice to the respawned world advancing
-      training progress.
-    * ``collective_compression_ratio_2bit`` — dense vs 2-bit wire
-      bytes on the same model (gate >= 3x).
-    """
-    import shutil
-    import tempfile
-
-    base = tempfile.mkdtemp(prefix="mx-elastic-bench-")
-    K = max(2, int(os.environ.get("BENCH_MULTIHOST_K", 8)))
-    NB, BS = 4 * K, 32
-    try:
-        # phase 1: clean run, dispatch budget
-        s1, p1, _l = _launch(os.path.join(base, "clean"), 2, NB, BS, K)
-        fin = next(p for p in p1.values() if p.get("finished"))
-        disp = fin["dispatch_counts"].get("total", 0) / NB
-
-        # phase 2: a REAL SIGTERM to rank 1 once training progress
-        # reaches the first window boundary; recovery = SIGTERM
-        # delivery -> respawned world advances training progress
-        s2, _p2, _l2 = _launch(os.path.join(base, "preempt"), 2,
-                               NB, BS, K, sigterm_rank=1,
-                               sigterm_at_step=K)
-        recovery = (s2.get("recovery_s") or [float("nan")])[0]
-
-        # phase 3: compression wire-byte ratio (single process, dp=8
-        # in-process mesh: the byte accounting is host arithmetic)
-        dense = next((v for kname, v in
-                      fin["collective_bytes"].items()
-                      if kname == "psum"), 0)
-        sc, pc, _lc = _launch(
-            os.path.join(base, "comp"), 2, NB, BS, K,
-            env_extra={"MXNET_COLLECTIVE_COMPRESSION": "2bit"})
-        finc = next(p for p in pc.values() if p.get("finished"))
-        comp = next((v for kname, v in
-                     finc["collective_bytes"].items()
-                     if kname == "all_gather_q2bit"), 0)
-        ratio = (dense / comp) if comp else float("nan")
-        print(json.dumps({
-            "multihost_dispatches_per_step": round(disp, 4),
-            "budget": round((1 + 0.25) / K, 4),
-            "k": K, "world": 2, "steps": NB,
-            "multihost_recovery_s": round(recovery, 2),
-            "recovery_budget_s": 60.0,
-            "collective_compression_ratio_2bit": round(ratio, 2),
-            "compression_budget_x": 3.0,
-            "restarts": s2.get("restarts"),
-        }))
-    finally:
-        shutil.rmtree(base, ignore_errors=True)
-
-
 if __name__ == "__main__":
     if "--worker" in sys.argv:
         _worker_main(sys.argv[sys.argv.index("--worker") + 1:])
-    elif "--bench-json" in sys.argv:
-        _bench_json()
     else:
         _smoke()

@@ -54,9 +54,7 @@ kvstore shrinks to init/broadcast + optimizer-state fetch, and the
 per-step push/pull loop dies on the hot path.  Opt-out:
 ``MXNET_MESH_FUSED_STEP=0``.  ``python -m mxnet_tpu.parallel.fused`` is
 the CI smoke (8-fake-device dp×tp fit: dispatch budget + bitwise parity
-vs the per-param kvstore loop); ``--bench-json`` emits the
-``multichip_dispatches_per_step`` / ``multichip_comm_blocking_pct``
-phases for bench.py.
+vs the per-param kvstore loop).
 """
 from __future__ import annotations
 
@@ -417,7 +415,7 @@ class MeshFusedTrainStep(ScanTrainStep):
         # numerics observatory (ISSUE 14): stats need the globally
         # REDUCED gradient, so the mesh sentinel arms only where the
         # reduced pytree exists in-trace — the replicated layout with
-        # collectives on (fsdp shards the sum; comm off is a bench lie)
+        # collectives on (fsdp shards the sum; comm off computes nothing true)
         self._num_mode = _numerics.trace_mode()
         if self._num_mode != "off" and not (comm_on and
                                             layout == "replicated"):
@@ -813,7 +811,7 @@ class MeshFusedTrainStep(ScanTrainStep):
             st.add("step_dispatch", -share)
 
 
-# -- CI smoke / bench --------------------------------------------------------
+# -- CI smoke ----------------------------------------------------------------
 def _mesh_models():
     import mxnet_tpu as mx
 
@@ -833,14 +831,11 @@ def _mesh_models():
 
 
 def _run_mesh_fit(K, NB, BS, opt_name, opt_params, build, init, x, y,
-                  dp=2, tp=2, comm_mode=None, warm=False):
-    """Module.fit routed through the mesh fused window path; returns
-    (params, updater_states, dispatch_counts, wall_s_per_step, module).
-
-    ``warm=False`` (parity runs) fits exactly ONCE from ``init`` so the
-    result is step-for-step comparable to an NB-step reference loop;
-    ``warm=True`` (timing runs) fits a throwaway epoch first so the
-    measured epoch excludes trace+compile."""
+                  dp=2, tp=2):
+    """Module.fit routed through the mesh fused window path, ONE epoch
+    from ``init`` so the result is step-for-step comparable to an
+    NB-step reference loop; returns (params, updater_states,
+    dispatch_counts, module)."""
     import os
 
     import mxnet_tpu as mx
@@ -848,8 +843,6 @@ def _run_mesh_fit(K, NB, BS, opt_name, opt_params, build, init, x, y,
 
     os.environ["MXNET_MESH_FUSED_STEP"] = "1"
     os.environ["MXNET_SCAN_STEPS"] = str(K)
-    if comm_mode is not None:
-        os.environ["MXNET_COLLECTIVE_MODE"] = comm_mode
     mx.random.seed(0)
     from .mesh import make_mesh
     mesh = make_mesh(dp=dp, tp=tp)
@@ -857,26 +850,17 @@ def _run_mesh_fit(K, NB, BS, opt_name, opt_params, build, init, x, y,
                           label_name="softmax_label")
     mod = mx.mod.Module(build(), context=mx.cpu())
     with mesh:
-        if warm:
-            mod.fit(it, num_epoch=1, optimizer=opt_name,
-                    optimizer_params=opt_params,
-                    kvstore="dist_device_sync",
-                    arg_params={k: v.copy() for k, v in init.items()})
-            it.reset()
         _prof.reset_dispatch_counts()
-        t0 = time.perf_counter()
         mod.fit(it, num_epoch=1, optimizer=opt_name,
                 optimizer_params=opt_params, kvstore="dist_device_sync",
-                arg_params=None if warm else
-                {k: v.copy() for k, v in init.items()})
-        wall = (time.perf_counter() - t0) / NB
+                arg_params={k: v.copy() for k, v in init.items()})
         assert mod._mesh is not None, "mesh fused path did not engage"
     counts = _prof.dispatch_counts()
     params, _ = mod.get_params()
     states = {i: mod._updater.states[i]
               for i in range(len(mod._param_names))}
     return ({k: v.asnumpy() for k, v in params.items()},
-            states, counts, wall, mod)
+            states, counts, mod)
 
 
 def _run_kv_loop(NB, BS, n_shards, opt_name, opt_params, build, init,
@@ -971,7 +955,7 @@ def _smoke():
     x = rng.randn(NB * BS, 50).astype(np.float32)
     y = rng.randint(0, 10, NB * BS).astype(np.float32)
 
-    p_mesh, s_mesh, counts, _wall, _mod = _run_mesh_fit(
+    p_mesh, s_mesh, counts, _mod = _run_mesh_fit(
         K, NB, BS, "sgd", {"learning_rate": 0.1, "momentum": 0.9},
         build, init, x, y)
     p_loop, s_loop = _run_kv_loop(
@@ -1006,50 +990,5 @@ def _smoke():
           "per-param kvstore loop")
 
 
-def _bench_json():
-    """Emit the multichip bench phase as one JSON line (bench.py runs
-    this in a subprocess forced to 8 fake CPU devices):
-    ``multichip_dispatches_per_step`` (gate <= (1+eps)/K) and
-    ``multichip_comm_blocking_pct`` (gate <= 30: the differential
-    between the bucketed-collective window and the same window with
-    collectives compiled out isolates communication's share of step
-    wall)."""
-    import json
-    import os
-
-    _require_devices(4)
-    K = max(2, int(os.environ.get("BENCH_MULTICHIP_K", 8)))
-    NB, BS = 2 * K, 32
-    build, init, rng = _mesh_models()
-    x = rng.randn(NB * BS, 50).astype(np.float32)
-    y = rng.randint(0, 10, NB * BS).astype(np.float32)
-    opt = {"learning_rate": 0.1, "momentum": 0.9}
-
-    _p, _s, counts, wall_on, mod = _run_mesh_fit(
-        K, NB, BS, "sgd", opt, build, init, x, y, warm=True)
-    comm_est = mod._scan.comm_seconds_per_step() if mod._scan else 0.0
-    _p, _s, _c, wall_off, _m = _run_mesh_fit(
-        K, NB, BS, "sgd", opt, build, init, x, y, comm_mode="off",
-        warm=True)
-    os.environ["MXNET_COLLECTIVE_MODE"] = "bucketed"
-    blocking = max(0.0, 1.0 - wall_off / wall_on) if wall_on else 0.0
-    print(json.dumps({
-        "multichip_dispatches_per_step":
-            round(counts.get("total", 0) / NB, 4),
-        "budget": round((1 + 0.25) / K, 4),
-        "k": K, "mesh": "dp=2,tp=2", "steps": NB,
-        "multichip_comm_blocking_pct": round(blocking * 100.0, 2),
-        "blocking_budget_pct": 30.0,
-        "step_ms": round(wall_on * 1e3, 3),
-        "step_ms_comm_off": round(wall_off * 1e3, 3),
-        "comm_standalone_ms_per_step": round(comm_est * 1e3, 4),
-    }))
-
-
 if __name__ == "__main__":
-    import sys
-
-    if "--bench-json" in sys.argv:
-        _bench_json()
-    else:
-        _smoke()
+    _smoke()

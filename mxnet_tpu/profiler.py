@@ -232,7 +232,7 @@ def record_counter(name, value, args_key="value"):
     ProfileCounter).  Module-level entry point so subsystems (serving
     metrics, checkpoint, storage, …) can emit counters without holding a
     Domain/Counter object.  The last value per counter is always kept
-    (``last_counters()``) so bench/monitoring can read e.g.
+    (``last_counters()``) so monitoring can read e.g.
     ``checkpoint:save_blocking_ms`` without a running trace; trace
     events are only appended while the profiler runs."""
     with _records_lock:
@@ -254,8 +254,8 @@ def record_dispatch(kind="op"):
     """Count one framework-issued XLA computation launch (an eager op
     ``invoke``, a compiled executor forward/backward, a fused train
     step).  Unlike trace events these are counted even while the
-    profiler is stopped, so bench/CI can measure dispatches-per-step
-    (docs/perf_notes.md "dispatch overhead") without arming a trace.
+    profiler is stopped, so tests and the CI smokes can count
+    dispatches per step (fused_step.py's budget) without arming a trace.
     Host<->device transfers are deliberately NOT counted — they overlap
     compute under PJRT; this lane measures computation launches."""
     with _records_lock:

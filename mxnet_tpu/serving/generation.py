@@ -96,8 +96,8 @@ class GenerationModel:
 
     ``jit=True`` wraps both functions in ``jax.jit`` (the serving
     configuration); ``jit=False`` runs them as plain host callables —
-    the CPU-only configuration bench.py's per-token-cost runner
-    uses, so the machinery gate never depends on device timing.
+    the CPU-only configuration the tests' per-token-cost engines use,
+    so the machinery gates never depend on device timing.
     """
 
     def __init__(self, params, prefill_fn, decode_fn, init_arena_fn,
@@ -127,11 +127,11 @@ def _np_softmax(x):
 
 def tiny_lm(vocab=32, d_model=16, max_len=256, seed=0, jit=True,
             eos_id=None, per_token_cost_s=0.0):
-    """A deterministic single-layer-attention LM for tests, smokes and
-    benches.  ``jit=True`` builds jax functions (the serving config);
+    """A deterministic single-layer-attention LM for tests and smokes.
+    ``jit=True`` builds jax functions (the serving config);
     ``jit=False`` builds numpy twins — same math, pure host — plus an
-    optional ``per_token_cost_s`` busy-wait so bench.py can model a
-    fixed per-token device cost without any device in the loop."""
+    optional ``per_token_cost_s`` sleep so a test can model a fixed
+    per-token device cost without any device in the loop."""
     rng = np.random.RandomState(seed)
     scale = 1.0 / np.sqrt(d_model)
     params = {

@@ -85,21 +85,12 @@ class ServingMetrics:
 
     def drain_observations(self, key):
         """Return AND clear one named reservoir (windowed percentile
-        measurement, like :meth:`drain_latencies`)."""
+        measurement)."""
         with self._lock:
             res = self._reservoirs.get(key)
             out = list(res) if res else []
             if res:
                 res.clear()
-        return out
-
-    def drain_latencies(self):
-        """Return AND clear the latency reservoir — windowed percentile
-        measurement (the bench spike phase compares the p99 of disjoint
-        steady/spike windows on one live pool)."""
-        with self._lock:
-            out = list(self._latencies_ms)
-            self._latencies_ms.clear()
         return out
 
     def observe_batch(self, n_real, n_slots):

@@ -92,7 +92,7 @@ class AdmissionController:
     starts; no hand-tuned watermark tracks the model's speed.
     Prediction leads measurement: the request that WOULD have blown the
     p99 is shed before it queues, which is what keeps the spike p99
-    bounded (bench gate ``serve_spike_p99_ms``).
+    bounded.
     """
 
     # ignore samples shorter than this (rate estimates from sub-20ms

@@ -41,7 +41,7 @@ lost ranks' stale alerts tagged.
 
 ``MXNET_ALERTS=<seconds>`` arms a daemon evaluation thread at that
 interval; the disabled module-level :func:`tick` is one global check
-(< 1 µs, bench-gated like span/trace/failpoint).
+(< 1 µs, like a disabled span, trace hook or failpoint).
 """
 from __future__ import annotations
 

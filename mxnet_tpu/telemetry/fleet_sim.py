@@ -15,7 +15,7 @@ in-process with virtualized time, so a 1000-rank, 50-push-cycle run
 completes in seconds on a laptop.
 
 The report is machine-readable (``--json``) and the simulator IS the
-gate (bench.py ``BENCH_FLEET`` and the CI smoke call it):
+gate (the CI smoke calls it):
 
 * ``merge_p99_ms``  — per-push leader merge cost, p99 < 1 ms;
 * ``rollup_ms``     — summary rollup at scrape, max < 50 ms;

@@ -266,7 +266,7 @@ def slope_bytes_per_s(points):
 class HostSampler:
     """Sliding-window host resource sampler.  ``sample_now()`` is also
     callable directly (the collector takes one on-demand sample when no
-    thread is running, and the bench phase times it)."""
+    thread is running)."""
 
     def __init__(self, window=240):
         self._lock = threading.Lock()

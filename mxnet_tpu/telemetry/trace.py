@@ -31,8 +31,7 @@ read stage by stage.
 Disabled (``MXNET_TRACE`` unset, the default) :func:`start` is one
 module-global check returning the shared :data:`NULL_TRACE`, whose
 methods are allocation-free no-ops — the same < 1 µs bar as a disabled
-telemetry span / chaos failpoint (test-asserted, bench-tracked by
-``trace_disabled_overhead_ns``).
+telemetry span / chaos failpoint (test-asserted).
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
 """chip_smoke.py and the no-silent-fallback rules around it, on the CPU.
 
-Tier-1 part (a few seconds): the smoke and bench.py refuse to run
-without a TPU, ``mx.tpu(0)`` raises where there is no chip, the compile
+Tier-1 part (a few seconds): the smoke refuses to run without a TPU,
+the one table of peaks refuses a device it does not know,
+``mx.tpu(0)`` raises where there is no chip, the compile
 cache resolves to the directory placed from outside.  The ``slow`` part
 is the dry drive the on-chip-measurement guide asks for before chip time
 is spent: the smoke's legs, imported as functions and given ``mx.cpu()``,
@@ -22,39 +23,32 @@ from mxnet_tpu.base import MXNetError
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _load(name):
+def _load(name, *where):
     spec = importlib.util.spec_from_file_location(
-        name, os.path.join(_REPO, f"{name}.py"))
+        name, os.path.join(_REPO, *where, f"{name}.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-def test_smoke_and_bench_fail_without_a_tpu():
+def test_smoke_fails_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)  # one host device: fastest start
-    procs = {script: subprocess.Popen(
-        [sys.executable, os.path.join(_REPO, script)], env=env, cwd=_REPO,
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-        for script in ("chip_smoke.py", "bench.py")}
-    done = {script: p.communicate(timeout=120) + (p.returncode,)
-            for script, p in procs.items()}
-    out, err, rc = done["chip_smoke.py"]
-    assert rc != 0
-    assert "platform 'cpu'" in err, err[-500:]
+    done = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "chip_smoke.py")], env=env,
+        cwd=_REPO, capture_output=True, text=True, timeout=120)
+    assert done.returncode != 0
+    assert "platform 'cpu'" in done.stderr, done.stderr[-500:]
     # names what it found, prints no result line
-    assert "platform=cpu" in out and '"ok"' not in out
-    out, err, rc = done["bench.py"]
-    assert rc != 0
-    assert "platform 'cpu'" in err, err[-500:]
-    assert out.strip() == "", "a phase printed a number first"
+    assert "platform=cpu" in done.stdout and '"ok"' not in done.stdout
 
 
-def test_bench_refuses_an_unknown_device_kind():
-    bench = _load("bench")
-    assert bench.peak_flops_for("TPU v5 lite")[0] == 197e12
-    with pytest.raises(ValueError, match="TPU v9000"):
-        bench.peak_flops_for("TPU v9000")
+def test_the_peak_table_refuses_an_unknown_device_kind():
+    """chip_smoke.py's clock leg and the benchmark's MFU read one table."""
+    benchcore = _load("benchcore", "benchmark", "harness")
+    assert benchcore.peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(benchcore.BenchFailure, match="TPU v9000"):
+        benchcore.peak_flops("TPU v9000")
 
 
 def test_tpu_context_raises_without_a_chip():

@@ -1,11 +1,13 @@
 """Fused train step: one donated XLA computation per step.
 
-Covers the contracts from the dispatch-overhead PR (docs/perf_notes.md):
+Covers the contracts in ``mxnet_tpu/fused_step.py``'s docstring:
 
-* numerical parity — the fused forward+VJP+update program is BIT-identical
-  to the per-param dispatch loop over >= 10 steps for SGD, SGD-momentum
-  and Adam (fp32), and for multi-precision SGD at the optimizer level
-  (fp16 weights + fp32 master copies);
+* numerical parity with the per-param dispatch loop for SGD,
+  SGD-momentum and Adam (fp32): bit for bit after one step in
+  everything but the first layer, within 4 spacings there, within 5e-6
+  after ten steps (``test_fused_parity_with_the_loop`` has the cause);
+  bit for bit for multi-precision SGD at the optimizer level (fp16
+  weights + fp32 master copies);
 * donation safety — old weight buffers are actually donated (deleted)
   after a step, while externally-held arrays are defensively copied and
   survive;
@@ -96,32 +98,83 @@ def _run_steps(mod, batch, steps):
     return {k: v.asnumpy() for k, v in params.items()}, outs
 
 
+def _fused_and_loop(monkeypatch, optimizer, opt_params, steps):
+    """The same ``steps`` train steps through the fused step and through
+    the per-param loop: (params, outputs per step, optimizer state) of
+    each."""
+    batch = _data()
+    runs = []
+    for fused in ("1", "0"):
+        monkeypatch.setenv("MXNET_FUSED_STEP", fused)
+        prof.reset_dispatch_counts()
+        mod = _make_module(optimizer, dict(opt_params))
+        params, outs = _run_steps(mod, batch, steps)
+        assert bool(prof.dispatch_counts().get("fused_step")) == \
+            (fused == "1"), "the wrong path engaged"
+        runs.append((params, outs, _opt_state_leaves(mod)))
+    return runs
+
+
+def _ulps(a, b):
+    """max|a - b| in units of the float32 spacing at ``b``'s largest
+    magnitude."""
+    return float(np.abs(a - b).max() / np.spacing(np.abs(b).max()))
+
+
+def _rel(a, b):
+    return float(np.abs(a - b).max() / np.abs(b).max())
+
+
+_LAST_LAYER = {"fc2_weight": 2, "fc2_bias": 3}     # name -> state index
+_FIRST_LAYER = {"fc1_weight": 0, "fc1_bias": 1}
+
+
 @pytest.mark.parametrize("optimizer,opt_params", [
     ("sgd", {"learning_rate": 0.05}),
     ("sgd", {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}),
     ("adam", {"learning_rate": 0.01, "wd": 1e-4}),
 ])
-def test_fused_parity_bitwise(monkeypatch, optimizer, opt_params):
-    """Fused step == per-param loop bit for bit over 10 steps, including
-    outputs every step and the optimizer state at the end."""
-    batch = _data()
-    monkeypatch.setenv("MXNET_FUSED_STEP", "1")
-    mf = _make_module(optimizer, dict(opt_params))
-    pf, of = _run_steps(mf, batch, 10)
-    assert prof.dispatch_counts().get("fused_step"), \
-        "fused path did not engage"
-    monkeypatch.setenv("MXNET_FUSED_STEP", "0")
-    ml = _make_module(optimizer, dict(opt_params))
-    pl, ol = _run_steps(ml, batch, 10)
-    for k in pf:
-        assert np.array_equal(pf[k], pl[k]), f"param {k} diverged"
+def test_fused_parity_with_the_loop(monkeypatch, optimizer, opt_params):
+    """The per-param loop is the reference of every fused path; this is
+    how closely the fused step agrees with it, and why not bit for bit.
+
+    The loop runs forward and backward as TWO XLA programs (the vjp's
+    residuals cross between them), the fused step as ONE.  Compiled as
+    one, the CPU backend sums the first layer's backward products in
+    another order: the cotangent that crosses the activation through
+    ``fc2_weight`` (sums of 10) and, from it, ``fc1``'s gradients.  A
+    jit of forward + vjp alone, with no optimizer in it, differs from
+    the loop's gradients in exactly the same two tensors, so the update
+    math is not the cause.  Everything that does not depend on that
+    cotangent is bit-equal after one step: the outputs, the last
+    layer's weights and its optimizer state.  The first layer's differ
+    by at most 2 float32 spacings of the tensor's largest element
+    (measured 0.25-2.0; bound 4).  Ten steps feed the difference back
+    through the weights: measured 9e-8 to 4.7e-7 of the largest element
+    (Adam the most, it divides by a root), bound 5e-6.
+    """
+    (pf, of, sf), (pl, ol, sl) = _fused_and_loop(
+        monkeypatch, optimizer, opt_params, 1)
+    assert np.array_equal(of[0], ol[0]), "step-1 outputs diverged"
+    for name, idx in _LAST_LAYER.items():
+        assert np.array_equal(pf[name], pl[name]), f"{name} diverged"
+        for a, b in zip(sf[idx], sl[idx]):
+            assert np.array_equal(a, b), f"state of {name} diverged"
+    for name, idx in _FIRST_LAYER.items():
+        assert _ulps(pf[name], pl[name]) <= 4, name
+        for a, b in zip(sf[idx], sl[idx]):
+            assert _ulps(a, b) <= 4, f"state of {name}"
+
+    (pf, of, sf), (pl, ol, sl) = _fused_and_loop(
+        monkeypatch, optimizer, opt_params, 10)
+    for name in pf:
+        assert _rel(pf[name], pl[name]) <= 5e-6, name
     for a, b in zip(of, ol):
-        assert np.array_equal(a, b), "outputs diverged"
-    # optimizer state (momenta / adam moments) must match too
-    sf, sl = _opt_state_leaves(mf), _opt_state_leaves(ml)
+        assert _rel(a, b) <= 5e-6, "outputs diverged"
+    assert sf.keys() == sl.keys()
     for i in sf:
         for a, b in zip(sf[i], sl[i]):
-            assert np.array_equal(a, b), f"optimizer state {i} diverged"
+            assert _rel(a, b) <= 5e-6, f"optimizer state {i}"
 
 
 def test_fused_parity_multi_precision():

@@ -403,7 +403,7 @@ def test_mesh_fit_reference_bitwise_and_counts(monkeypatch):
     for m in ("off", "reference"):
         monkeypatch.setenv("MXNET_KERNELS", m)
         kernels.reset_for_tests()
-        params, _s, counts, _w, _mod = F._run_mesh_fit(
+        params, _s, counts, _mod = F._run_mesh_fit(
             K, NB, BS, "sgd", {"learning_rate": 0.1},
             build, {k: v.copy() for k, v in init.items()}, x, y)
         assert counts.get("mesh_window", 0) == NB // K, (m, counts)

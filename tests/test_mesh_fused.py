@@ -122,7 +122,7 @@ def test_mesh_fit_bitwise_parity_10_steps(opt_name, opt_params):
     build, init, _rng = F._mesh_models()
     K, NB, BS = 5, 10, 16
     x, y = _data(NB, BS)
-    p_mesh, s_mesh, counts, _w, mod = F._run_mesh_fit(
+    p_mesh, s_mesh, counts, mod = F._run_mesh_fit(
         K, NB, BS, opt_name, opt_params, build, init, x, y)
     assert counts.get("mesh_window", 0) == NB // K
     assert counts.get("total", 0) <= NB // K + 1
@@ -144,7 +144,7 @@ def test_mesh_fit_multi_bucket_dispatch_budget():
     build, init, _rng = F._mesh_models()
     K, NB, BS = 4, 8, 16
     x, y = _data(NB, BS)
-    p_mesh, _s, counts, _w, mod = F._run_mesh_fit(
+    p_mesh, _s, counts, mod = F._run_mesh_fit(
         K, NB, BS, "sgd", {"learning_rate": 0.1, "momentum": 0.9},
         build, init, x, y)
     assert len(mod._scan._plan) > 1  # the budget actually split
@@ -295,7 +295,7 @@ def test_mesh_fallback_then_plain_forward():
     build, init, _rng = F._mesh_models()
     K, NB, BS = 2, 4, 16
     x, y = _data(NB, BS)
-    p_mesh, _s, _c, _w, mod = F._run_mesh_fit(
+    p_mesh, _s, _c, mod = F._run_mesh_fit(
         K, NB, BS, "sgd", {"learning_rate": 0.1}, build, init, x, y)
     assert getattr(mod, "_mesh_arrays_live", False)
     it = mxio.NDArrayIter(mx.nd.array(x), mx.nd.array(y), batch_size=BS,
@@ -321,7 +321,7 @@ def test_mesh_comm_telemetry_families_and_lane():
         b0 = bytes_c.value(labels={"kind": "psum"})
         o0 = ops_c.value(labels={"kind": "psum"})
         T.reset_step_stats()
-        _p, _s, _c, _w, mod = F._run_mesh_fit(
+        _p, _s, _c, mod = F._run_mesh_fit(
             K, NB, BS, "sgd", {"learning_rate": 0.1}, build, init, x, y)
         plan_len = len(mod._scan._plan)
         grad_bytes = mod._scan._grad_bytes
@@ -422,13 +422,13 @@ def test_compression_2bit_shrinks_wire_bytes_and_trains():
     bts = T.REGISTRY.counter("mxnet_collective_bytes_total")
     d0 = bts.value(labels={"kind": "psum"})
     q0 = bts.value(labels={"kind": "all_gather_q2bit"})
-    p_dense, _s, _c, _w, _m = F._run_mesh_fit(
+    p_dense, _s, _c, _m = F._run_mesh_fit(
         K, NB, BS, "sgd", opt, build, init, x, y, dp=8, tp=1)
     dense = bts.value(labels={"kind": "psum"}) - d0
 
     os.environ["MXNET_COLLECTIVE_COMPRESSION"] = "2bit"
     try:
-        p_q, _s, counts, _w, mod = F._run_mesh_fit(
+        p_q, _s, counts, mod = F._run_mesh_fit(
             K, NB, BS, "sgd", opt, build, init, x, y, dp=8, tp=1)
     finally:
         os.environ.pop("MXNET_COLLECTIVE_COMPRESSION", None)
@@ -456,13 +456,13 @@ def test_compression_fp16_half_bytes_tight_tolerance():
     x = rng.randn(NB * BS, 50).astype(np.float32)
     y = rng.randint(0, 10, NB * BS).astype(np.float32)
     opt = {"learning_rate": 0.1, "momentum": 0.9}
-    p_dense, _s, _c, _w, _m = F._run_mesh_fit(
+    p_dense, _s, _c, _m = F._run_mesh_fit(
         K, NB, BS, "sgd", opt, build, init, x, y, dp=8, tp=1)
     bts = T.REGISTRY.counter("mxnet_collective_bytes_total")
     f0 = bts.value(labels={"kind": "psum_fp16"})
     os.environ["MXNET_COLLECTIVE_COMPRESSION"] = "fp16"
     try:
-        p_h, _s, _c, _w, mod = F._run_mesh_fit(
+        p_h, _s, _c, mod = F._run_mesh_fit(
             K, NB, BS, "sgd", opt, build, init, x, y, dp=8, tp=1)
     finally:
         os.environ.pop("MXNET_COLLECTIVE_COMPRESSION", None)

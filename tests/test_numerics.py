@@ -172,11 +172,11 @@ def test_armed_mesh_bitwise_parity():
     opt = {"learning_rate": 0.1, "momentum": 0.9}
     os.environ["MXNET_NUMERICS"] = "off"
     numerics.configure()
-    p_off, s_off, c_off, _w, _m = F._run_mesh_fit(
+    p_off, s_off, c_off, _m = F._run_mesh_fit(
         K, NB, BS, "sgd", opt, build, init, x, y)
     os.environ["MXNET_NUMERICS"] = "warn"
     numerics.configure()
-    p_on, s_on, c_on, _w, _m = F._run_mesh_fit(
+    p_on, s_on, c_on, _m = F._run_mesh_fit(
         K, NB, BS, "sgd", opt, build, init, x, y)
     assert c_on.get("mesh_window") == c_off.get("mesh_window") == NB // K
     assert c_on.get("total") == c_off.get("total")

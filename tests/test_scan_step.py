@@ -1,6 +1,7 @@
 """Multi-step scanned training: one donated XLA dispatch per K steps.
 
-Covers the contracts from the scan-window PR (docs/perf_notes.md):
+Covers the contracts of ``fused_step.ScanTrainStep`` and
+``Module._fit_epoch_scan``:
 
 * bitwise parity — a K-step scanned fit epoch == K sequential fused
   steps for SGD / SGD-momentum / Adam, including optimizer state and an

@@ -562,7 +562,7 @@ def test_stats_snapshot_and_config_knobs():
     desc = mx.config.describe()
     for knob in ("MXNET_SERVING_MAX_BATCH", "MXNET_SERVING_MAX_LATENCY_MS",
                  "MXNET_SERVING_QUEUE_DEPTH", "MXNET_SERVING_SHED_WATERMARK",
-                 "MXNET_SERVING_EXECUTOR_CACHE", "BENCH_SERVE"):
+                 "MXNET_SERVING_EXECUTOR_CACHE"):
         assert knob in desc
 
 
@@ -868,8 +868,7 @@ def test_wedged_replica_requests_resolve_typed_under_router():
 def test_replica_pool_throughput_scales_vs_single_batcher():
     """Replica pools exist to scale throughput: 3 replicas must beat
     one batcher by a clear margin on a service-time-dominated runner
-    (the bench gate serve_sustained_img_per_sec enforces >= 2x; this
-    in-suite bar is softer to stay timing-robust)."""
+    (a soft bar, to stay timing-robust)."""
     from mxnet_tpu.serving import ReplicaPool
 
     def factory(rid):

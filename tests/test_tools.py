@@ -138,45 +138,6 @@ def test_config_registry():
         cfg.get("MXNET_NO_SUCH_VAR")
 
 
-def test_hlo_flops_parser_canonical_lines():
-    """tools/hlo_flops.py underpins the round-5 perf conclusions; pin its
-    FLOP formulas on canonical HLO lines (both operand dialects: inline
-    shapes and bare %names resolved via the symbol table)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "hlo_flops", os.path.join(_REPO, "tools", "hlo_flops.py"))
-    hlo_flops = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(hlo_flops)
-    analyze_hlo = hlo_flops.analyze_hlo
-
-    inline = (
-        "%c = f32[8,64,56,56]{3,2,1,0} convolution("
-        "f32[8,64,56,56]{3,2,1,0} %p0, f32[64,64,3,3]{3,2,1,0} %w), "
-        "window={size=3x3 pad=1_1x1_1}, dim_labels=bf01_oi01->bf01")
-    convs, dots, notes = analyze_hlo(inline)
-    assert len(convs) == 1
-    assert convs[0]["flops"] == 2 * 8 * 64 * 56 * 56 * 64 * 9
-    assert not convs[0]["lhs_dilated"]
-    assert notes["convolution"] == 1
-
-    named = "\n".join([
-        "%a = bf16[32,2048]{1,0} parameter(0)",
-        "%b = bf16[2048,1000]{1,0} parameter(1)",
-        "%dot.7 = f32[32,1000]{1,0} dot(%a, %b), "
-        "lhs_contracting_dims={1}, rhs_contracting_dims={0}",
-    ])
-    convs, dots, _ = analyze_hlo(named)
-    assert len(dots) == 1
-    assert dots[0]["flops"] == 2 * 32 * 1000 * 2048
-
-    dilated = (
-        "%d = f32[8,56,56,256]{3,2,1,0} convolution("
-        "f32[8,28,28,512]{3,2,1,0} %x, f32[512,256,1,1]{3,2,1,0} %k), "
-        "window={size=1x1 lhs_dilate=2x2}, dim_labels=bf01_oi01->bf01")
-    convs, _, _ = analyze_hlo(dilated)
-    assert len(convs) == 1 and convs[0]["lhs_dilated"]
-
-
 def test_graftlint_json_schema_round_trips(tmp_path):
     """--json is a machine interface (schema v2): findings + parse
     errors + call_graph stats must survive a loads->dumps->loads round

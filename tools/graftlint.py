@@ -40,7 +40,7 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-DEFAULT_PATHS = ("mxnet_tpu", "tools", "bench.py", "__graft_entry__.py")
+DEFAULT_PATHS = ("mxnet_tpu", "tools", "__graft_entry__.py")
 DEFAULT_BASELINE = os.path.join("ci", "graftlint_baseline.json")
 
 
