@@ -37,7 +37,10 @@ _TRACING = threading.local()
 
 # shared executor for cached-op pullbacks: the vjp Partial is a pytree whose
 # leaves are the residual arrays, so one jit covers every (block, signature)
-# with the same residual structure
+# with the same residual structure. ``cts`` holds one cotangent per
+# user-visible output of the block and nothing else: the parameters the
+# forward mutated (BatchNorm's running statistics) left it as auxiliary
+# outputs, which have no cotangent
 _BWD_EXEC = jax.jit(lambda vjp_fn, cts: vjp_fn(cts))
 
 # thread-local: the layers a trace is to checkpoint one by one (remat_scope)
@@ -633,14 +636,18 @@ class HybridBlock(Block):
                     d._data = orig
 
         jitted = jax.jit(raw)
-        # training path: one jitted computation returning (outputs, pullback);
-        # the pullback (a jax tree_util Partial holding residuals) is executed
-        # by the shared _BWD_EXEC jit — fwd and bwd each compile exactly once
-        # per signature (parity: CachedOp caches fwd and bwd graphs,
-        # cached_op.cc:904/1128)
+        # training path: one jitted computation returning (outputs, pullback,
+        # mutated); the pullback (a jax tree_util Partial holding residuals)
+        # is executed by the shared _BWD_EXEC jit — fwd and bwd each compile
+        # exactly once per signature (parity: CachedOp caches fwd and bwd
+        # graphs, cached_op.cc:904/1128). The mutated parameters are the
+        # vjp's auxiliary outputs (parity: CachedOp never differentiates
+        # auxiliary states), so the pullback takes cotangents for the
+        # block's outputs only and keeps no residual for the statistics
         fwd_vjp_jit = jax.jit(
             lambda key, *arrays: jax.vjp(
-                lambda *a: raw(key, a[:n_params], a[n_params:]), *arrays))
+                lambda *a: raw(key, a[:n_params], a[n_params:]), *arrays,
+                has_aux=True))
         return _CachedEntry(jitted, fwd_vjp_jit, raw, out_fmt_box,
                             mutated_idx_box, param_list, ctx, arg_is_nd,
                             n_params)
@@ -727,17 +734,17 @@ class HybridBlock(Block):
             # one tape node for the whole block: compiled forward returns the
             # pullback (parity: CachedOp::Backward replays one cached graph)
             with _telemetry.span("gluon/cached_op/dispatch"):
-                (outs, mutated), vjp_fn = fwd_vjp_jit(key_arr, *arrays)
+                outs, vjp_fn, mutated = fwd_vjp_jit(key_arr, *arrays)
+                if _telemetry.enabled():
+                    _telemetry.record_cached_op_aux_outputs(len(mutated))
             results = [NDArray(o, ctx) for o in outs]
             self._apply_mutation(mutated_idx_box, param_list, mutated, ctx)
 
-            import jax.numpy as jnp
             import weakref
 
-            def vjp_user(cts, _vjp=vjp_fn, _mut=mutated):
-                cts_t = cts if isinstance(cts, tuple) else (cts,)
-                zeros_mut = tuple(jnp.zeros_like(m) for m in _mut)
-                return _BWD_EXEC(_vjp, (tuple(cts_t), zeros_mut))
+            def vjp_user(cts, _vjp=vjp_fn):
+                return _BWD_EXEC(_vjp, cts if isinstance(cts, tuple)
+                                 else (cts,))
 
             node = autograd.TapeNode(
                 f"CachedOp_{self.name}", nd_inputs,

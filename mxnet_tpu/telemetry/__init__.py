@@ -104,6 +104,12 @@ _TRAINER_UPDATE_CALLS = REGISTRY.counter(
     "updater calls made by gluon.Trainer._update (one optimizer program "
     "each, or one per aggregate_num tensors); counted only while "
     "telemetry is enabled")
+_CACHED_OP_AUX_OUTPUTS = REGISTRY.counter(
+    "mxnet_cached_op_aux_outputs_total",
+    "parameters a hybridized block's recorded forward mutated (BatchNorm's "
+    "running statistics) and returned as auxiliary outputs of its jax.vjp: "
+    "the pullback takes no cotangent for them; counted only while "
+    "telemetry is enabled")
 _DATA_WAIT = REGISTRY.histogram(
     "mxnet_data_wait_seconds",
     "train-thread time blocked waiting on the streaming data plane "
@@ -204,6 +210,13 @@ def record_step_host_args(step, stats):
 def record_trainer_update_calls(n):
     """Account the updater calls of one ``gluon.Trainer._update``."""
     count_in_span(_TRAINER_UPDATE_CALLS, n)
+
+
+def record_cached_op_aux_outputs(n):
+    """Account one recorded forward of a hybridized block from inside
+    ``gluon/cached_op/dispatch``: ``n`` mutated parameters left it as
+    auxiliary outputs (0 for a block that mutates nothing)."""
+    count_in_span(_CACHED_OP_AUX_OUTPUTS, n)
 
 
 def record_scan_window(steps):

@@ -236,6 +236,199 @@ def test_hybrid_grad_consistency():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
+class _AuxNet(gluon.HybridBlock):
+    """Convolution -> BatchNorm -> ReLU twice (``bn``) or without the
+    BatchNorm, then one dense head, or two (``heads``) returned as a pair."""
+
+    def __init__(self, bn=True, heads=1, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.body = nn.HybridSequential()
+            in_ch = 3
+            for _ in range(2):
+                self.body.add(nn.Conv2D(4, 3, padding=1, in_channels=in_ch))
+                if bn:
+                    self.body.add(nn.BatchNorm(in_channels=4))
+                self.body.add(nn.Activation("relu"))
+                in_ch = 4
+            self.body.add(nn.Flatten())
+            self.heads = nn.HybridSequential()
+            for _ in range(heads):
+                self.heads.add(nn.Dense(3, in_units=4 * 6 * 6))
+
+    def hybrid_forward(self, F, x):
+        h = self.body(x)
+        outs = tuple(head(h) for head in self.heads)
+        return outs if len(outs) > 1 else outs[0]
+
+
+def _aux_net(bn=True, heads=1, grad_req="write", like=None):
+    net = _AuxNet(bn=bn, heads=heads)
+    net.initialize(mx.initializer.Xavier())
+    if like is not None:
+        for p, q in zip(net.collect_params().values(),
+                        like.collect_params().values()):
+            p.set_data(q.data())
+    for p in net.collect_params().values():
+        if p.grad_req != "null":
+            p.grad_req = grad_req
+    return net
+
+
+def _aux_data():
+    rng = np.random.RandomState(7)
+    x = mx.nd.array(rng.randn(2, 3, 6, 6).astype(np.float32))
+    head_grad = mx.nd.array(rng.randn(2, 3).astype(np.float32))
+    return x, head_grad
+
+
+def _recorded_entry(net):
+    """The cached entry of the block's forward under ``autograd.record``."""
+    (entry,) = [e for (_sig, _train, recording), e in net._jit_cache.items()
+                if recording]
+    return entry
+
+
+def _zero_cotangent_reference(entry, x, cts):
+    """The gradients of the block's one compiled forward as the parent
+    computed them: ``jax.vjp`` over ``entry.raw`` with respect to the outputs
+    and the mutated statistics both, the statistics' cotangents explicit
+    zeros; compiled as two programs the way the block's own are."""
+    import jax
+    import jax.numpy as jnp
+    n = entry.n_params
+    arrays = [p.data(entry.ctx)._data for p in entry.param_list] + [x._data]
+    key = jax.random.PRNGKey(0)
+    fwd = jax.jit(lambda key, *arrays: jax.vjp(
+        lambda *a: entry.raw(key, a[:n], a[n:]), *arrays))
+    with autograd.train_mode():     # raw traces in the mode it is called in
+        (outs, mutated), vjp_fn = fwd(key, *arrays)
+    assert len(outs) == len(cts)
+    zeros = tuple(jnp.zeros_like(m) for m in mutated)
+    grads = jax.jit(lambda f, c: f(c))(vjp_fn, (tuple(cts), zeros))
+    return dict(zip((p.name for p in entry.param_list), grads[:n])), mutated
+
+
+@pytest.mark.parametrize("case", ["write", "add", "retain_graph",
+                                  "unused_head", "no_batchnorm"])
+def test_hybrid_aux_outputs_grads_match_zero_cotangent_reference(case):
+    """The running statistics leave the recorded forward as auxiliary outputs
+    of its vjp: same gradients, bit for bit, as differentiating them with
+    zero cotangents; same statistics as the un-hybridized block."""
+    import jax.numpy as jnp
+    bn = case != "no_batchnorm"
+    heads = 2 if case == "unused_head" else 1
+    grad_req = "add" if case == "add" else "write"
+    x, head_grad = _aux_data()
+    net = _aux_net(bn, heads, grad_req)
+    plain = _aux_net(bn, heads, grad_req, like=net)
+    net.hybridize()
+
+    def step(block):
+        with autograd.record():
+            out = block(x)
+        head = out[0] if heads > 1 else out
+        if case == "retain_graph":
+            head.backward(head_grad, retain_graph=True)
+        head.backward(head_grad)
+
+    step(net)       # builds the entry; the reference reads the state it left
+    step(plain)
+    before = {p.name: p.grad().asnumpy()
+              for p in net.collect_params().values() if p.grad_req != "null"}
+    cts = [head_grad._data] + [jnp.zeros((2, 3), jnp.float32)] * (heads - 1)
+    entry = _recorded_entry(net)
+    want, want_stats = _zero_cotangent_reference(entry, x, cts)
+    step(net)
+    step(plain)
+
+    mutated_idx = entry.mutated_idx_box[0]
+    assert len(mutated_idx) == (4 if bn else 0)
+    checked = 0
+    for p in net.collect_params().values():
+        if p.grad_req == "null":
+            continue
+        g = np.asarray(want[p.name])
+        if case == "add":
+            g = before[p.name] + g
+        np.testing.assert_array_equal(p.grad().asnumpy(), g, err_msg=p.name)
+        checked += 1
+    assert checked == (8 if bn else 4) + 2 * heads
+    # the unused head got a gradient of zeros, not none at all
+    if heads > 1:
+        unused = list(net.heads[1].collect_params().values())
+        assert all(not p.grad().asnumpy().any() for p in unused)
+    for idx, stat in zip(mutated_idx, want_stats):
+        np.testing.assert_array_equal(
+            entry.param_list[idx].data().asnumpy(), np.asarray(stat))
+    for p, q in zip(net.collect_params().values(),
+                    plain.collect_params().values()):
+        if p.grad_req == "null":
+            assert "running" in p.name
+            np.testing.assert_allclose(p.data().asnumpy(), q.data().asnumpy(),
+                                       rtol=1e-6, atol=1e-7, err_msg=p.name)
+
+
+def _backward_launches(head, head_grad, tmp_path):
+    """Names of the programs jax launched on this thread between entering
+    ``head.backward`` and its return."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        head.backward(head_grad)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    return [event.name for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for event in line.events
+            if event.name.startswith("PjitFunction(")]
+
+
+@pytest.mark.parametrize("bn", [True, False], ids=["batchnorm", "no_batchnorm"])
+def test_hybrid_backward_is_one_launch_and_counts_aux_outputs(
+        bn, tmp_path, monkeypatch):
+    """``backward()`` through a recorded hybridized block is one call of the
+    shared pullback program with a cotangent per output: no zeros are built
+    for the statistics."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.gluon import block as block_mod
+    x, head_grad = _aux_data()
+    net = _aux_net(bn)
+    net.hybridize()
+    with autograd.record():
+        net(x).backward(head_grad)      # compile both programs
+    seen = []
+    real = block_mod._BWD_EXEC
+    monkeypatch.setattr(
+        block_mod, "_BWD_EXEC",
+        lambda vjp_fn, cts: seen.append(cts) or real(vjp_fn, cts))
+    telemetry.enable()
+    telemetry.reset_span_records()
+    try:
+        net(x)                          # not recording: nothing counted
+        with autograd.record():
+            out = net(x)
+        launches = _backward_launches(out, head_grad, tmp_path)
+        records = [r["counts"] for r in telemetry.span_records()
+                   if r["name"] == "gluon/cached_op/dispatch"]
+    finally:
+        telemetry.disable()
+        telemetry.reset_span_records()
+    assert "PjitFunction(broadcast_in_dim)" not in launches, launches
+    assert launches.count("PjitFunction(<lambda>)") >= 1, launches
+    (cts,) = seen
+    assert isinstance(cts, tuple) and len(cts) == 1
+    assert cts[0] is head_grad._data
+    # two statistics a BatchNorm layer, two layers
+    assert records == [None, {"mxnet_cached_op_aux_outputs_total":
+                              4 if bn else 0}]
+
+
 def test_trainer_updates():
     net = nn.Dense(1, in_units=2)
     net.initialize(mx.initializer.Constant(0.5))
