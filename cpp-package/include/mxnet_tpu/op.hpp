@@ -465,6 +465,11 @@ inline NDArray _contrib_index_copy(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("_contrib_index_copy", inputs, attrs).Invoke();
 }
+// operator _contrib_kda_scan — inputs: q, k, v, g, beta
+inline NDArray _contrib_kda_scan(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_kda_scan", inputs, attrs).Invoke();
+}
 // operator _contrib_moe_ffn
 inline std::vector<NDArray> _contrib_moe_ffn(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
@@ -529,6 +534,11 @@ inline std::vector<NDArray> _contrib_requantize(const std::vector<NDArray>& inpu
 inline NDArray _contrib_rms_norm(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("_contrib_rms_norm", inputs, attrs).Invoke();
+}
+// operator _contrib_routed_experts — inputs: data, router_weight, w1, w3, w2
+inline std::vector<NDArray> _contrib_routed_experts(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_routed_experts", inputs, attrs).InvokeMulti();
 }
 // operator _contrib_ssd_scan — inputs: data, dt, A, B, C, D
 inline NDArray _contrib_ssd_scan(const std::vector<NDArray>& inputs,
@@ -1750,6 +1760,11 @@ inline NDArray inverse(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("inverse", inputs, attrs).Invoke();
 }
+// operator kda_scan — inputs: q, k, v, g, beta
+inline NDArray kda_scan(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("kda_scan", inputs, attrs).Invoke();
+}
 // operator khatri_rao
 inline NDArray khatri_rao(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
@@ -2169,6 +2184,11 @@ inline std::vector<NDArray> rmspropalex_update(const std::vector<NDArray>& input
 inline NDArray round(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("round", inputs, attrs).Invoke();
+}
+// operator routed_experts — inputs: data, router_weight, w1, w3, w2
+inline std::vector<NDArray> routed_experts(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("routed_experts", inputs, attrs).InvokeMulti();
 }
 // operator rsqrt
 inline NDArray rsqrt(const std::vector<NDArray>& inputs,

@@ -135,19 +135,24 @@ class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with ``num_kv_heads`` key/value heads under
     ``num_heads`` query heads, no bias and no positional encoding:
     ``softmax(q kᵀ · scale) v`` through the flash kernel
-    (op ``_contrib_flash_attention``), then the output projection."""
+    (op ``_contrib_flash_attention``), then the output projection.  With
+    ``gate`` the heads' outputs are multiplied elementwise by
+    ``sigmoid(W_g h)`` before it."""
 
     # the flash kernel's tiles: (512, 64) query rows against (512, 64)
     # keys keep the grid at 8 × 8 steps a head at 4096 positions
     BLOCK = 512
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
-                 scale, prefix=None, params=None):
+                 scale, gate=False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._scale = head_dim, float(scale)
         self._hidden = hidden_size
         with self.name_scope():
+            if gate:
+                self.g_weight = self.params.get(
+                    "g_weight", shape=(num_heads * head_dim, hidden_size))
             self.q_weight = self.params.get(
                 "q_weight", shape=(num_heads * head_dim, hidden_size))
             self.k_weight = self.params.get(
@@ -157,7 +162,8 @@ class GroupedQueryAttention(HybridBlock):
             self.o_weight = self.params.get(
                 "o_weight", shape=(hidden_size, num_heads * head_dim))
 
-    def hybrid_forward(self, F, h, q_weight, k_weight, v_weight, o_weight):
+    def hybrid_forward(self, F, h, q_weight, k_weight, v_weight, o_weight,
+                       g_weight=None):
         def heads(w, n):   # (batch, T, n·d) -> (batch, n, T, d)
             y = _dense(F, h, w, n * self._head_dim)
             return F.transpose(
@@ -171,6 +177,9 @@ class GroupedQueryAttention(HybridBlock):
                 sm_scale=self._scale, block_q=self.BLOCK, block_k=self.BLOCK)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))
+            if g_weight is not None:
+                out = out * F.sigmoid(_dense(F, h, g_weight,
+                                             self._heads * self._head_dim))
             return _dense(F, out, o_weight, self._hidden)
 
 

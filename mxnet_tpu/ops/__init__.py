@@ -18,4 +18,6 @@ from . import _op_quantization  # noqa: F401
 from . import _op_image  # noqa: F401
 from . import _op_spatial  # noqa: F401
 from . import _op_ssm  # noqa: F401
+from . import _op_linear_attention  # noqa: F401
+from . import _op_moe  # noqa: F401
 from . import pallas_attention  # noqa: F401

@@ -1,0 +1,183 @@
+"""Routed experts for one holder of a share of a mixture of experts: scores
+over ALL experts, top-k, and the gated MLPs of the experts held here for
+exactly the rows routed to them.
+
+No reference analog: MXNet 1.x has no routed layer.  ``parallel/moe.py``
+is a top-1 router with a capacity; this op is the layer a deployment with
+experts over several chips runs on each of them, without the exchange:
+
+    s = sigmoid(W_r h)  over all ``experts_total`` outputs (float32, highest)
+    top-k of s;  w_e = s_e / Σ_topk s · scaling   (``norm_topk``)
+    y = Σ_{e in top-k, first ≤ e < first + E_here} w_e · W2_e (silu(W1_e h) ⊙ W3_e h)
+
+What an absent expert would add is left out.  NOTHING IS DROPPED: the held
+assignments are sorted by expert and walked in tiles of ``tile`` rows, one
+expert a tile, as many tiles as the routing of this batch needs.  The only
+static bounds are on index arrays: a token chooses an expert at most once,
+so no routing gives more than ``N · min(top_k, E_here)`` held assignments
+or more than that over ``tile`` plus ``E_here`` tiles; the rows themselves
+are gathered a tile at a time inside the loop and never laid out at that
+bound.  The products therefore cost what the routing asks plus each
+expert's last, partly filled tile; a batch in which every token chooses one
+held expert just walks more tiles.  The backward walks the same tiles.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register
+from ..base import MXNetError
+
+
+def _plan(expert, held, n_held, tile):
+    """The tiles of one batch.  ``expert`` (A,) the local expert of every
+    assignment, ``held`` (A,) whether it lies here.  Returns ``order`` (A,)
+    assignments sorted by expert with the absent ones last, ``counts``,
+    ``first_row`` and ``first_tile`` (E_here,) of each expert's group in
+    that order, and the number of tiles."""
+    key = jnp.where(held, expert, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    tiles = (counts + tile - 1) // tile
+    return (order, counts, jnp.cumsum(counts) - counts,
+            jnp.cumsum(tiles) - tiles, jnp.sum(tiles))
+
+
+def _tile(j, plan, top_k, tile, n_tokens):
+    """Tile ``j``: its expert, the flat assignment, token and validity of
+    each of its rows.  Rows past the end of the expert's group are not
+    valid: their token index is ``n_tokens`` (out of range: scatters
+    drop it, gathers are told to clip) and their weight must be taken as 0."""
+    order, counts, first_row, first_tile, _ = plan
+    # a group with no rows shares its first tile with the next group: of
+    # the groups that start at or before j the last is the one with rows
+    e = jnp.sum(first_tile <= j) - 1
+    within = (j - first_tile[e]) * tile + jnp.arange(tile, dtype=jnp.int32)
+    valid = within < counts[e]
+    flat = order[jnp.clip(first_row[e] + within, 0, order.shape[0] - 1)]
+    token = jnp.where(valid, flat // top_k, n_tokens)
+    return e, flat, token, valid
+
+
+def _expert(x, w1, w3, w2):
+    return (jax.nn.silu(x @ w1.T) * (x @ w3.T)) @ w2.T
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _walk(h, weight, w1, w3, w2, plan, top_k, tile):
+    """``y[n] = Σ weight[a] · expert_e(a)(h[n])`` over the held assignments
+    ``a`` of token ``n``: h (N, hidden), weight (A,) per flat assignment."""
+    n = h.shape[0]
+
+    def body(state):
+        j, y = state
+        e, flat, token, valid = _tile(j, plan, top_k, tile, n)
+        out = _expert(h.at[token].get(mode="clip"), w1[e], w3[e], w2[e]) \
+            * jnp.where(valid, weight[flat], 0.0)[:, None]
+        return j + 1, y.at[token].add(out, mode="drop")
+
+    return lax.while_loop(lambda s: s[0] < plan[-1], body,
+                          (jnp.int32(0), jnp.zeros_like(h)))[1]
+
+
+def _walk_fwd(h, weight, w1, w3, w2, plan, top_k, tile):
+    return (_walk(h, weight, w1, w3, w2, plan, top_k, tile),
+            (h, weight, w1, w3, w2, plan))
+
+
+def _walk_bwd(top_k, tile, saved, dy):
+    h, weight, w1, w3, w2, plan = saved
+    n = h.shape[0]
+
+    def body(state):
+        j, dh, dweight, dw1, dw3, dw2 = state
+        e, flat, token, valid = _tile(j, plan, top_k, tile, n)
+        x = h.at[token].get(mode="clip")
+        a, b = x @ w1[e].T, x @ w3[e].T
+        s = jax.nn.silu(a)
+        mid = s * b
+        # rows that are not valid gather dy's last row: mask it
+        dout = jnp.where(valid[:, None], dy.at[token].get(mode="clip"),
+                         0.0)
+        dmid = dout @ w2[e]                                   # unweighted
+        dweight = dweight.at[jnp.where(valid, flat, weight.shape[0])].add(
+            jnp.sum(dmid * mid, axis=-1), mode="drop")
+        wt = jnp.where(valid, weight[flat], 0.0)[:, None]
+        dmid = dmid * wt
+        sig = jax.nn.sigmoid(a)
+        da = dmid * b * (sig + s * (1.0 - sig))               # silu'
+        db = dmid * s
+        dw1 = dw1.at[e].add(da.T @ x)
+        dw3 = dw3.at[e].add(db.T @ x)
+        dw2 = dw2.at[e].add(dout.T @ (mid * wt))
+        dh = dh.at[token].add(da @ w1[e] + db @ w3[e], mode="drop")
+        return j + 1, dh, dweight, dw1, dw3, dw2
+
+    out = lax.while_loop(
+        lambda s: s[0] < plan[-1], body,
+        (jnp.int32(0),) + tuple(jnp.zeros_like(v)
+                                for v in (h, weight, w1, w3, w2)))
+    return out[1:] + (None,)
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
+                   scaling=1.0, norm_topk=True, tile=256):
+    """The held experts' part of a routed layer.  h (..., hidden);
+    router_w (experts_total, hidden); w1, w3 (E_here, width, hidden); w2
+    (E_here, hidden, width); the experts held are ``first_expert ..
+    first_expert + E_here − 1`` of ``experts_total``.  Returns ``(y, load,
+    rows)``: y like h; load (E_here,) float32, the assignments each held
+    expert received; rows (1,) float32, the rows the grouped products ran,
+    every expert's last tile counted whole.  No assignment is dropped
+    whatever the imbalance (see the module's head)."""
+    total, hidden = router_w.shape
+    n_held = w1.shape[0]
+    if not (0 <= first_expert and first_expert + n_held <= total
+            and 1 <= top_k <= total and tile >= 1):
+        raise MXNetError(
+            f"routed_experts: experts {first_expert}..{first_expert + n_held}"
+            f" of {total}, top {top_k}, tile {tile}")
+    x = h.reshape(-1, hidden)
+    with jax.named_scope("routed_experts/router"):
+        # in float32 at the highest precision: near-ties among 320 scores
+        # must fall the same way wherever the product is computed
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST))
+        chosen, expert = lax.top_k(scores, top_k)
+        if norm_topk:
+            chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        weight = (chosen * scaling).astype(h.dtype).reshape(-1)
+    with jax.named_scope("routed_experts/dispatch"):
+        local = expert.reshape(-1) - first_expert
+        plan = _plan(local, (local >= 0) & (local < n_held), n_held, tile)
+    with jax.named_scope("routed_experts/experts"):
+        y = _walk(x, weight, w1, w3, w2, plan, top_k, tile)
+    load = plan[1].astype(jnp.float32)
+    rows = (plan[-1] * tile).astype(jnp.float32).reshape(1)
+    return y.reshape(h.shape), load, rows
+
+
+@register("_contrib_routed_experts", alias=("routed_experts",),
+          num_outputs=3,
+          input_names=("data", "router_weight", "w1", "w3", "w2"))
+def _routed_experts(attrs, h, router_w, w1, w3, w2):
+    total = int(attrs.get("experts_total", router_w.shape[0]))
+    if total != router_w.shape[0]:
+        raise MXNetError(f"routed_experts: the router has "
+                         f"{router_w.shape[0]} outputs, experts_total "
+                         f"{total}")
+    return routed_experts(
+        h, router_w, w1, w3, w2, int(attrs["top_k"]),
+        int(attrs.get("first_expert", 0)),
+        float(attrs.get("routed_scaling_factor", 1.0)),
+        bool(attrs.get("norm_topk_prob", True)),
+        int(attrs.get("tile", 256)))

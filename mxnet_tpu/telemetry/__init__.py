@@ -139,6 +139,21 @@ _REMAT_BOUNDARIES = REGISTRY.gauge(
     "rematerialisation boundaries (jax.checkpoint regions) the last "
     "traced parallel.spmd.TrainStep program holds: one per declared "
     "layer, 1 for a whole-forward wrap, 0 without remat")
+_MOE_ASSIGNMENTS = REGISTRY.gauge(
+    "mxnet_moe_assignments_held",
+    "(token, expert) assignments the routed-expert layers sent to the "
+    "experts held here, all layers summed, a step: the mean over the steps "
+    "last recorded from the block's auxiliary state (record_moe_load)")
+_MOE_ROWS = REGISTRY.gauge(
+    "mxnet_moe_rows_computed",
+    "rows the routed-expert layers' grouped products ran, all layers "
+    "summed, every expert's last tile counted whole, a step (the same "
+    "mean): what exceeds mxnet_moe_assignments_held is padding")
+_MOE_LOAD_SKEW = REGISTRY.gauge(
+    "mxnet_moe_expert_load_max_over_mean",
+    "the busiest held expert's assignments over the mean of the held "
+    "experts, summed over the recorded steps, in the layer where that "
+    "ratio is largest (1 = even; 0 when no layer routed anything here)")
 _COLLECTIVE_BYTES = REGISTRY.counter(
     "mxnet_collective_bytes_total",
     "logical payload bytes moved by gradient-synchronization "
@@ -228,6 +243,20 @@ def record_remat_boundaries(n):
     """Record how many rematerialisation boundaries a train step program
     was traced with (parallel.spmd.TrainStep)."""
     _REMAT_BOUNDARIES.set(int(n))
+
+
+def record_moe_load(load, rows, steps=1):
+    """Record the routed-expert load of ``steps`` steps from host copies of
+    a block's auxiliary state, which sums over them: ``load`` (layers,
+    experts held) assignments, ``rows`` (layers,) rows the grouped products
+    ran.  The two counts are set as means a step, the skew is of the sums."""
+    _MOE_ASSIGNMENTS.set(float(load.sum()) / steps)
+    _MOE_ROWS.set(float(rows.sum()) / steps)
+    mean = load.mean(axis=1)
+    busy = mean > 0
+    _MOE_LOAD_SKEW.set(
+        float((load.max(axis=1)[busy] / mean[busy]).max()) if busy.any()
+        else 0.0)
 
 
 def record_data_wait(seconds):
