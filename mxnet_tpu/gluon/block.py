@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import math
 import re
 import threading
 import warnings
@@ -21,6 +22,7 @@ import warnings
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from .. import autograd, ndarray as nd
 from .. import random as _random
@@ -35,13 +37,87 @@ from .utils import _indent
 # run their imperative path inside the parent's trace
 _TRACING = threading.local()
 
-# shared executor for cached-op pullbacks: the vjp Partial is a pytree whose
+# shared executor for cached-op pullbacks: the pullback is a pytree whose
 # leaves are the residual arrays, so one jit covers every (block, signature)
 # with the same residual structure. ``cts`` holds one cotangent per
 # user-visible output of the block and nothing else: the parameters the
 # forward mutated (BatchNorm's running statistics) left it as auxiliary
 # outputs, which have no cotangent
 _BWD_EXEC = jax.jit(lambda vjp_fn, cts: vjp_fn(cts))
+
+# a pullback residual under this many bytes crosses the host packed with the
+# others of its dtype: a program output costs the host per buffer, not per
+# byte (0.06 ms each on a v5e's host, PERF.md §6, PR 33), and two thirds of
+# a vision net's residuals are per-channel vectors and scalars
+_RESIDUAL_PACK_BYTES = 64 * 1024
+
+
+class _PackedPullback:
+    """The pullback ``jax.vjp`` returned inside the recorded forward
+    program, in the form it leaves that program: the residuals under
+    ``_RESIDUAL_PACK_BYTES`` ravelled into one flat buffer per dtype
+    (``packed``), every other one as it is (``large``).  A pytree with
+    those buffers as leaves, so the forward program returns it and
+    ``_BWD_EXEC`` takes it; its static data are the pullback's ``treedef``
+    and, leaf by leaf, where the leaf travels (``slots``): ``(None, i)`` is
+    ``large[i]``, ``(k, offset, shape)`` is ``shape`` elements of
+    ``packed[k]`` from ``offset``.  Calling it (inside ``_BWD_EXEC``'s
+    trace) slices the small leaves back out and calls the pullback."""
+
+    __slots__ = ("large", "packed", "treedef", "slots")
+
+    def __init__(self, large, packed, treedef, slots):
+        self.large = large
+        self.packed = packed
+        self.treedef = treedef
+        self.slots = slots
+
+    @classmethod
+    def pack(cls, vjp_fn):
+        leaves, treedef = jax.tree_util.tree_flatten(vjp_fn)
+        avals = [jax.typeof(leaf) for leaf in leaves]
+        by_dtype = {}
+        for i, aval in enumerate(avals):
+            dtype = aval.dtype
+            # a weakly typed leaf or an extended dtype (a random key)
+            # would not come back from a concatenate as the type it was
+            if (aval.size * dtype.itemsize < _RESIDUAL_PACK_BYTES
+                    and not aval.weak_type
+                    and (jnp.issubdtype(dtype, jnp.number)
+                         or dtype == jnp.bool_)):
+                by_dtype.setdefault(dtype, []).append(i)
+        slots = [None] * len(leaves)
+        packed = []
+        for members in by_dtype.values():
+            offset = 0
+            for i in members:
+                slots[i] = (len(packed), offset, avals[i].shape)
+                offset += avals[i].size
+            packed.append(jnp.concatenate(
+                [jnp.ravel(leaves[i]) for i in members]))
+        large = []
+        for i, leaf in enumerate(leaves):
+            if slots[i] is None:
+                slots[i] = (None, len(large))
+                large.append(leaf)
+        return cls(tuple(large), tuple(packed), treedef, tuple(slots))
+
+    def __call__(self, cts):
+        leaves = []
+        for slot in self.slots:
+            if slot[0] is None:
+                leaves.append(self.large[slot[1]])
+            else:
+                k, offset, shape = slot
+                leaves.append(self.packed[k][
+                    offset:offset + math.prod(shape)].reshape(shape))
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)(cts)
+
+
+jax.tree_util.register_pytree_node(
+    _PackedPullback,
+    lambda p: ((p.large, p.packed), (p.treedef, p.slots)),
+    lambda static, buffers: _PackedPullback(*buffers, *static))
 
 # thread-local: the layers a trace is to checkpoint one by one (remat_scope)
 _REMAT = threading.local()
@@ -637,17 +713,21 @@ class HybridBlock(Block):
 
         jitted = jax.jit(raw)
         # training path: one jitted computation returning (outputs, pullback,
-        # mutated); the pullback (a jax tree_util Partial holding residuals)
-        # is executed by the shared _BWD_EXEC jit — fwd and bwd each compile
-        # exactly once per signature (parity: CachedOp caches fwd and bwd
-        # graphs, cached_op.cc:904/1128). The mutated parameters are the
-        # vjp's auxiliary outputs (parity: CachedOp never differentiates
-        # auxiliary states), so the pullback takes cotangents for the
-        # block's outputs only and keeps no residual for the statistics
-        fwd_vjp_jit = jax.jit(
-            lambda key, *arrays: jax.vjp(
+        # mutated); the pullback (a pytree holding the residuals, the small
+        # ones packed: _PackedPullback) is executed by the shared _BWD_EXEC
+        # jit — fwd and bwd each compile exactly once per signature (parity:
+        # CachedOp caches fwd and bwd graphs, cached_op.cc:904/1128). The
+        # mutated parameters are the vjp's auxiliary outputs (parity:
+        # CachedOp never differentiates auxiliary states), so the pullback
+        # takes cotangents for the block's outputs only and keeps no
+        # residual for the statistics
+        def fwd_vjp(key, *arrays):
+            outs, vjp_fn, mutated = jax.vjp(
                 lambda *a: raw(key, a[:n_params], a[n_params:]), *arrays,
-                has_aux=True))
+                has_aux=True)
+            return outs, _PackedPullback.pack(vjp_fn), mutated
+
+        fwd_vjp_jit = jax.jit(fwd_vjp)
         return _CachedEntry(jitted, fwd_vjp_jit, raw, out_fmt_box,
                             mutated_idx_box, param_list, ctx, arg_is_nd,
                             n_params)
@@ -737,6 +817,9 @@ class HybridBlock(Block):
                 outs, vjp_fn, mutated = fwd_vjp_jit(key_arr, *arrays)
                 if _telemetry.enabled():
                     _telemetry.record_cached_op_aux_outputs(len(mutated))
+                    _telemetry.record_cached_op_residuals(
+                        len(vjp_fn.slots),
+                        len(vjp_fn.large) + len(vjp_fn.packed))
             results = [NDArray(o, ctx) for o in outs]
             self._apply_mutation(mutated_idx_box, param_list, mutated, ctx)
 

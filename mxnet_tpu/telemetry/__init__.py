@@ -110,6 +110,16 @@ _CACHED_OP_AUX_OUTPUTS = REGISTRY.counter(
     "running statistics) and returned as auxiliary outputs of its jax.vjp: "
     "the pullback takes no cotangent for them; counted only while "
     "telemetry is enabled")
+_CACHED_OP_RESIDUAL_LEAVES = REGISTRY.counter(
+    "mxnet_cached_op_residual_leaves_total",
+    "residual arrays the pullback of a hybridized block's recorded forward "
+    "holds (the leaves of its jax.vjp); counted only while telemetry is "
+    "enabled")
+_CACHED_OP_RESIDUAL_BUFFERS = REGISTRY.counter(
+    "mxnet_cached_op_residual_buffers_total",
+    "buffers those residuals crossed the host in, from the forward program "
+    "to the pullback program: one per leaf of 64 KiB or more, one per dtype "
+    "for all the smaller ones; counted only while telemetry is enabled")
 _DATA_WAIT = REGISTRY.histogram(
     "mxnet_data_wait_seconds",
     "train-thread time blocked waiting on the streaming data plane "
@@ -232,6 +242,14 @@ def record_cached_op_aux_outputs(n):
     ``gluon/cached_op/dispatch``: ``n`` mutated parameters left it as
     auxiliary outputs (0 for a block that mutates nothing)."""
     count_in_span(_CACHED_OP_AUX_OUTPUTS, n)
+
+
+def record_cached_op_residuals(leaves, buffers):
+    """Account, beside ``record_cached_op_aux_outputs``, the residuals of
+    one recorded forward: the pullback's ``leaves`` and the ``buffers``
+    they left the forward program in."""
+    count_in_span(_CACHED_OP_RESIDUAL_LEAVES, leaves)
+    count_in_span(_CACHED_OP_RESIDUAL_BUFFERS, buffers)
 
 
 def record_scan_window(steps):

@@ -238,9 +238,10 @@ def test_hybrid_grad_consistency():
 
 class _AuxNet(gluon.HybridBlock):
     """Convolution -> BatchNorm -> ReLU twice (``bn``) or without the
-    BatchNorm, then one dense head, or two (``heads``) returned as a pair."""
+    BatchNorm, then one dense head, or two (``heads``) returned as a pair;
+    for images of ``side`` x ``side``."""
 
-    def __init__(self, bn=True, heads=1, **kwargs):
+    def __init__(self, bn=True, heads=1, side=6, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.body = nn.HybridSequential()
@@ -254,7 +255,7 @@ class _AuxNet(gluon.HybridBlock):
             self.body.add(nn.Flatten())
             self.heads = nn.HybridSequential()
             for _ in range(heads):
-                self.heads.add(nn.Dense(3, in_units=4 * 6 * 6))
+                self.heads.add(nn.Dense(3, in_units=4 * side * side))
 
     def hybrid_forward(self, F, x):
         h = self.body(x)
@@ -262,8 +263,8 @@ class _AuxNet(gluon.HybridBlock):
         return outs if len(outs) > 1 else outs[0]
 
 
-def _aux_net(bn=True, heads=1, grad_req="write", like=None):
-    net = _AuxNet(bn=bn, heads=heads)
+def _aux_net(bn=True, heads=1, grad_req="write", like=None, side=6):
+    net = _AuxNet(bn=bn, heads=heads, side=side)
     net.initialize(mx.initializer.Xavier())
     if like is not None:
         for p, q in zip(net.collect_params().values(),
@@ -282,6 +283,21 @@ def _aux_data():
     return x, head_grad
 
 
+def _dispatch_counts(run):
+    """The ``counts`` of every ``gluon/cached_op/dispatch`` span that
+    ``run()`` closed, in order, with telemetry on for the call only."""
+    from mxnet_tpu import telemetry
+    telemetry.enable()
+    telemetry.reset_span_records()
+    try:
+        run()
+        return [r["counts"] for r in telemetry.span_records()
+                if r["name"] == "gluon/cached_op/dispatch"]
+    finally:
+        telemetry.disable()
+        telemetry.reset_span_records()
+
+
 def _recorded_entry(net):
     """The cached entry of the block's forward under ``autograd.record``."""
     (entry,) = [e for (_sig, _train, recording), e in net._jit_cache.items()
@@ -293,16 +309,24 @@ def _zero_cotangent_reference(entry, x, cts):
     """The gradients of the block's one compiled forward as the parent
     computed them: ``jax.vjp`` over ``entry.raw`` with respect to the outputs
     and the mutated statistics both, the statistics' cotangents explicit
-    zeros; compiled as two programs the way the block's own are."""
+    zeros; compiled as two programs the way the block's own are, the
+    pullback's small residuals packed between them as the block's own are
+    (on the CPU a residual that a consumer fuses is contracted otherwise
+    than one that is a program output: an ulp here and there)."""
     import jax
     import jax.numpy as jnp
+    from mxnet_tpu.gluon import block as block_mod
     n = entry.n_params
     arrays = [p.data(entry.ctx)._data for p in entry.param_list] + [x._data]
     key = jax.random.PRNGKey(0)
-    fwd = jax.jit(lambda key, *arrays: jax.vjp(
-        lambda *a: entry.raw(key, a[:n], a[n:]), *arrays))
+
+    def fwd(key, *arrays):
+        out, vjp_fn = jax.vjp(
+            lambda *a: entry.raw(key, a[:n], a[n:]), *arrays)
+        return out, block_mod._PackedPullback.pack(vjp_fn)
+
     with autograd.train_mode():     # raw traces in the mode it is called in
-        (outs, mutated), vjp_fn = fwd(key, *arrays)
+        (outs, mutated), vjp_fn = jax.jit(fwd)(key, *arrays)
     assert len(outs) == len(cts)
     zeros = tuple(jnp.zeros_like(m) for m in mutated)
     grads = jax.jit(lambda f, c: f(c))(vjp_fn, (tuple(cts), zeros))
@@ -369,6 +393,153 @@ def test_hybrid_aux_outputs_grads_match_zero_cotangent_reference(case):
                                        rtol=1e-6, atol=1e-7, err_msg=p.name)
 
 
+class _EmbeddingNet(gluon.HybridBlock):
+    """Embedding -> mean over the positions -> Dense: the token ids are an
+    integer residual of the gather."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.embed = nn.Embedding(50, 8)
+            self.out = nn.Dense(3, in_units=8)
+
+    def hybrid_forward(self, F, x):
+        return self.out(self.embed(x).mean(axis=1))
+
+
+def _packing_case(case):
+    """``(net, x, head_grads)`` of one case of the packing test: at 48x48 the
+    convolutions' activations are over the packing limit and the per-channel
+    leaves under it; every leaf of the perceptron and of the embedding net
+    is under it."""
+    rng = np.random.RandomState(11)
+    if case == "embedding":
+        net = _EmbeddingNet()
+        net.initialize(mx.initializer.Xavier())
+        x = mx.nd.array(rng.randint(0, 50, (4, 5)), dtype="int32")
+    elif case == "all_small":
+        net = nn.HybridSequential()
+        net.add(nn.Dense(16, activation="relu", in_units=10),
+                nn.Dense(3, in_units=16))
+        net.initialize(mx.initializer.Xavier())
+        x = mx.nd.array(rng.randn(8, 10).astype(np.float32))
+    else:
+        net = _aux_net(bn=case != "no_batchnorm",
+                       heads=2 if case == "two_outputs" else 1, side=48)
+        x = mx.nd.array(rng.randn(32, 3, 48, 48).astype(np.float32))
+    if case != "embedding":
+        x.attach_grad()
+    n_out = 2 if case == "two_outputs" else 1
+    head_grads = [mx.nd.array(rng.randn(x.shape[0], 3).astype(np.float32))
+                  for _ in range(n_out)]
+    return net, x, head_grads
+
+
+def _unpacked_pullback_reference(entry, x, cts):
+    """The gradients of ``entry.raw`` by ``jax.vjp`` with every residual a
+    program output of its own, as before the packing: two programs, the
+    forward handing the pullback to ``jit(lambda f, c: f(c))``."""
+    import jax
+    n = entry.n_params
+    arrays = [p.data(entry.ctx)._data for p in entry.param_list] + [x._data]
+    fwd = jax.jit(lambda key, *arrays: jax.vjp(
+        lambda *a: entry.raw(key, a[:n], a[n:]), *arrays, has_aux=True))
+    with autograd.train_mode():     # raw traces in the mode it is called in
+        _outs, vjp_fn, _mutated = fwd(jax.random.PRNGKey(0), *arrays)
+    grads = jax.jit(lambda f, c: f(c))(vjp_fn, tuple(cts))
+    return ({p.name: g for p, g in zip(entry.param_list, grads[:n])},
+            grads[n], len(jax.tree_util.tree_leaves(vjp_fn)))
+
+
+@pytest.mark.parametrize("case", ["batchnorm", "no_batchnorm", "two_outputs",
+                                  "embedding", "all_small"])
+def test_hybrid_packed_residuals_grads_bit_equal_to_unpacked_pullback(case):
+    """The pullback's residuals under 64 KiB cross from the recorded forward
+    program to the pullback program in one buffer per dtype: the gradients
+    are those of the same ``jax.vjp`` with every residual on its own, bit
+    for bit, and a second ``backward`` over the same residuals repeats
+    them."""
+    import jax
+    from mxnet_tpu.gluon import block as block_mod
+    net, x, head_grads = _packing_case(case)
+    net.hybridize()
+    heads = []
+
+    def forward():
+        with autograd.record():
+            out = net(x)
+        heads.extend(out if isinstance(out, (list, tuple)) else [out])
+
+    (counts,) = [c for c in _dispatch_counts(forward) if c]
+    pullback = heads[0]._autograd_node.vjp_fn.__defaults__[0]
+    assert isinstance(pullback, block_mod._PackedPullback)
+
+    def grads_now():
+        got = {p.name: p.grad().asnumpy()
+               for p in net.collect_params().values() if p.grad_req != "null"}
+        if x.grad is not None:
+            got["data"] = x.grad.asnumpy()
+        return got
+
+    autograd.backward(heads, head_grads, retain_graph=True)
+    first = grads_now()
+    autograd.backward(heads, head_grads)
+    second = grads_now()
+
+    entry = _recorded_entry(net)
+    want, want_x, n_leaves = _unpacked_pullback_reference(
+        entry, x, [g._data for g in head_grads])
+    assert set(first) - {"data"} == {
+        p.name for p in entry.param_list if p.grad_req != "null"}
+    for name, g in first.items():
+        ref = want_x if name == "data" else want[name]
+        np.testing.assert_array_equal(g, np.asarray(ref), err_msg=name)
+        np.testing.assert_array_equal(second[name], g, err_msg=name)
+
+    # what crossed the host, by the pullback and by the span's record
+    leaves = jax.tree_util.tree_leaves(pullback)
+    limit = block_mod._RESIDUAL_PACK_BYTES
+    assert len(leaves) == len(pullback.large) + len(pullback.packed)
+    assert len(pullback.slots) == n_leaves
+    assert counts["mxnet_cached_op_residual_leaves_total"] == n_leaves
+    assert counts["mxnet_cached_op_residual_buffers_total"] == len(leaves)
+    assert n_leaves > len(leaves) >= 1
+    assert all(b.ndim == 1 for b in pullback.packed)
+    assert len({b.dtype for b in pullback.packed}) == len(pullback.packed)
+    if case in ("all_small", "embedding"):
+        assert not pullback.large
+        assert {str(b.dtype) for b in pullback.packed} == {
+            "float32", "int32" if case == "embedding" else "bool"}
+    else:
+        assert pullback.large and all(
+            a.nbytes >= limit for a in pullback.large)
+
+
+def test_hybrid_mobilenet_v2_residuals_cross_in_a_third_of_the_buffers():
+    """The benchmark's Gluon model: of the 763 residuals of its recorded
+    forward, the per-channel vectors, scalars and small weights (two thirds
+    of them) travel in one float32 and one bool buffer."""
+    from mxnet_tpu.gluon.model_zoo import vision
+    net = vision.mobilenet_v2_1_0()
+    net.initialize(mx.initializer.Xavier())
+    net.hybridize()
+    x = mx.nd.array(np.random.RandomState(3).randn(2, 3, 224, 224)
+                    .astype(np.float32))
+
+    def forward():
+        with autograd.record():
+            net(x)
+
+    records = _dispatch_counts(forward)
+    # the children's dispatches of the dry run that finishes deferred
+    # initialisation are not recorded forwards: they count nothing
+    assert all(r is None for r in records[:-1])
+    counts = records[-1]
+    assert counts["mxnet_cached_op_aux_outputs_total"] == 106
+    assert counts["mxnet_cached_op_residual_leaves_total"] == 763
+    assert 2 <= counts["mxnet_cached_op_residual_buffers_total"] <= 260
+
+
 def _backward_launches(head, head_grad, tmp_path):
     """Names of the programs jax launched on this thread between entering
     ``head.backward`` and its return."""
@@ -395,7 +566,6 @@ def test_hybrid_backward_is_one_launch_and_counts_aux_outputs(
     """``backward()`` through a recorded hybridized block is one call of the
     shared pullback program with a cotangent per output: no zeros are built
     for the statistics."""
-    from mxnet_tpu import telemetry
     from mxnet_tpu.gluon import block as block_mod
     x, head_grad = _aux_data()
     net = _aux_net(bn)
@@ -407,26 +577,28 @@ def test_hybrid_backward_is_one_launch_and_counts_aux_outputs(
     monkeypatch.setattr(
         block_mod, "_BWD_EXEC",
         lambda vjp_fn, cts: seen.append(cts) or real(vjp_fn, cts))
-    telemetry.enable()
-    telemetry.reset_span_records()
-    try:
+    launches = []
+
+    def forwards_and_backward():
         net(x)                          # not recording: nothing counted
         with autograd.record():
             out = net(x)
-        launches = _backward_launches(out, head_grad, tmp_path)
-        records = [r["counts"] for r in telemetry.span_records()
-                   if r["name"] == "gluon/cached_op/dispatch"]
-    finally:
-        telemetry.disable()
-        telemetry.reset_span_records()
+        launches.extend(_backward_launches(out, head_grad, tmp_path))
+
+    records = _dispatch_counts(forwards_and_backward)
     assert "PjitFunction(broadcast_in_dim)" not in launches, launches
     assert launches.count("PjitFunction(<lambda>)") >= 1, launches
     (cts,) = seen
     assert isinstance(cts, tuple) and len(cts) == 1
     assert cts[0] is head_grad._data
-    # two statistics a BatchNorm layer, two layers
-    assert records == [None, {"mxnet_cached_op_aux_outputs_total":
-                              4 if bn else 0}]
+    # two statistics a BatchNorm layer, two layers; every residual of this
+    # small net is under the packing limit: one float32 buffer, and one of
+    # bool for the two ReLU masks
+    (first, counts) = records
+    assert first is None
+    assert counts.pop("mxnet_cached_op_residual_leaves_total") > 2
+    assert counts == {"mxnet_cached_op_aux_outputs_total": 4 if bn else 0,
+                      "mxnet_cached_op_residual_buffers_total": 2}
 
 
 def test_trainer_updates():
