@@ -2,7 +2,10 @@
 ``HybridBlock``s with every size given at construction."""
 from .granite import *  # noqa: F401,F403
 from .granite import (GatedMLP, GraniteHybrid, GroupedQueryAttention,
-                      HybridDecoderLayer, Mamba2Mixer, granite_hybrid)
+                      HybridDecoderLayer, Mamba2Mixer, Relu2MLP,
+                      granite_hybrid)
 from .solar_open2 import *  # noqa: F401,F403
 from .solar_open2 import (KimiDeltaAttention, SolarDecoderLayer, SolarOpen2,
-                          SparseExperts, solar_open2)
+                          SparseExperts, balanced_bias, solar_open2)
+from .nemotron_h import *  # noqa: F401,F403
+from .nemotron_h import NemotronH, NemotronLayer, nemotron_h

@@ -23,7 +23,7 @@ from .... import ndarray as nd
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
 
-__all__ = ["Mamba2Mixer", "GroupedQueryAttention", "GatedMLP",
+__all__ = ["Mamba2Mixer", "GroupedQueryAttention", "GatedMLP", "Relu2MLP",
            "HybridDecoderLayer", "GraniteHybrid", "granite_hybrid"]
 
 
@@ -64,7 +64,8 @@ class Mamba2Mixer(HybridBlock):
     ``xBC = silu(conv1d_causal(xBC))`` split into x (heads × head_dim) and
     the groups' B and C (state_size each); ``Δ = softplus(dt + dt_bias)``,
     ``a = −exp(A_log)``; the selective scan (op ``_contrib_ssd_scan``, in
-    chunks of ``chunk_size``); ``RMSNorm(y · silu(z))``; ``W_out``."""
+    chunks of ``chunk_size``); ``RMSNorm(y · silu(z))``, over each of the
+    ``n_groups`` groups of channels alone; ``W_out``."""
 
     def __init__(self, hidden_size, num_heads, head_dim, state_size,
                  n_groups=1, conv_kernel=4, chunk_size=256, epsilon=1e-5,
@@ -91,7 +92,8 @@ class Mamba2Mixer(HybridBlock):
             self.D = self.params.get("D", shape=(num_heads,), init="ones")
             self.dt_bias = self.params.get(
                 "dt_bias", shape=(num_heads,), init=MambaDtBias())
-            self.norm = RMSNorm(self._inner, epsilon, prefix="norm_")
+            self.norm = RMSNorm(self._inner, epsilon, num_groups=n_groups,
+                                prefix="norm_")
             self.out_proj_weight = self.params.get(
                 "out_proj_weight", shape=(hidden_size, self._inner))
 
@@ -203,6 +205,25 @@ class GatedMLP(HybridBlock):
             u = F.slice_axis(gu, axis=-1, begin=self._width, end=None)
             return _dense(F, F.Activation(g, act_type="silu") * u,
                           out_weight, self._hidden)
+
+
+class Relu2MLP(HybridBlock):
+    """``W_out relu(W_in h)²``, no gate, no bias."""
+
+    def __init__(self, hidden_size, intermediate_size, prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._width, self._hidden = intermediate_size, hidden_size
+        with self.name_scope():
+            self.in_weight = self.params.get(
+                "in_weight", shape=(intermediate_size, hidden_size))
+            self.out_weight = self.params.get(
+                "out_weight", shape=(hidden_size, intermediate_size))
+
+    def hybrid_forward(self, F, h, in_weight, out_weight):
+        with jax.named_scope("relu2_mlp"):
+            u = F.relu(_dense(F, h, in_weight, self._width))
+            return _dense(F, u * u, out_weight, self._hidden)
 
 
 class HybridDecoderLayer(HybridBlock):

@@ -46,10 +46,10 @@ from .... import initializer as init_mod
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
 from .granite import (GatedMLP, GroupedQueryAttention, MambaALog,
-                      MambaDtBias, _dense)
+                      MambaDtBias, Relu2MLP, _dense)
 
-__all__ = ["KimiDeltaAttention", "SparseExperts", "SolarDecoderLayer",
-           "SolarOpen2", "solar_open2"]
+__all__ = ["KimiDeltaAttention", "SparseExperts", "balanced_bias",
+           "SolarDecoderLayer", "SolarOpen2", "solar_open2"]
 
 
 class KimiDeltaAttention(HybridBlock):
@@ -144,37 +144,71 @@ class SparseExperts(HybridBlock):
     ``first_expert .. first_expert + experts_held − 1`` are held and
     computed here for the rows routed to them (op
     ``_contrib_routed_experts``: nothing is dropped), and the shared expert
-    is added.  Returns ``(y, load, rows)``: the assignments each held
-    expert received and the rows the grouped products ran."""
+    is added.  ``form`` is the experts' (and the shared expert's):
+    ``"gated_silu"``, three matrices, or ``"relu2"``, two; the shared
+    expert is ``shared_width`` wide (by default ``shared_experts × width``);
+    ``scope`` is the ``jax.named_scope`` its parts are traced under.
+    Returns ``(y, load, rows)``: the assignments each held expert received
+    and the rows the grouped products ran.
+
+    With ``select_bias`` the block holds a bias per expert
+    (``select_bias``, no gradient) that is added to the scores to CHOOSE
+    the top k and never weighs them, and returns a fourth output, the
+    assignments to each of all ``experts_total`` experts: what the rule
+    that balances the bias reads (``balanced_bias``).  The block reads
+    the bias and does not write it: whoever owns the step applies the
+    rule, outside any rematerialisation boundary."""
 
     def __init__(self, hidden_size, width, experts_total, experts_held,
                  first_expert, top_k, shared_experts=1, scaling=1.0,
-                 norm_topk=True, tile=256, prefix=None, params=None):
+                 norm_topk=True, tile=256, form="gated_silu",
+                 shared_width=None, select_bias=False, scope="solar/moe",
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
+        self._traced_as = scope
         self._attrs = dict(
             experts_total=experts_total, top_k=top_k,
             first_expert=first_expert, routed_scaling_factor=scaling,
-            norm_topk_prob=norm_topk, tile=tile)
+            norm_topk_prob=norm_topk, tile=tile, expert_form=form,
+            select_bias=select_bias)
+        shared = {"gated_silu": GatedMLP, "relu2": Relu2MLP}[form]
         with self.name_scope():
             self.router_weight = self.params.get(
                 "router_weight", shape=(experts_total, hidden_size))
             self.w1 = self.params.get(
                 "w1", shape=(experts_held, width, hidden_size))
-            self.w3 = self.params.get(
-                "w3", shape=(experts_held, width, hidden_size))
+            if form == "gated_silu":
+                self.w3 = self.params.get(
+                    "w3", shape=(experts_held, width, hidden_size))
             self.w2 = self.params.get(
                 "w2", shape=(experts_held, hidden_size, width))
-            self.shared = GatedMLP(hidden_size, shared_experts * width,
-                                   prefix="shared_")
+            if select_bias:
+                self.select_bias = self.params.get(
+                    "select_bias", shape=(experts_total,), grad_req="null")
+            self.shared = shared(hidden_size,
+                                 shared_width or shared_experts * width,
+                                 prefix="shared_")
 
-    def hybrid_forward(self, F, h, router_weight, w1, w3, w2):
-        with jax.named_scope("solar/moe"):      # the op's own scopes nest
-            y, load, rows = F.contrib.routed_experts(
-                h, router_weight, w1, w3, w2, **self._attrs)
-        with jax.named_scope("solar/moe/shared"):
+    def hybrid_forward(self, F, h, router_weight, w1, w2, w3=None,
+                       select_bias=None):
+        inputs = [v for v in (h, router_weight, w1, w3, w2, select_bias)
+                  if v is not None]
+        with jax.named_scope(self._traced_as):      # the op's own scopes nest
+            y, load, rows, *counts = F.contrib.routed_experts(
+                *inputs, **self._attrs)
+        with jax.named_scope(self._traced_as + "/shared"):
             shared = self.shared(h)
-        with jax.named_scope("solar/moe/combine"):
-            return y + shared, load, rows
+        with jax.named_scope(self._traced_as + "/combine"):
+            return (y + shared, load, rows, *counts)
+
+
+def balanced_bias(F, bias, counts, rate):
+    """The selection bias after one step of the auxiliary-loss-free
+    balancing rule (Wang et al. arXiv:2408.15664): an expert that received
+    fewer assignments than the mean is raised by ``rate``, one that
+    received more is lowered: ``b + rate · sign(mean(c) − c)``."""
+    return bias + rate * F.sign(F.mean(counts, axis=-1, keepdims=True)
+                                - counts)
 
 
 class SolarDecoderLayer(HybridBlock):

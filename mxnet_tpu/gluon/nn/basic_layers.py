@@ -337,23 +337,27 @@ class RMSNorm(HybridBlock):
     """Root-mean-square normalization over the trailing axis with a
     learned scale (Zhang & Sennrich arXiv:1910.07467; op ``RMSNorm``).
     Called with a second input it is Mamba-2's gated norm:
-    ``RMSNorm(x * silu(gate))``."""
+    ``RMSNorm(x * silu(gate))``.  With ``num_groups`` the mean square is
+    taken over each group of ``in_channels / num_groups`` consecutive
+    channels."""
 
     def __init__(self, in_channels, epsilon=1e-5, gamma_initializer="ones",
-                 prefix=None, params=None):
+                 num_groups=1, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        self._epsilon = epsilon
+        self._epsilon, self._groups = epsilon, num_groups
         with self.name_scope():
             self.gamma = self.params.get("gamma", shape=(in_channels,),
                                          init=gamma_initializer)
 
     def hybrid_forward(self, F, x, gate=None, *, gamma):
         gates = [] if gate is None else [gate]
-        return F.RMSNorm(x, gamma, *gates, eps=self._epsilon)
+        return F.RMSNorm(x, gamma, *gates, eps=self._epsilon,
+                         num_groups=self._groups)
 
     def __repr__(self):
         return (f"{self.__class__.__name__}(eps={self._epsilon}, "
-                f"in_channels={self.gamma.shape[0]})")
+                f"in_channels={self.gamma.shape[0]}, "
+                f"num_groups={self._groups})")
 
 
 class GroupNorm(HybridBlock):

@@ -1,14 +1,18 @@
 """Routed experts for one holder of a share of a mixture of experts: scores
-over ALL experts, top-k, and the gated MLPs of the experts held here for
-exactly the rows routed to them.
+over ALL experts, top-k, and the MLPs of the experts held here for exactly
+the rows routed to them.
 
 No reference analog: MXNet 1.x has no routed layer.  ``parallel/moe.py``
 is a top-1 router with a capacity; this op is the layer a deployment with
 experts over several chips runs on each of them, without the exchange:
 
     s = sigmoid(W_r h)  over all ``experts_total`` outputs (float32, highest)
-    top-k of s;  w_e = s_e / Σ_topk s · scaling   (``norm_topk``)
-    y = Σ_{e in top-k, first ≤ e < first + E_here} w_e · W2_e (silu(W1_e h) ⊙ W3_e h)
+    the chosen: top-k of s, or of s + b with a selection bias b (which
+        chooses and never weighs: Wang et al. arXiv:2408.15664)
+    w_e = s_e / Σ_chosen s · scaling   (``norm_topk``)
+    y = Σ_{e chosen, first ≤ e < first + E_here} w_e · expert_e(h)
+    expert_e(h) = W2_e (silu(W1_e h) ⊙ W3_e h)     (form ``gated_silu``)
+                = W2_e relu(W1_e h)²                (form ``relu2``)
 
 What an absent expert would add is left out.  NOTHING IS DROPPED: the held
 assignments are sorted by expert and walked in tiles of ``tile`` rows, one
@@ -64,64 +68,76 @@ def _tile(j, plan, top_k, tile, n_tokens):
     return e, flat, token, valid
 
 
-def _expert(x, w1, w3, w2):
-    return (jax.nn.silu(x @ w1.T) * (x @ w3.T)) @ w2.T
+# An expert's form: what stands between its up-projections and its
+# down-projection, as (the value, the derivative by each up-projection).
+# An expert of form F with up matrices U_1.. and the down matrix W2 is
+# ``W2 F(U_1 h, ..)``; the walk and its pullback are written over F.
+def _gated_silu(a, b):
+    s, sig = jax.nn.silu(a), jax.nn.sigmoid(a)
+    return s * b, (b * (sig + s * (1.0 - sig)), s)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _walk(h, weight, w1, w3, w2, plan, top_k, tile):
+def _relu2(a):
+    r = jnp.maximum(a, 0.0)
+    return r * r, (2.0 * r,)
+
+
+FORMS = {"gated_silu": _gated_silu, "relu2": _relu2}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _walk(h, weight, ups, down, plan, form, top_k, tile):
     """``y[n] = Σ weight[a] · expert_e(a)(h[n])`` over the held assignments
-    ``a`` of token ``n``: h (N, hidden), weight (A,) per flat assignment."""
+    ``a`` of token ``n``: h (N, hidden), weight (A,) per flat assignment,
+    ``ups`` the up matrices of ``FORMS[form]`` (each (E_here, width,
+    hidden)), ``down`` (E_here, hidden, width)."""
     n = h.shape[0]
 
     def body(state):
         j, y = state
         e, flat, token, valid = _tile(j, plan, top_k, tile, n)
-        out = _expert(h.at[token].get(mode="clip"), w1[e], w3[e], w2[e]) \
-            * jnp.where(valid, weight[flat], 0.0)[:, None]
+        x = h.at[token].get(mode="clip")
+        mid = FORMS[form](*(x @ w[e].T for w in ups))[0]
+        out = (mid @ down[e].T) * jnp.where(valid, weight[flat], 0.0)[:, None]
         return j + 1, y.at[token].add(out, mode="drop")
 
     return lax.while_loop(lambda s: s[0] < plan[-1], body,
                           (jnp.int32(0), jnp.zeros_like(h)))[1]
 
 
-def _walk_fwd(h, weight, w1, w3, w2, plan, top_k, tile):
-    return (_walk(h, weight, w1, w3, w2, plan, top_k, tile),
-            (h, weight, w1, w3, w2, plan))
+def _walk_fwd(h, weight, ups, down, plan, form, top_k, tile):
+    return (_walk(h, weight, ups, down, plan, form, top_k, tile),
+            (h, weight, ups, down, plan))
 
 
-def _walk_bwd(top_k, tile, saved, dy):
-    h, weight, w1, w3, w2, plan = saved
+def _walk_bwd(form, top_k, tile, saved, dy):
+    h, weight, ups, down, plan = saved
     n = h.shape[0]
 
     def body(state):
-        j, dh, dweight, dw1, dw3, dw2 = state
+        j, dh, dweight, dups, ddown = state
         e, flat, token, valid = _tile(j, plan, top_k, tile, n)
         x = h.at[token].get(mode="clip")
-        a, b = x @ w1[e].T, x @ w3[e].T
-        s = jax.nn.silu(a)
-        mid = s * b
+        mid, slopes = FORMS[form](*(x @ w[e].T for w in ups))
         # rows that are not valid gather dy's last row: mask it
         dout = jnp.where(valid[:, None], dy.at[token].get(mode="clip"),
                          0.0)
-        dmid = dout @ w2[e]                                   # unweighted
+        dmid = dout @ down[e]                                 # unweighted
         dweight = dweight.at[jnp.where(valid, flat, weight.shape[0])].add(
             jnp.sum(dmid * mid, axis=-1), mode="drop")
         wt = jnp.where(valid, weight[flat], 0.0)[:, None]
         dmid = dmid * wt
-        sig = jax.nn.sigmoid(a)
-        da = dmid * b * (sig + s * (1.0 - sig))               # silu'
-        db = dmid * s
-        dw1 = dw1.at[e].add(da.T @ x)
-        dw3 = dw3.at[e].add(db.T @ x)
-        dw2 = dw2.at[e].add(dout.T @ (mid * wt))
-        dh = dh.at[token].add(da @ w1[e] + db @ w3[e], mode="drop")
-        return j + 1, dh, dweight, dw1, dw3, dw2
+        dpre = tuple(dmid * slope for slope in slopes)
+        dups = tuple(dw.at[e].add(d.T @ x) for dw, d in zip(dups, dpre))
+        ddown = ddown.at[e].add(dout.T @ (mid * wt))
+        dh = dh.at[token].add(sum(d @ w[e] for d, w in zip(dpre, ups)),
+                              mode="drop")
+        return j + 1, dh, dweight, dups, ddown
 
     out = lax.while_loop(
         lambda s: s[0] < plan[-1], body,
-        (jnp.int32(0),) + tuple(jnp.zeros_like(v)
-                                for v in (h, weight, w1, w3, w2)))
+        (jnp.int32(0),) + jax.tree_util.tree_map(
+            jnp.zeros_like, (h, weight, ups, down)))
     return out[1:] + (None,)
 
 
@@ -129,14 +145,19 @@ _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
 def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
-                   scaling=1.0, norm_topk=True, tile=256):
+                   scaling=1.0, norm_topk=True, tile=256, select_bias=None):
     """The held experts' part of a routed layer.  h (..., hidden);
-    router_w (experts_total, hidden); w1, w3 (E_here, width, hidden); w2
-    (E_here, hidden, width); the experts held are ``first_expert ..
+    router_w (experts_total, hidden); w1 (E_here, width, hidden); w2
+    (E_here, hidden, width); w3 like w1 for gated SiLU experts, None for
+    relu² experts of two matrices; the experts held are ``first_expert ..
     first_expert + E_here − 1`` of ``experts_total``.  Returns ``(y, load,
     rows)``: y like h; load (E_here,) float32, the assignments each held
     expert received; rows (1,) float32, the rows the grouped products ran,
-    every expert's last tile counted whole.  No assignment is dropped
+    every expert's last tile counted whole.  With ``select_bias``
+    (experts_total,) the chosen are the top k of scores + bias, weighed by
+    their scores alone, and a fourth output counts the assignments to each
+    of ALL ``experts_total`` experts (the rule that balances the bias
+    needs the absent experts' load too).  No assignment is dropped
     whatever the imbalance (see the module's head)."""
     total, hidden = router_w.shape
     n_held = w1.shape[0]
@@ -145,6 +166,7 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
         raise MXNetError(
             f"routed_experts: experts {first_expert}..{first_expert + n_held}"
             f" of {total}, top {top_k}, tile {tile}")
+    form, ups = ("relu2", (w1,)) if w3 is None else ("gated_silu", (w1, w3))
     x = h.reshape(-1, hidden)
     with jax.named_scope("routed_experts/router"):
         # in float32 at the highest precision: near-ties among 320 scores
@@ -152,7 +174,12 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
         scores = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), router_w.astype(jnp.float32).T,
             precision=lax.Precision.HIGHEST))
-        chosen, expert = lax.top_k(scores, top_k)
+        if select_bias is None:     # the values top_k returns ARE the weights
+            chosen, expert = lax.top_k(scores, top_k)
+        else:
+            _, expert = lax.top_k(
+                scores + select_bias.astype(jnp.float32), top_k)
+            chosen = jnp.take_along_axis(scores, expert, axis=-1)
         if norm_topk:
             chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
         weight = (chosen * scaling).astype(h.dtype).reshape(-1)
@@ -160,24 +187,46 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
         local = expert.reshape(-1) - first_expert
         plan = _plan(local, (local >= 0) & (local < n_held), n_held, tile)
     with jax.named_scope("routed_experts/experts"):
-        y = _walk(x, weight, w1, w3, w2, plan, top_k, tile)
+        y = _walk(x, weight, ups, w2, plan, form, top_k, tile)
     load = plan[1].astype(jnp.float32)
     rows = (plan[-1] * tile).astype(jnp.float32).reshape(1)
-    return y.reshape(h.shape), load, rows
+    if select_bias is None:
+        return y.reshape(h.shape), load, rows
+    counts = jnp.sum(expert.reshape(-1, 1) == jnp.arange(total), axis=0,
+                     dtype=jnp.float32)
+    return y.reshape(h.shape), load, rows, counts
+
+
+def _input_names(attrs):
+    """``expert_form`` ``relu2`` has no ``w3``; ``select_bias`` adds the
+    bias as the last input (and the count over all experts as the last
+    output)."""
+    form = attrs.get("expert_form", "gated_silu")
+    if form not in FORMS:
+        raise MXNetError(f"routed_experts: expert_form {form!r} is not one "
+                         f"of {sorted(FORMS)}")
+    return (("data", "router_weight", "w1")
+            + (("w3",) if form == "gated_silu" else ()) + ("w2",)
+            + (("select_bias",) if attrs.get("select_bias", False) else ()))
 
 
 @register("_contrib_routed_experts", alias=("routed_experts",),
-          num_outputs=3,
-          input_names=("data", "router_weight", "w1", "w3", "w2"))
-def _routed_experts(attrs, h, router_w, w1, w3, w2):
+          num_outputs=lambda a: 4 if a.get("select_bias", False) else 3,
+          input_names=_input_names)
+def _routed_experts(attrs, h, router_w, *rest):
+    names = _input_names(attrs)[2:]
+    if len(rest) != len(names):
+        raise MXNetError(f"routed_experts: inputs {names} expected after "
+                         f"the router's weight, {len(rest)} given")
+    given = dict(zip(names, rest))
     total = int(attrs.get("experts_total", router_w.shape[0]))
     if total != router_w.shape[0]:
         raise MXNetError(f"routed_experts: the router has "
                          f"{router_w.shape[0]} outputs, experts_total "
                          f"{total}")
     return routed_experts(
-        h, router_w, w1, w3, w2, int(attrs["top_k"]),
-        int(attrs.get("first_expert", 0)),
+        h, router_w, given["w1"], given.get("w3"), given["w2"],
+        int(attrs["top_k"]), int(attrs.get("first_expert", 0)),
         float(attrs.get("routed_scaling_factor", 1.0)),
         bool(attrs.get("norm_topk_prob", True)),
-        int(attrs.get("tile", 256)))
+        int(attrs.get("tile", 256)), given.get("select_bias"))

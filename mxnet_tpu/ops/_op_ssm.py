@@ -27,12 +27,22 @@ from ..base import MXNetError
 def _rms_norm(attrs, x, gamma, *maybe_gate):
     """``x · rsqrt(mean(x², -1) + eps) · gamma`` over the trailing axis,
     statistics in float32.  With a third input ``gate`` the input is first
-    multiplied by ``silu(gate)`` (Mamba-2's gated norm)."""
+    multiplied by ``silu(gate)`` (Mamba-2's gated norm).  With
+    ``num_groups`` G the mean is over each of the G equal groups of
+    consecutive channels (Mamba-2 with several B/C groups norms each
+    group's channels alone)."""
     eps = float(attrs.get("eps", 1e-5))
+    groups = int(attrs.get("num_groups", 1))
+    if groups < 1 or x.shape[-1] % groups:
+        raise MXNetError(f"RMSNorm: {x.shape[-1]} channels in {groups} "
+                         "groups")
     h = x.astype(jnp.float32)
     if maybe_gate:
         h = h * jax.nn.silu(maybe_gate[0].astype(jnp.float32))
-    h = h * lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
+    by_group = h.reshape(h.shape[:-1] + (groups, -1))
+    h = (by_group * lax.rsqrt(
+        jnp.mean(by_group * by_group, axis=-1, keepdims=True) + eps)
+         ).reshape(h.shape)
     return (h * gamma.astype(jnp.float32)).astype(x.dtype)
 
 

@@ -164,6 +164,11 @@ _MOE_LOAD_SKEW = REGISTRY.gauge(
     "the busiest held expert's assignments over the mean of the held "
     "experts, summed over the recorded steps, in the layer where that "
     "ratio is largest (1 = even; 0 when no layer routed anything here)")
+_MOE_BIAS = REGISTRY.gauge(
+    "mxnet_moe_router_bias_abs_mean",
+    "mean |b| of the routers' selection biases (all layers, all experts) "
+    "as the last recorded step left them: how far the balancing rule has "
+    "moved the choice from the scores (0 for a router without a bias)")
 _COLLECTIVE_BYTES = REGISTRY.counter(
     "mxnet_collective_bytes_total",
     "logical payload bytes moved by gradient-synchronization "
@@ -263,11 +268,14 @@ def record_remat_boundaries(n):
     _REMAT_BOUNDARIES.set(int(n))
 
 
-def record_moe_load(load, rows, steps=1):
+def record_moe_load(load, rows, steps=1, bias=None):
     """Record the routed-expert load of ``steps`` steps from host copies of
     a block's auxiliary state, which sums over them: ``load`` (layers,
     experts held) assignments, ``rows`` (layers,) rows the grouped products
-    ran.  The two counts are set as means a step, the skew is of the sums."""
+    ran.  The two counts are set as means a step, the skew is of the sums.
+    ``bias`` (layers, experts) is the routers' selection bias, where the
+    model has one."""
+    _MOE_BIAS.set(0.0 if bias is None else float(abs(bias).mean()))
     _MOE_ASSIGNMENTS.set(float(load.sum()) / steps)
     _MOE_ROWS.set(float(rows.sum()) / steps)
     mean = load.mean(axis=1)
