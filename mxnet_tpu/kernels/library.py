@@ -182,8 +182,10 @@ def _attn_tol(dtype):
 register_kernel(KernelSpec(
     name="attention",
     doc="blockwise (flash) causal attention (pallas_attention.py); "
-        "config = MXU tiles {block_q, block_k}; fwd pallas online "
-        "softmax, bwd rematerializing custom_vjp",
+        "config = MXU tiles {block_q, block_k} of all three kernels; fwd "
+        "pallas online softmax that keeps (out, lse), bwd two pallas "
+        "kernels (dq; dk, dv over the whole query group) that recompute "
+        "the scores a tile at a time in VMEM",
     reference=_attn_reference,
     make=_attn_make,
     config_space=_attn_space,

@@ -19,12 +19,20 @@ divisor of its head count); query head ``i`` reads key/value head
 ``i // (h_q / h_kv)``.  The forward kernel does that in the key/value
 blocks' index maps, so no head is ever repeated in HBM.
 
-Backward: custom_vjp that recomputes attention in plain XLA one block of
-``BWD_BLOCK_Q`` query rows at a time (``lax.scan`` over the blocks, dk
-and dv accumulated in the carry): the scores alive at once are
-(heads, BWD_BLOCK_Q, S), never (heads, S, S).  Rematerialisation trades
-FLOPs for HBM, same recipe as jax.checkpoint; a dedicated Pallas backward
-kernel is a later optimisation.
+Backward: custom_vjp whose forward rule keeps ``(q, k, v, out, lse)``
+with ``lse = m + log(l)``, one float32 a query row, and whose backward is
+two more Pallas kernels over the same tiles.  Both recompute a tile's
+scores as the forward computes them, so ``P = exp(S - lse)`` is the
+forward's softmax, and form ``dS = P * (dO vᵀ - delta)`` with
+``delta = rowsum(dO * out)``; scores stay in VMEM and causal tiles above
+the diagonal are skipped, their index maps clamped so that a skipped
+grid step copies nothing in.
+  ``mx_flash_attention_bwd_dq``: grid (batch·heads, S/BLOCK_Q, S/BLOCK_K),
+  ``dQ += dS k`` in float32 scratch across the key sweep.
+  ``mx_flash_attention_bwd_dkv``: grid (batch·kv heads, S/BLOCK_K, group,
+  S/BLOCK_Q); the tile is laid out (key, query) so that ``dV += Pᵀ dO``
+  and ``dK += dSᵀ q`` need no transpose, and a key/value head's
+  gradients accumulate over its whole group of query heads in scratch.
 
 Where the program is lowered for anything but a tpu the same kernel runs
 under the Pallas interpreter (_pallas_rows.per_platform), so unit tests
@@ -33,12 +41,14 @@ exercise the identical code path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry import record_flash_attention_bwd_lowered
 from ._pallas_rows import per_platform
 from .registry import register
 
@@ -110,22 +120,32 @@ def _round_up(x, m):
     return (x + m - 1) // m * m
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
-                                             "block_q", "block_k"))
-def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
-    import math
-    b, h, s, d = q.shape
-    group = h // k.shape[1]       # query heads per key/value head
+def _tiles(s, block_q, block_k):
+    """``(bq, bk, s_pad)``: the tiles of a call at ``s`` positions and the
+    length its operands are padded to."""
     bq = min(block_q, _round_up(s, 128))
     bk = min(block_k, _round_up(s, 128))
     # pad to a common multiple of BOTH block sizes — a floor-divided grid
     # would silently drop tail key blocks
-    s_pad = _round_up(s, math.lcm(bq, bk))
-    if s_pad != s:
-        pad = [(0, 0), (0, 0), (0, s_pad - s), (0, 0)]
-        q = jnp.pad(q, pad)
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
+    return bq, bk, _round_up(s, math.lcm(bq, bk))
+
+
+def _pad_seq(s_pad, *arrays):
+    """Zero rows up to ``s_pad`` on the sequence axis of (b, h, s, …)."""
+    pad = s_pad - arrays[0].shape[2]
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(a, [(0, 0), (0, 0), (0, pad)]
+                         + [(0, 0)] * (a.ndim - 3)) for a in arrays)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
+                                             "block_q", "block_k"))
+def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
+    b, h, s, d = q.shape
+    group = h // k.shape[1]       # query heads per key/value head
+    bq, bk, s_pad = _tiles(s, block_q, block_k)
+    q, k, v = _pad_seq(s_pad, q, k, v)
     bh = b * h
     qf = q.reshape(bh, s_pad, d)
     kf = k.reshape(bh // group, s_pad, d)
@@ -180,22 +200,21 @@ def _check_heads(q, k, v):
             "count must divide the query head count")
 
 
-def _reference_attention(q, k, v, causal, sm_scale, q_start=0):
-    """Plain XLA attention of the query rows ``q`` (global positions
-    ``q_start`` onward) over all keys; used by the recompute backward.
-    k, v: (batch, h_kv, S, d) with h_kv dividing q's head count."""
-    b, h, rows, d = q.shape
-    h_kv, s = k.shape[1], k.shape[2]
-    qg = q.astype(jnp.float32).reshape(b, h_kv, h // h_kv, rows, d)
+def _reference_attention(q, k, v, causal, sm_scale):
+    """Plain XLA attention: the (S, S) scores in HBM.  k, v: (batch, h_kv,
+    S, d) with h_kv dividing q's head count."""
+    b, h, s, d = q.shape
+    h_kv = k.shape[1]
+    qg = q.astype(jnp.float32).reshape(b, h_kv, h // h_kv, s, d)
     logits = jnp.einsum("bkgqd,bksd->bkgqs", qg,
                         k.astype(jnp.float32)) * sm_scale
     if causal:
-        qi = q_start + jnp.arange(rows)[:, None]
+        qi = jnp.arange(s)[:, None]
         ki = jnp.arange(s)[None, :]
         logits = jnp.where(ki <= qi, logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
-    return out.reshape(b, h, rows, d).astype(q.dtype)
+    return out.reshape(b, h, s, d).astype(q.dtype)
 
 
 def _static_sm_scale(sm_scale, head_dim):
@@ -238,48 +257,214 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     array — the old ``float(sm_scale)`` host conversion would silently
     concretize a tracer inside jit/shard_map bodies.
     """
-    sm_scale = _static_sm_scale(sm_scale, q.shape[-1])
+    return _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
+
+
+def _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     _check_heads(q, k, v)
-    out, _, _ = _flash_fwd(q, k, v, causal=causal, sm_scale=sm_scale,
-                           block_q=block_q, block_k=block_k)
-    return out
+    return _flash_fwd(q, k, v, causal=causal,
+                      sm_scale=_static_sm_scale(sm_scale, q.shape[-1]),
+                      block_q=block_q, block_k=block_k)
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
-    out = flash_attention(q, k, v, causal, sm_scale, block_q, block_k)
-    return out, (q, k, v)
+    out, m, l = _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    # every row sees a key (its own when causal), so l > 0
+    return out, (q, k, v, out, m + jnp.log(l))
 
 
-# query rows recomputed at once in the backward: the scores alive are
-# (batch, heads, BWD_BLOCK_Q, S) float32 — 268 MB at 32 heads × 4096 keys
-BWD_BLOCK_Q = 512
+_NT = (((1,), (1,)), ((), ()))    # a bᵀ: contract both last dimensions
+_NN = (((1,), (0,)), ((), ()))    # a b
+
+
+def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
+               s_actual, sm_scale, causal):
+    """``P`` and ``P * (dP - delta)`` of one tile, laid out (query, key)
+    for ``q_axis`` 0 and (key, query) for 1; ``lse`` and ``delta`` hold
+    one value a query and broadcast along the key axis.  The scores are
+    the forward kernel's: float32 operands, the scale after the product,
+    the same mask."""
+    def over_heads(of_q, of_k):
+        """(query, key) or (key, query) products over the head dimension."""
+        return jax.lax.dot_general(
+            *((of_q, of_k) if q_axis == 0 else (of_k, of_q)), _NT,
+            preferred_element_type=jnp.float32)
+
+    s = over_heads(q.astype(jnp.float32), k.astype(jnp.float32)) * sm_scale
+    q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                               1 - q_axis)
+    mask = k_ids < s_actual                          # padded keys
+    if causal:
+        mask &= k_ids <= q_ids
+    p = jnp.exp(jnp.where(mask, s, _NEG_INF) - lse)
+    # Mosaic's product of float32 operands is one bfloat16 pass, and what
+    # dO loses to it is one error for a query's whole row of dP, which the
+    # sums over the keys do not average out: a float32 dO goes in as two
+    # bfloat16 parts.  On the chip dQ and dK then read 3.7e-3 from the
+    # exact gradient; with one pass 4.1e-3, farther than the XLA backward
+    # this replaced (4.0e-3, its delta taken from the same dP): PERF.md §6
+    parts = (do,)
+    if do.dtype == jnp.float32:
+        high = do.astype(jnp.bfloat16).astype(jnp.float32)
+        parts = (high, do - high)
+    dp = sum(over_heads(part, v) for part in parts)
+    return p, p * (dp - delta)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   acc_ref, *, block_q, block_k, s_actual, sm_scale, causal):
+    """One (q-block, k-block) grid step of ``dQ = scale · Σ_k dS k``."""
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    q_start = pl.program_id(1) * block_q
+    k_start = kb * block_k
+    run = True
+    if causal:
+        run = k_start <= q_start + block_q - 1
+
+    @pl.when(run)
+    def _compute():
+        k = k_ref[0]
+        _, ds = _tile_p_ds(
+            q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
+            delta_ref[0][:, :1], q_start, k_start, q_axis=0,
+            s_actual=s_actual, sm_scale=sm_scale, causal=causal)
+        acc_ref[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = (acc_ref[:] * sm_scale).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, block_q, block_k, s_actual,
+                    sm_scale, causal):
+    """One (k-block, query head of the group, q-block) grid step of
+    ``dV = Σ Pᵀ dO`` and ``dK = scale · Σ dSᵀ q``, the tile transposed."""
+    g, qb = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((g == 0) & (qb == 0))
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q_start = qb * block_q
+    k_start = pl.program_id(1) * block_k
+    run = True
+    if causal:
+        run = k_start <= q_start + block_q - 1
+
+    @pl.when(run)
+    def _compute():
+        q, do = q_ref[0], do_ref[0]
+        p, ds = _tile_p_ds(
+            q, k_ref[0], v_ref[0], do, lse_ref[0, 0], delta_ref[0, 0],
+            q_start, k_start, q_axis=1, s_actual=s_actual, sm_scale=sm_scale,
+            causal=causal)
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dk_acc[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
+
+    @pl.when((g == pl.num_programs(2) - 1) & (qb == pl.num_programs(3) - 1))
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
+                                             "block_q", "block_k"))
+def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
+    """``(dq, dk, dv)`` with the sequence still padded to the tiles: rows
+    past ``s`` are zero and the caller cuts them off."""
+    b, h, s, d = q.shape
+    h_kv = k.shape[1]
+    group = h // h_kv
+    bq, bk, s_pad = _tiles(s, block_q, block_k)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
+    # padded query rows: zero cotangent and delta, lse 0, so P stays finite
+    # and dS is zero; padded keys are masked, so P is zero there
+    q, k, v, do, lse, delta = _pad_seq(s_pad, q, k, v, do, lse, delta)
+    bh = b * h
+    qf, dof = q.reshape(bh, s_pad, d), do.reshape(bh, s_pad, d)
+    kf, vf = (a.reshape(b * h_kv, s_pad, d) for a in (k, v))
+    params = dict(block_q=bq, block_k=bk, s_actual=s, sm_scale=sm_scale,
+                  causal=causal)
+    n_qb, n_kb = s_pad // bq, s_pad // bk
+
+    # dq: statistics one value a row, broadcast over a lane tile as the
+    # forward writes its own; a skipped step keeps the last block needed
+    def last_kb(qi, ki):
+        return jnp.minimum(ki, (qi * bq + bq - 1) // bk) if causal else ki
+
+    q_spec = pl.BlockSpec((1, bq, d), lambda i, qi, ki: (i, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda i, qi, ki: (i // group, last_kb(qi, ki), 0))
+    col_spec = pl.BlockSpec((1, bq, 128), lambda i, qi, ki: (i, qi, 0))
+    cols = [jnp.broadcast_to(a.reshape(bh, s_pad, 1), (bh, s_pad, 128))
+            for a in (lse, delta)]
+
+    def dq_call(interpret, *operands):
+        return pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **params),
+            grid=(bh, n_qb, n_kb),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+            name="mx_flash_attention_bwd_dq",
+        )(*operands)
+
+    dq = per_platform(dq_call, qf, kf, vf, dof, *cols)
+
+    # dk, dv: flat query head = (batch · h_kv + kv head) · group + g
+    def first_qb(ki, qi):
+        return jnp.maximum(qi, ki * bk // bq) if causal else qi
+
+    q_spec = pl.BlockSpec(
+        (1, bq, d), lambda i, ki, g, qi: (i * group + g, first_qb(ki, qi), 0))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda i, ki, g, qi: (i, ki, 0))
+    # one row of bq statistics a block: the block's last two dimensions are
+    # the array's, whatever bq is
+    row_spec = pl.BlockSpec(
+        (1, 1, 1, bq),
+        lambda i, ki, g, qi: (i * group + g, first_qb(ki, qi), 0, 0))
+    rows = [a.reshape(bh, n_qb, 1, bq) for a in (lse, delta)]
+
+    def dkv_call(interpret, *operands):
+        return pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, **params),
+            grid=(b * h_kv, n_kb, group, n_qb),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=(kv_spec, kv_spec),
+            out_shape=(jax.ShapeDtypeStruct(kf.shape, k.dtype),
+                       jax.ShapeDtypeStruct(vf.shape, v.dtype)),
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)],
+            interpret=interpret,
+            name="mx_flash_attention_bwd_dkv",
+        )(*operands)
+
+    dk, dv = per_platform(dkv_call, qf, kf, vf, dof, *rows)
+    return (dq.reshape(b, h, s_pad, d), dk.reshape(b, h_kv, s_pad, d),
+            dv.reshape(b, h_kv, s_pad, d))
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, g):
-    q, k, v = res
-    sm_scale = _static_sm_scale(sm_scale, q.shape[-1])
-    s = q.shape[2]
-    rows = min(BWD_BLOCK_Q, s)
-    pad = -s % rows
-    # rows padded past the sequence carry a zero cotangent: they add
-    # nothing to dk and dv, and their dq is cut off again
-    padding = [(0, 0), (0, 0), (0, pad), (0, 0)]
-    blocks = [jnp.moveaxis(jnp.pad(a, padding).reshape(
-        a.shape[0], a.shape[1], -1, rows, a.shape[3]), 2, 0) for a in (q, g)]
-
-    def one_block(acc, inp):
-        q_i, g_i, start = inp
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _reference_attention(
-                q_, k_, v_, causal, sm_scale, q_start=start), q_i, k, v)
-        dq_i, dk_i, dv_i = vjp(g_i)
-        return (acc[0] + dk_i, acc[1] + dv_i), dq_i
-
-    (dk, dv), dq = jax.lax.scan(
-        one_block, (jnp.zeros_like(k), jnp.zeros_like(v)),
-        (blocks[0], blocks[1], jnp.arange(0, s + pad, rows)))
-    dq = jnp.moveaxis(dq, 0, 2).reshape(q.shape[:2] + (s + pad, q.shape[3]))
-    return dq[:, :, :s], dk, dv
+    q, k, v, out, lse = res
+    record_flash_attention_bwd_lowered("pallas")
+    grads = _flash_bwd(
+        q, k, v, out, lse, g, causal=causal,
+        sm_scale=_static_sm_scale(sm_scale, q.shape[-1]),
+        block_q=block_q, block_k=block_k)
+    return tuple(a[:, :, :q.shape[2]] for a in grads)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -149,6 +149,12 @@ _REMAT_BOUNDARIES = REGISTRY.gauge(
     "rematerialisation boundaries (jax.checkpoint regions) the last "
     "traced parallel.spmd.TrainStep program holds: one per declared "
     "layer, 1 for a whole-forward wrap, 0 without remat")
+_FLASH_BWD_LOWERED = REGISTRY.counter(
+    "mxnet_flash_attention_bwd_lowered_total",
+    "times the backward rule of ops.pallas_attention.flash_attention was "
+    "traced, by the implementation it put in the program: impl=pallas "
+    "(the mx_flash_attention_bwd_* kernels) or impl=xla; one per attention "
+    "layer a traced train step, none when a cached program runs")
 _MOE_ASSIGNMENTS = REGISTRY.gauge(
     "mxnet_moe_assignments_held",
     "(token, expert) assignments the routed-expert layers sent to the "
@@ -266,6 +272,12 @@ def record_remat_boundaries(n):
     """Record how many rematerialisation boundaries a train step program
     was traced with (parallel.spmd.TrainStep)."""
     _REMAT_BOUNDARIES.set(int(n))
+
+
+def record_flash_attention_bwd_lowered(impl):
+    """Account one trace of flash attention's backward rule; ``impl`` is
+    ``pallas`` or ``xla``."""
+    _FLASH_BWD_LOWERED.inc(1, labels={"impl": impl})
 
 
 def record_moe_load(load, rows, steps=1, bias=None):

@@ -1,0 +1,70 @@
+"""The flash attention kernels, forward and backward, compiled by Mosaic for
+a DESCRIBED TPU v5e (no chip attached): what the interpreter cannot show,
+a block shape, a layout or a VMEM budget the compiler refuses.  Nothing
+runs; the shapes are the three token cells' and the suite's awkward ones.
+All compiles live in this one file and the topology is described inside a
+fixture, so only the worker that is given the file loads the TPU's
+library."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops.pallas_attention import flash_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a chip that is not attached cannot be read back
+    # from the persistent cache: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (query heads, key/value heads, positions, head size, dtype, block_q,
+# block_k, causal)
+@pytest.mark.parametrize("h,h_kv,s,d,dtype,block_q,block_k,causal", [
+    (32, 2, 8192, 128, "float32", 512, 512, True),     # Nemotron's layer
+    (8, 1, 8192, 128, "float32", 512, 512, True),      # Solar's
+    (32, 8, 4096, 64, "float32", 512, 512, True),      # granite's
+    (32, 2, 8192, 128, "bfloat16", 512, 512, True),
+    (8, 8, 1024, 128, "bfloat16", 128, 128, True),     # chip_smoke's
+    (1, 1, 128, 8, "float32", 128, 128, True),
+    (4, 2, 200, 16, "float32", 128, 128, True),        # a tail, heads of 16
+    (4, 2, 200, 32, "bfloat16", 128, 128, False),
+    (2, 2, 256, 32, "float32", 64, 64, True),          # the tuner's pairs
+    (2, 2, 256, 32, "float32", 64, 128, True),
+    (2, 2, 512, 32, "float32", 256, 128, False),
+    (2, 2, 256, 32, "float32", 96, 128, True),         # no lane multiple
+])
+def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
+                                                dtype, block_q, block_k,
+                                                causal):
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, heads, s, d), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def out_and_grads(q, k, v, do):
+        out, vjp = jax.vjp(lambda *a: flash_attention(
+            *a, causal, None, block_q, block_k), q, k, v)
+        return (out,) + vjp(do)
+
+    text = jax.jit(out_and_grads).lower(
+        spec(h), spec(h_kv), spec(h_kv), spec(h)).compile().as_text()
+    for kernel in ("mx_flash_attention_fwd", "mx_flash_attention_bwd_dq",
+                   "mx_flash_attention_bwd_dkv"):
+        assert kernel in text

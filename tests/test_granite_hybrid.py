@@ -3,7 +3,7 @@ small size on the CPU: the chunked scan op ``_contrib_ssd_scan`` against
 the step-by-step recurrence, the model through ``parallel.spmd.TrainStep``
 against the benchmark's plain reference (logits, loss, every parameter's
 gradient), the per-layer remat boundary, the vocabulary slice, grouped
-heads and the row-blocked backward of flash attention, and the published
+heads and the Pallas backward of flash attention, and the published
 configuration's counts from its shapes alone."""
 import os
 import sys
@@ -124,11 +124,13 @@ def trained():
         with step.mesh.jax_mesh:
             logits = np.asarray(jax.jit(lambda ps, a: step._apply(
                 jax.random.PRNGKey(0), ps, (a,))[0][0])(step.params, x))
+        lowered = _flash_bwd_lowered("pallas")
         loss = float(step(x, y))
         after = {names[n]: np.asarray(a)
                  for n, a in zip(step.param_names, step.params)}
         out[remat] = dict(params=params, x=x, y=y, logits=logits, loss=loss,
-                          after=after, boundaries=step.remat_boundaries)
+                          after=after, boundaries=step.remat_boundaries,
+                          flash_bwd=_flash_bwd_lowered("pallas") - lowered)
     with jax.default_matmul_precision("highest"):
         t = out[True]
         out["ref_logits"] = np.asarray(
@@ -178,6 +180,15 @@ def test_remat_holds_a_boundary_per_layer_and_changes_nothing(trained):
     # the fixture traced the remat step last
     assert telemetry.REGISTRY.get(
         "mxnet_step_remat_boundaries").value() == 5.0
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_every_attention_layer_lowers_the_pallas_backward_once(trained, remat):
+    """Tracing the step counts one kernel backward per attention layer,
+    under remat too, and the zoo's shapes never take another."""
+    assert trained[remat]["flash_bwd"] == \
+        SMALL["layer_types"].count("attention")
+    assert _flash_bwd_lowered("xla") == 0
 
 
 def test_whole_forward_remat_is_one_boundary_for_a_block_without_layers():
@@ -242,36 +253,105 @@ def test_logits_over_a_vocabulary_slice_are_the_uncut_columns():
                                rtol=1e-4, atol=1e-5)
 
 
-# -- (iv) flash attention: grouped heads, row-blocked backward -------------------------
-@pytest.mark.parametrize("causal", [True, False])
-def test_row_blocked_backward_equals_the_full_one(causal, monkeypatch):
-    """8 key/value heads under 32 query heads, 200 positions in blocks of
-    64 rows (a tail of 8): forward and every gradient equal plain
-    attention over repeated heads."""
-    monkeypatch.setattr(pallas_attention, "BWD_BLOCK_Q", 64)
+# -- (iv) flash attention: grouped heads, the Pallas backward --------------------------
+def _flash_bwd_lowered(impl):
+    from mxnet_tpu import telemetry
+    return telemetry.REGISTRY.get(
+        "mxnet_flash_attention_bwd_lowered_total").value({"impl": impl})
+
+
+def _qkvw(heads, kv_heads, s, d, dtype=np.float32):
     rng = np.random.default_rng(0)
-    q = rng.standard_normal((1, 32, 200, 16)).astype(np.float32)
-    k, v = (rng.standard_normal((1, 8, 200, 16)).astype(np.float32)
-            for _ in range(2))
-    w = rng.standard_normal(q.shape).astype(np.float32)
+    return tuple(jnp.asarray(rng.standard_normal((1, n, s, d)), dtype)
+                 for n in (heads, kv_heads, kv_heads, heads))
+
+
+def _plain(q, k, v, causal):
+    """softmax(q kᵀ · 0.2) v over repeated heads."""
+    k, v = (jnp.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.2
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+# (causal, key/value heads, query heads a key/value head, positions, head
+# size, block_q, block_k); the first two were the row-blocked backward's
+# cases: 8 key/value heads under 32 query heads, 200 positions (a tail)
+@pytest.mark.parametrize("causal,kv_heads,group,s,d,block_q,block_k", [
+    (True, 8, 4, 200, 16, 128, 128), (False, 8, 4, 200, 16, 128, 128),
+    (True, 2, 1, 200, 64, 128, 128), (True, 1, 16, 200, 16, 128, 128),
+    (True, 1, 16, 256, 128, 128, 128), (False, 1, 16, 200, 64, 64, 128),
+    (True, 2, 4, 256, 64, 128, 64), (True, 2, 4, 256, 16, 64, 128),
+    (True, 2, 1, 384, 16, 128, 256), (False, 2, 1, 256, 128, 128, 256),
+    (True, 2, 4, 512, 128, 256, 128), (False, 2, 4, 300, 64, 256, 128)])
+def test_pallas_backward_equals_the_plain_gradient(causal, kv_heads, group,
+                                                   s, d, block_q, block_k):
+    """Forward and every gradient equal plain attention over repeated
+    heads, and each traced backward counts once as the kernels'."""
+    q, k, v, w = _qkvw(group * kv_heads, kv_heads, s, d)
 
     def flash(q, k, v):
-        return (pallas_attention.flash_attention(q, k, v, causal, 0.2)
-                * w).sum()
+        return pallas_attention.flash_attention(q, k, v, causal, 0.2,
+                                                block_q, block_k)
 
-    def plain(q, k, v):
-        k, v = (jnp.repeat(a, 4, axis=1) for a in (k, v))
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * 0.2
-        if causal:
-            s = jnp.where(jnp.tril(jnp.ones((200, 200), bool)), s, -jnp.inf)
-        return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
-                * w).sum()
-
-    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), rtol=1e-5)
-    for got, want in zip(jax.grad(flash, (0, 1, 2))(q, k, v),
-                         jax.grad(plain, (0, 1, 2))(q, k, v)):
-        np.testing.assert_allclose(got, want, rtol=1e-4,
+    np.testing.assert_allclose(flash(q, k, v), _plain(q, k, v, causal),
+                               rtol=1e-4, atol=1e-5)
+    before = _flash_bwd_lowered("pallas")
+    got = jax.grad(lambda *a: (flash(*a) * w).sum(), (0, 1, 2))(q, k, v)
+    assert _flash_bwd_lowered("pallas") == before + 1
+    assert _flash_bwd_lowered("xla") == 0
+    for g, want in zip(got, jax.grad(
+            lambda *a: (_plain(*a, causal) * w).sum(), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(g, want, rtol=1e-4,
                                    atol=1e-5 * float(np.abs(want).max()))
+
+
+def test_bfloat16_operands_give_bfloat16_gradients_near_the_float32_ones():
+    args = _qkvw(4, 1, 200, 64)
+
+    def grads(*a):
+        return jax.grad(lambda q, k, v, w: (pallas_attention.flash_attention(
+            q, k, v, True, 0.2) * w).sum().astype(jnp.float32),
+            (0, 1, 2))(*a)
+
+    for g, want in zip(grads(*(a.astype(jnp.bfloat16) for a in args)),
+                       grads(*args)):
+        assert g.dtype == jnp.bfloat16
+        g = np.asarray(g, np.float32)
+        assert np.isfinite(g).all()
+        assert np.abs(g - want).max() <= 2e-2 * float(np.abs(want).max())
+
+
+def test_rows_past_the_sequence_get_exactly_zero_gradients():
+    """200 positions in tiles of 128 are padded to 256: the padded keys
+    are masked, the padded queries carry no cotangent, and what the
+    kernels write there is zero, not small."""
+    q, k, v, do = _qkvw(4, 2, 200, 16)
+    out, m, l = pallas_attention._flash_fwd(
+        q, k, v, causal=True, sm_scale=0.2, block_q=128, block_k=128)
+    grads = pallas_attention._flash_bwd(
+        q, k, v, out, m + jnp.log(l), do, causal=True, sm_scale=0.2,
+        block_q=128, block_k=128)
+    for g in grads:
+        assert g.shape[2] == 256
+        assert np.isfinite(g).all() and np.abs(g[:, :, :200]).max() > 0
+        assert not np.asarray(g[:, :, 200:]).any()
+
+
+def test_the_forward_under_differentiation_is_the_forward_bit_for_bit():
+    """The rule that keeps (out, lse) for the backward returns the very
+    output of the forward kernel, whose (out, m, l) ring attention reads."""
+    q, k, v, _ = _qkvw(4, 2, 200, 16)
+    out, m, l = pallas_attention._flash_fwd(
+        q, k, v, causal=True, sm_scale=0.2, block_q=128, block_k=128)
+    primal, (_, _, _, kept, lse) = pallas_attention._flash_fwd_rule(
+        q, k, v, True, 0.2, 128, 128)
+    plain = pallas_attention.flash_attention(q, k, v, True, 0.2, 128, 128)
+    for a in (primal, kept, plain):
+        np.testing.assert_array_equal(a, out)
+    assert lse.shape == (1, 4, 200) and lse.dtype == jnp.float32
+    np.testing.assert_array_equal(lse, m + jnp.log(l))
 
 
 def test_flash_attention_refuses_heads_that_do_not_divide():
