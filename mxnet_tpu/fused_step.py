@@ -234,9 +234,10 @@ class FusedTrainStep:
                 # baked in only while the site is armed, so production
                 # armed windows pay zero extra gradient traffic
                 grads = [g * poison.astype(g.dtype) for g in grads]
-            new_params, new_states = opt.fused_update(
-                list(train_vals), grads, list(states),
-                *hyper_scalars(lrs, wds, train_vals, states))
+            with jax.named_scope("step/optimizer"):
+                new_params, new_states = opt.fused_update(
+                    list(train_vals), grads, list(states),
+                    *hyper_scalars(lrs, wds, train_vals, states))
             if num_mode != "off":
                 # numerics observatory (ISSUE 14): health stats ride the
                 # same donated dispatch; skip mode gates the poisoned
@@ -543,9 +544,10 @@ class ScanTrainStep(FusedTrainStep):
                     # step wall.  The barrier materializes grads once.
                     grads_sum = list(jax.lax.optimization_barrier(
                         tuple(grads_sum)))
-                new_params, new_states = opt.fused_update(
-                    list(tv), grads_sum, list(st),
-                    *hyper_scalars(lr_s, wd_s, tv, st))
+                with jax.named_scope("step/optimizer"):
+                    new_params, new_states = opt.fused_update(
+                        list(tv), grads_sum, list(st),
+                        *hyper_scalars(lr_s, wd_s, tv, st))
                 ys = tuple(jnp.stack([o[i] for o in outs_micro])
                            for i in range(len(outs_micro[0])))
                 if num_mode != "off":

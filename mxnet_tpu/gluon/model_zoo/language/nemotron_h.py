@@ -165,17 +165,18 @@ class NemotronH(HybridBlock):
             # program.  The two counts are added to (sums over the forwards
             # made since they were zero, as SolarOpen2's)
             loads, rows, counts = zip(*notes)
-            expert_load._set_data(
-                (expert_load + F.stack(*loads, axis=0))._data)
-            expert_rows._set_data(
-                (expert_rows + F.concat(*rows, dim=0))._data)
-            if autograd.is_training():
-                # the layers above have read their bias; the next step
-                # reads what is written here
-                for layer, count in zip(self.expert_layers, counts):
-                    bias = layer.mixer.select_bias.data(ids.context)
-                    bias._set_data(balanced_bias(
-                        F, bias, count, self._bias_rate)._data)
+            with jax.named_scope("step/aux_state"):
+                expert_load._set_data(
+                    (expert_load + F.stack(*loads, axis=0))._data)
+                expert_rows._set_data(
+                    (expert_rows + F.concat(*rows, dim=0))._data)
+                if autograd.is_training():
+                    # the layers above have read their bias; the next step
+                    # reads what is written here
+                    for layer, count in zip(self.expert_layers, counts):
+                        bias = layer.mixer.select_bias.data(ids.context)
+                        bias._set_data(balanced_bias(
+                            F, bias, count, self._bias_rate)._data)
         with jax.named_scope("nemotron/head"):
             return _dense(F, self.final_norm(x), head_weight, self._vocab)
 

@@ -311,10 +311,11 @@ class SolarOpen2(HybridBlock):
         # Added, not overwritten: the state is the sum over the forwards
         # made since it was last zero (whole numbers, exact in float32 up
         # to 2**24 an entry)
-        expert_load._set_data(
-            (expert_load + F.stack(*loads, axis=0))._data)
-        expert_rows._set_data(
-            (expert_rows + F.concat(*rows, dim=0))._data)
+        with jax.named_scope("step/aux_state"):
+            expert_load._set_data(
+                (expert_load + F.stack(*loads, axis=0))._data)
+            expert_rows._set_data(
+                (expert_rows + F.concat(*rows, dim=0))._data)
         with jax.named_scope("solar/head"):
             return _dense(F, self.final_norm(x), head_weight, self._vocab)
 

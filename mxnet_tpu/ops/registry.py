@@ -89,6 +89,8 @@ class Operator:
                  mutate_aux=(), fgradient=None, alias=(), scalar_args=("scalar",),
                  num_visible=None, input_names=None, eager_only=False):
         self.name = name
+        # the jax.named_scope its device ops run under (docs/observability.md)
+        self.scope = "op/" + name
         self.fcompute = fcompute
         self.num_outputs = num_outputs
         # eager_only: op produces data-dependent (dynamic) shapes — legal in
@@ -140,22 +142,28 @@ class Operator:
         return tuple(ma(attrs)) if callable(ma) else tuple(ma)
 
     # -- compiled execution ------------------------------------------------
+    def _call(self, attrs):
+        """The one closure every path runs: ``fcompute`` under the scope
+        ``op/<name>``, so the device ops it becomes carry the operator's
+        name in their HLO metadata (``profiler.device_ops`` reads it back
+        from a trace; docs/observability.md has the vocabulary)."""
+        fcompute = self.fcompute
+        amp_mode = attrs.get("_amp")
+        clean = {k: v for k, v in attrs.items() if k != "_amp"}
+        scope = self.scope
+
+        def call(*arrays):
+            if amp_mode:
+                arrays = _amp_cast(arrays, amp_mode)
+            with jax.named_scope(scope):
+                return fcompute(clean, *arrays)
+
+        return call
+
     def jitted(self, attrs_key, attrs):
         fn = self._jit_cache.get(attrs_key)
         if fn is None:
-            fcompute = self.fcompute
-            amp_mode = attrs.get("_amp")
-
-            def call(*arrays):
-                if amp_mode:
-                    arrays = _amp_cast(arrays, amp_mode)
-                out = fcompute(
-                    {k: v for k, v in attrs.items() if k != "_amp"},
-                    *arrays)
-                return out
-
-            fn = jax.jit(call)
-            self._jit_cache[attrs_key] = fn
+            fn = self._jit_cache[attrs_key] = jax.jit(self._call(attrs))
         return fn
 
     def bind(self, **attrs):
@@ -166,16 +174,7 @@ class Operator:
     def raw(self, attrs):
         """Unjitted closure — used under jax.vjp (jax 0.9 cannot linearize
         some primitives, e.g. reduce_window, through an inner jit)."""
-        fcompute = self.fcompute
-        amp_mode = attrs.get("_amp")
-
-        def call(*arrays):
-            if amp_mode:
-                arrays = _amp_cast(arrays, amp_mode)
-            return fcompute(
-                {k: v for k, v in attrs.items() if k != "_amp"}, *arrays)
-
-        return call
+        return self._call(attrs)
 
     def grad_aware(self, attrs):
         """Compute closure that honors a registered custom ``fgradient``
@@ -211,7 +210,10 @@ class Operator:
 
         def bwd(primals, cts):
             cts_t = tuple(cts) if isinstance(cts, (tuple, list)) else (cts,)
-            gs = fg(clean, primals, cts_t)
+            # the forward's scope ends with ``base``: the custom rule's
+            # device ops are the operator's too
+            with jax.named_scope(self.scope):
+                gs = fg(clean, primals, cts_t)
             import jax.numpy as jnp
             out = []
             for g, p in zip(gs, primals):

@@ -259,8 +259,9 @@ def fsdp_bucket_update(opt, params, grads, states, lrs, wds, axis_names,
                 flat_s, (start,), (shard_len,)))
         st_shard = jax.tree_util.tree_unflatten(treedef, st_shard_leaves)
 
-        upd_p, upd_s = opt.fused_update(
-            [w_shard], [g_shard], [st_shard], [lr_shard], [wd_shard])
+        with jax.named_scope("step/optimizer"):
+            upd_p, upd_s = opt.fused_update(
+                [w_shard], [g_shard], [st_shard], [lr_shard], [wd_shard])
         new_flat_w = jax.lax.all_gather(upd_p[0], axis_names, tiled=True)  # graftlint: disable=per-param-collective -- one all-gather per BUCKET: the batched form itself
         bucket_params = _unflatten_bucket(new_flat_w, ws)
         for i, npar in zip(bucket, bucket_params):
@@ -487,9 +488,10 @@ class MeshFusedTrainStep(ScanTrainStep):
                         # is what the sentinel judges, codec or not
                         grads_sum = [g * poison.astype(g.dtype)
                                      for g in grads_sum]
-                    new_params, new_states = opt.fused_update(
-                        list(tv), grads_sum, list(st),
-                        lr_row, wd_row)
+                    with jax.named_scope("step/optimizer"):
+                        new_params, new_states = opt.fused_update(
+                            list(tv), grads_sum, list(st),
+                            lr_row, wd_row)
                 ys = tuple(jnp.stack([o[i] for o in outs_micro])
                            for i in range(len(outs_micro[0])))
                 if num_mode != "off":

@@ -371,18 +371,23 @@ class TrainStep:
 
                 def compute_loss(tps):
                     pred, mutated = fwd(tps, x)
-                    return loss_raw(pred, y), mutated
+                    with jax.named_scope("step/loss"):
+                        return loss_raw(pred, y), mutated
 
                 (loss, mutated), grads = jax.value_and_grad(
                     compute_loss, has_aux=True)(train_params)
                 if grad_sync is not None:
-                    grads, loss = grad_sync(list(grads), loss)
+                    with jax.named_scope("step/grad_sync"):
+                        grads, loss = grad_sync(list(grads), loss)
                 new_params = []
                 new_state = []
-                for w, g, st in zip(train_params, grads, opt_state):
-                    nw, ns = opt_update(opt_attrs, w, g, st)
-                    new_params.append(nw)
-                    new_state.append(ns)
+                # the functional optimizers call the update ops' fcompute
+                # themselves, past the registry's ``op/<name>`` scope
+                with jax.named_scope("step/optimizer"):
+                    for w, g, st in zip(train_params, grads, opt_state):
+                        nw, ns = opt_update(opt_attrs, w, g, st)
+                        new_params.append(nw)
+                        new_state.append(ns)
                 # mutated comes back in ascending-param-index order == aux
                 # order; write the new running stats into the aux slot
                 # (round-1 dropped them: inference-mode BN saw frozen

@@ -9,9 +9,14 @@ chrome-trace JSON + aggregate table the reference emits.
 """
 from __future__ import annotations
 
+import bisect
+import collections
+import functools
+import glob
 import json
 import logging
 import os
+import re
 import threading
 import time
 
@@ -32,7 +37,10 @@ _config = {
     "dump_period": 1.0,
 }
 _state = {"running": False, "jax_trace_dir": None, "dump_timer": None,
-          "kvstore": None, "last_mem_sample": 0.0}
+          "kvstore": None, "last_mem_sample": 0.0,
+          # where the last start() traced the device to: dumps() reads
+          # the newest xplane under it once the trace has stopped
+          "xplane_dir": None}
 _records = []
 _records_lock = threading.Lock()
 _last_counters = {}
@@ -98,7 +106,7 @@ def start(profile_process="worker"):
     if xdir:
         import jax
         jax.profiler.start_trace(xdir)
-        _state["jax_trace_dir"] = xdir
+        _state["jax_trace_dir"] = _state["xplane_dir"] = xdir
     if _config["continuous_dump"]:
         _schedule_dump()
     _forward_to_server("profiler_set_state", "run")
@@ -174,6 +182,204 @@ def jax_trace_dir():
     """Directory of the live jax xplane trace (None when no device trace
     is running) — telemetry spans mirror themselves into it."""
     return _state["jax_trace_dir"]
+
+
+# -- the device trace, by the program's own scopes ---------------------------
+# What a jax name stack holds that is jax's own and no scope of the
+# program's: the call a component names (``jit(step)``), the transforms
+# wrapped round a scope (``transpose(jvp(op/Convolution))``), and the
+# components control flow, calls and ``jax.checkpoint`` put in.
+_CALL = re.compile(r"(^|/)(jit|pjit|pmap)\([^()]*\)")
+_TRANSFORM = re.compile(r"\w+\(|\)")
+_JAX_OWN = re.compile(
+    r"^(while|body|cond|body_pred|branch_\d+_fun|closed_call|core_call|"
+    r"checkpoint|rematted_computation|pjit|shard_map|custom_jvp_call|"
+    r"custom_vjp_call|custom_vjp_call_jaxpr)$")
+RECOMPUTE = "rematted_computation"   # what jax.checkpoint's backward re-runs
+# an op that only holds other ops of its line (a scanned window's ``while``)
+_CONTAINER = re.compile(r"^%?(while|conditional|call)[.\d]* = ")
+PHASES = ("forward", "backward", "recompute", "other")
+
+DeviceOp = collections.namedtuple(
+    "DeviceOp", "device start_ns duration_ns name program scopes phases")
+
+
+@functools.lru_cache(maxsize=None)
+def parse_op_name(op_name):
+    """``(scopes, phases)`` of one op-name statistic of a device trace: the
+    ``;``-joined jax name stacks of the primitives XLA fused into the op.
+    ``scopes[i]`` is the i-th name's path of ``jax.named_scope`` names
+    (``"nemotron/attention/granite/attention/op/_contrib_flash_attention"``,
+    ``""`` where the primitive ran under none) with jax's own components
+    and the primitive's name stripped; ``phases[i]`` says which pass put
+    it there: ``recompute`` (under ``jax.checkpoint``'s
+    ``rematted_computation``), ``backward`` (``transpose(...)``),
+    ``forward`` (``jvp(...)``), else ``other`` (the update, a metric)."""
+    scopes, phases = [], []
+    for name in (op_name or "").split(";"):
+        name = name.strip().rstrip(":")   # the statistic is "<name>:<type>"
+        if not name:
+            continue
+        if RECOMPUTE in name:
+            phases.append("recompute")
+        elif "transpose(" in name:
+            phases.append("backward")
+        elif "jvp(" in name:
+            phases.append("forward")
+        else:
+            phases.append("other")
+        # the last component is the primitive (a call's is ``jit(f)``)
+        parts = _TRANSFORM.sub("", _CALL.sub(r"\1", name)).split("/")[:-1]
+        scopes.append("/".join(
+            p for p in parts if p and not _JAX_OWN.match(p)))
+    return tuple(scopes), tuple(phases)
+
+
+def _wire(buf, pos, end):
+    """``(field, value)`` of one protobuf message in ``buf[pos:end]``: an
+    int for a varint, ``(start, end)`` for a length-delimited field,
+    fixed-width fields skipped."""
+    while pos < end:
+        key = shift = 0
+        while True:
+            b = buf[pos]
+            pos += 1
+            key |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                break
+        kind = key & 7
+        if kind == 2 or kind == 0:
+            val = shift = 0
+            while True:
+                b = buf[pos]
+                pos += 1
+                val |= (b & 0x7F) << shift
+                shift += 7
+                if b < 0x80:
+                    break
+            if kind == 2:
+                yield key >> 3, (pos, pos + val)
+                pos += val
+            else:
+                yield key >> 3, val
+        else:
+            pos += 8 if kind == 1 else 4
+
+
+def device_ops(xplane_path):
+    """Every executed HLO operation of a profiler trace (``.xplane.pb``),
+    by the program's own names: for each ``/device:TPU:<n>`` plane the
+    events of its ``XLA Ops`` line as ``DeviceOp(device, start_ns,
+    duration_ns, name, program, scopes, phases)``: the clock and the HLO
+    name as ``jax.profiler.ProfileData`` gives them, ``program`` the
+    ``XLA Modules`` run the op started in, ``scopes`` and ``phases`` from
+    the op's ``tf_op`` statistic (``parse_op_name``), which
+    ``ProfileData`` does not hand out.  Ordered by device, then start.
+    ``[]`` for a trace with no TPU plane.
+
+    The file is an ``XSpace`` message (tsl/profiler/protobuf/
+    xplane.proto); read by walking its wire format, since the generated
+    classes live in packages a training machine need not have:
+    ``XSpace.planes = 1``; ``XPlane.name = 2, lines = 3, event_metadata
+    = 4, stat_metadata = 5``; ``XLine.name = 2, timestamp_ns = 3, events
+    = 4``; ``XEvent.metadata_id = 1, offset_ps = 2, duration_ps = 3``;
+    ``XEventMetadata.id = 1, name = 2, stats = 5``; ``XStat.metadata_id
+    = 1, str_value = 5, ref_value = 7``; ``XStatMetadata.id = 1, name =
+    2``."""
+    with open(xplane_path, "rb") as f:
+        buf = f.read()
+
+    def text(span):
+        return buf[span[0]:span[1]].decode("utf-8", "replace")
+
+    def entry(span):   # a map entry: key = 1, value = 2
+        return dict(_wire(buf, *span))
+
+    out = []
+    for field, plane in _wire(buf, 0, len(buf)):
+        if field != 1:
+            continue
+        parts = collections.defaultdict(list)
+        for f_, v in _wire(buf, *plane):
+            parts[f_].append(v)
+        m = re.match(r"^/device:TPU:(\d+)$", text(parts[2][0])) \
+            if parts[2] else None
+        if not m:
+            continue
+        stat_names = {}
+        for span in parts[5]:
+            meta = dict(_wire(buf, *entry(span)[2]))
+            stat_names[meta.get(1, 0)] = text(meta[2]) if 2 in meta else ""
+        events = {}     # metadata id -> (HLO name, op-name statistic)
+        for span in parts[4]:
+            name, op_name, mid = "", "", 0
+            for f_, v in _wire(buf, *entry(span)[2]):
+                if f_ == 1:
+                    mid = v
+                elif f_ == 2:
+                    name = text(v)
+                elif f_ == 5:
+                    stat = dict(_wire(buf, *v))
+                    if stat_names.get(stat.get(1)) == "tf_op":
+                        op_name = text(stat[5]) if 5 in stat else \
+                            stat_names.get(stat.get(7), "")
+            events[mid] = (name, op_name)
+        lines = {}
+        for span in parts[3]:
+            line = collections.defaultdict(list)
+            for f_, v in _wire(buf, *span):
+                line[f_].append(v)
+            name = text(line[2][0]) if line[2] else ""
+            if name in ("XLA Ops", "XLA Modules"):
+                t0 = line[3][0] if line[3] else 0
+                rows = []
+                for ev in line[4]:
+                    e = dict(_wire(buf, *ev))
+                    rows.append((int(t0 + e.get(2, 0) / 1000),
+                                 int(e.get(3, 0) / 1000), e.get(1, 0)))
+                lines[name] = sorted(rows)
+        runs = lines.get("XLA Modules", [])
+        run_starts = [r[0] for r in runs]
+        for start, dur, mid in lines.get("XLA Ops", []):
+            name, op_name = events.get(mid, ("", ""))
+            i = bisect.bisect_right(run_starts, start) - 1
+            program = ""
+            if i >= 0 and start < runs[i][0] + max(runs[i][1], 1):
+                program = events.get(runs[i][2], ("", ""))[0]
+            scopes, phases = parse_op_name(op_name)
+            out.append(DeviceOp(int(m.group(1)), start, dur, name,
+                                program.partition("(")[0], scopes, phases))
+    out.sort(key=lambda op: (op.device, op.start_ns))
+    return out
+
+
+def device_time_by_scope(xplane_path):
+    """``{scope path: [ops, seconds]}`` over a trace's TPU planes, most
+    time first: an op fused from primitives of several scopes gives each
+    an equal part; ``"(unscoped)"`` holds what ran under none.  Ops that
+    only hold others (the ``while`` of a scanned window) are left out."""
+    by = {}
+    for op in device_ops(xplane_path):
+        if _CONTAINER.match(op.name):
+            continue
+        paths = sorted({s for s in op.scopes if s}) or ["(unscoped)"]
+        for path in paths:
+            row = by.setdefault(path, [0, 0.0])
+            row[0] += 1
+            row[1] += op.duration_ns / 1e9 / len(paths)
+    return dict(sorted(by.items(), key=lambda kv: -kv[1][1]))
+
+
+def _last_xplane():
+    """The newest xplane under the directory the last ``start()`` traced
+    to, once that trace has stopped; None without one."""
+    xdir = _state["xplane_dir"]
+    if not xdir or _state["jax_trace_dir"]:
+        return None
+    files = glob.glob(os.path.join(xdir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return max(files, key=os.path.getmtime) if files else None
 
 
 def _reset_after_fork():
@@ -360,7 +566,14 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False,
     it only per-op duration rows make the table, so launches-per-step
     was invisible in the very output meant to summarize the trace
     (json: under the ``"dispatch_counts"`` key; table: a trailing
-    "Dispatch Counts" section)."""
+    "Dispatch Counts" section).  Where ``start()`` traced the device
+    (``MXNET_PROFILER_XPLANE_DIR``) and the trace has stopped, the output
+    also holds the device's own time by the program's scopes
+    (``device_time_by_scope``: an operator is ``op/<name>``; json: under
+    ``"device_time_by_scope"``; table: a trailing "Device time by scope"
+    section) — the rows above are a host clock around blocking calls."""
+    xplane = _last_xplane()
+    by_scope = device_time_by_scope(xplane) if xplane else {}
     with _records_lock:
         events = list(_records)
         if reset:
@@ -381,6 +594,10 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False,
                for name, (c, t, mn, mx) in agg.items()}
         if counts:
             out["dispatch_counts"] = counts
+        if by_scope:
+            out["device_time_by_scope"] = {
+                path: {"count": n, "total_ms": sec * 1e3}
+                for path, (n, sec) in by_scope.items()}
         return out
     lines = ["Profile Statistics:",
              f"{'Name':<40}{'Total Count':>12}{'Time (ms)':>14}"
@@ -397,6 +614,13 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False,
         lines.append(f"{'Kind':<40}{'Count':>12}")
         for kind in sorted(counts):
             lines.append(f"{kind:<40}{counts[kind]:>12}")
+    if by_scope:
+        whole = sum(sec for _n, sec in by_scope.values()) or 1.0
+        lines += ["", f"Device time by scope ({os.path.basename(xplane)}):",
+                  f"{'Scope':<72}{'Ops':>10}{'Time (ms)':>14}{'Share (%)':>11}"]
+        for path, (n, sec) in by_scope.items():
+            lines.append(f"{path:<72}{n:>10}{sec * 1e3:>14.4f}"
+                         f"{100 * sec / whole:>11.2f}")
     return "\n".join(lines)
 
 

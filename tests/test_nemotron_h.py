@@ -617,16 +617,17 @@ def test_named_scopes_are_in_the_step_program():
         text = step._step.lower(
             jax.random.PRNGKey(0), step._train_params, step._aux_params,
             step.opt_state, x, y).as_text(debug_info=True)
-    # the op's and the shared blocks' own scopes nest under the model's
+    # the op's and the shared blocks' own scopes nest under the model's,
+    # the registry's ``op/<name>`` between the model's and the op's own
     for scope in ("nemotron/mamba/granite/mamba/in_proj",
                   "nemotron/mamba/granite/mamba/conv",
                   "nemotron/mamba/granite/mamba/ssd",
                   "nemotron/mamba/granite/mamba/gated_norm",
                   "nemotron/mamba/granite/mamba/out_proj",
                   "nemotron/attention/granite/attention",
-                  "nemotron/moe/routed_experts/router",
-                  "nemotron/moe/routed_experts/dispatch",
-                  "nemotron/moe/routed_experts/experts",
+                  "nemotron/moe/op/_contrib_routed_experts/routed_experts/router",
+                  "nemotron/moe/op/_contrib_routed_experts/routed_experts/dispatch",
+                  "nemotron/moe/op/_contrib_routed_experts/routed_experts/experts",
                   "nemotron/moe/shared/relu2_mlp",
                   "nemotron/moe/combine", "nemotron/head"):
         assert scope in text, scope

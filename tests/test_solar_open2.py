@@ -383,13 +383,14 @@ def test_named_scopes_are_in_the_step_program():
         text = step._step.lower(
             jax.random.PRNGKey(0), step._train_params, step._aux_params,
             step.opt_state, x, y).as_text(debug_info=True)
-    # the op's and the shared blocks' own scopes nest under the model's
+    # the op's and the shared blocks' own scopes nest under the model's,
+    # the registry's ``op/<name>`` between the model's and the op's own
     for scope in ("solar/kda/proj", "solar/kda/conv", "solar/kda/gates",
                   "solar/kda/scan", "solar/kda/out",
                   "solar/attention/granite/attention",
-                  "solar/moe/routed_experts/router",
-                  "solar/moe/routed_experts/dispatch",
-                  "solar/moe/routed_experts/experts",
+                  "solar/moe/op/_contrib_routed_experts/routed_experts/router",
+                  "solar/moe/op/_contrib_routed_experts/routed_experts/dispatch",
+                  "solar/moe/op/_contrib_routed_experts/routed_experts/experts",
                   "solar/moe/shared/granite/mlp",
                   "solar/moe/combine", "solar/head"):
         assert scope in text, scope
