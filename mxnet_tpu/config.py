@@ -114,9 +114,6 @@ _register("MXNET_KVSTORE_PEER_TIMEOUT_S", float, 30.0,
           "marked lost, and every in-flight sync pull/barrier that "
           "needs it fails with typed PeerLostError instead of timing "
           "out against a corpse (docs/parallel.md)")
-_register("MXNET_OPTIMIZER_AGGREGATION_SIZE", int, 4,
-          "weights per aggregated multi_sgd_* dispatch in the SGD "
-          "optimizer (0 disables; parity: reference sgd.py)")
 _register("MXNET_KVSTORE_BIGARRAY_BOUND", int, 1000000,
           "arrays larger than this many elements are pushed/pulled in "
           "row chunks (parity: kvstore_dist.h:243 key sharding)")

@@ -6,6 +6,25 @@ via kvstore push/pull (trainer.py:356), then per-device fused updates
 multiple contexts the gradient reduction is an explicit cross-device mean
 (kvstore='local'/'device' semantics); SPMD data parallelism over a mesh lives
 in mxnet_tpu.parallel and plugs in through the same KVStore facade.
+
+The update is ONE jitted program per context and step: ``Optimizer.
+fused_update`` over every parameter of the step together, under the scope
+``step/optimizer``, with the step's learning rates and weight decays as two
+host ``float32`` arrays (``fused_step.host_hyperparams``).  A program is
+built once per key and kept on the trainer; the key is
+``optimizer.fused_static_signature()`` (what ``fused_update`` bakes in:
+``rescale_grad``, ``clip_gradient``, ``multi_precision``, momentum or the
+betas), the indices of the parameters taking part, and the structure of
+their states.  A learning rate, a weight decay or a scheduler's step is an
+argument and builds nothing.  The states stay ``Updater.states``, index by
+index; their buffers are donated, the weights' are not.  Which path a tensor
+takes is read from the step itself: the per-tensor updater call keeps a
+``RowSparseNDArray`` gradient, and every tensor of an optimizer without a
+``fused_update`` that states its current per-tensor update (NAG, RMSProp,
+LBSGD; Adam under ``multi_precision``; a subclass that overrides ``update``
+below the class that wrote ``fused_update``).  A parameter with
+``grad_req='null'``, a stale gradient or ``update_on_kvstore`` takes part in
+neither, as before.
 """
 from __future__ import annotations
 
@@ -13,6 +32,25 @@ from .. import optimizer as opt
 from ..base import MXNetError
 from ..ndarray import NDArray
 from .parameter import Parameter
+
+
+def _fused_update_is_current(optimizer):
+    """Whether ``optimizer.fused_update`` states the update the optimizer
+    makes per tensor: it exists, it implements the master-weight wrapper
+    if ``multi_precision`` asks for one, and no subclass below the class
+    that wrote it has overridden ``update`` or ``update_multi_precision``
+    since (a user's ``class MySGD(SGD)`` with its own ``update`` inherits
+    a ``fused_update`` that no longer says what it does)."""
+    if not callable(getattr(optimizer, "fused_update", None)):
+        return False
+    if optimizer.multi_precision and not optimizer.fused_multi_precision:
+        return False
+    mro = type(optimizer).__mro__
+
+    def depth(name):
+        return next(k for k, c in enumerate(mro) if name in vars(c))
+    return depth("fused_update") <= min(depth("update"),
+                                        depth("update_multi_precision"))
 
 
 class Trainer:
@@ -82,6 +120,10 @@ class Trainer:
         self._updaters = [opt.get_updater(self._optimizer)
                           for _ in self._contexts] or \
             [opt.get_updater(self._optimizer)]
+        self._one_program = _fused_update_is_current(self._optimizer)
+        # (fused_static_signature, parameter indices, state structure)
+        # -> jitted update; see _update_in_one_program
+        self._update_programs = {}
 
     def _reset_kvstore(self):
         self._kv_initialized = False
@@ -230,9 +272,11 @@ class Trainer:
 
     def _update(self, ignore_stale_grad=False):
         """Run the optimizer on every (param, ctx) pair
-        (parity: trainer.py:399)."""
-        import collections
-        pending = collections.defaultdict(list)
+        (parity: trainer.py:399): one program per context for the pairs
+        ``_one_program`` takes, the per-tensor updater call for the rest."""
+        from ..ndarray.sparse import BaseSparseNDArray
+        batched = [[] for _ in self._updaters]
+        calls = 0
         for i, param in enumerate(self._params):
             if param.grad_req == "null" or param._data is None:
                 continue
@@ -257,26 +301,77 @@ class Trainer:
             for j, (upd, arr, grad) in enumerate(
                     zip(self._updaters, param.list_data(),
                         param.list_grad())):
-                pending[j].append((i, grad, arr))
-        agg = getattr(self._optimizer, "aggregate_num", 0)
-        calls = 0
-        for j, triples in pending.items():
-            upd = self._updaters[j]
-            if agg and len(triples) > 1:
-                # multi-tensor dispatch: agg weights per updater call
-                # (reference trainer.py batches when aggregate_num > 0)
-                for k in range(0, len(triples), agg):
-                    chunk = triples[k:k + agg]
-                    upd([t[0] for t in chunk], [t[1] for t in chunk],
-                        [t[2] for t in chunk])
-                    calls += 1
-            else:
-                for i, grad, arr in triples:
+                if self._one_program and \
+                        not isinstance(grad, BaseSparseNDArray):
+                    batched[j].append((i, grad, arr))
+                else:
                     upd(i, grad, arr)
-                calls += len(triples)
+                    calls += 1
+        for upd, triples in zip(self._updaters, batched):
+            if triples:
+                self._update_in_one_program(upd, triples)
+                calls += 1
         from .. import telemetry as _telemetry
         if _telemetry.enabled():
             _telemetry.record_trainer_update_calls(calls)
+
+    def _update_in_one_program(self, updater, triples):
+        """Update every ``(index, grad, weight)`` of one context in ONE
+        jitted call of ``Optimizer.fused_update``.
+
+        The states are the ``updater.states`` the per-tensor call keeps
+        (``save_states``/``load_states`` see no difference); their buffers
+        are donated.  The weights are not: the pullback residuals of the
+        step just walked still hold them."""
+        import jax
+        from .. import engine, profiler
+        from ..fused_step import host_hyperparams
+        opt = self._optimizer
+        indices, grads, weights = zip(*triples)
+        for i, w in zip(indices, weights):
+            updater._ensure_state(i, w)
+        states, structure = jax.tree_util.tree_flatten(
+            [updater.states[i] for i in indices])
+        key = (opt.fused_static_signature(), indices, structure)
+        program = self._update_programs.get(key)
+        if program is None:
+            program = self._update_programs[key] = \
+                self._build_update_program(structure)
+        lrs, wds = host_hyperparams(opt, indices)
+        new_weights, new_states = program(
+            [w._data for w in weights], [g._data for g in grads],
+            [s._data for s in states], lrs, wds)
+        for arr, buf in zip(weights, new_weights):
+            arr._set_data(buf)
+        for arr, buf in zip(states, new_states):
+            arr._set_data(buf)
+        profiler.record_dispatch("trainer_update")
+        engine.get().on_compute(weights)
+
+    def _build_update_program(self, structure):
+        """``update(weights, grads, states, lrs, wds)`` for one key of
+        ``_update_in_one_program``; ``states`` are the leaves of
+        ``structure``.  What ``fused_update`` bakes in as constants is
+        the key's ``fused_static_signature``; the rates and decays are two
+        array arguments, so a schedule builds nothing."""
+        import jax
+        from .. import compile as _compile
+        from ..fused_step import hyper_scalars
+        _compile.ensure_persistent_cache()
+        _compile.record_trace(
+            "gluon_trainer_update",
+            "key-change" if self._update_programs else "build")
+        opt = self._optimizer
+
+        def update(weights, grads, states, lrs, wds):
+            states = jax.tree_util.tree_unflatten(structure, states)
+            with jax.named_scope("step/optimizer"):
+                new_weights, new_states = opt.fused_update(
+                    weights, grads, states,
+                    *hyper_scalars(lrs, wds, weights, states))
+            return new_weights, jax.tree_util.tree_leaves(new_states)
+
+        return jax.jit(update, donate_argnums=(2,))
 
     def save_states(self, fname):
         """Save optimizer/updater states (parity: trainer.py save_states).

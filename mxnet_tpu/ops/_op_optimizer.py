@@ -196,8 +196,7 @@ def _multi_all_finite(attrs, *arrays):
     return _all_finite(attrs, *arrays)
 
 
-# --- aggregated multi-tensor updates (reference: optimizer_op.cc:320-406,
-# MXNET_OPTIMIZER_AGGREGATION_SIZE) ------------------------------------------
+# --- aggregated multi-tensor updates (reference: optimizer_op.cc:320-406) ---
 # One op updates N weights in a single dispatch; XLA fuses the per-weight
 # elementwise updates into one kernel pass, which is exactly what the
 # reference's hand-rolled MultiSGDKernel buys on GPU.

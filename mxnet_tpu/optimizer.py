@@ -44,7 +44,6 @@ class Optimizer:
         self._index_update_count = {}
         self.clip_gradient = clip_gradient
         self.multi_precision = multi_precision
-        self.aggregate_num = 0
         if param_idx2name is None:
             param_idx2name = {}
         if not isinstance(param_idx2name, dict):
@@ -100,6 +99,10 @@ class Optimizer:
     # parameter boundaries, feeding per-element lr/wd vectors — only
     # legal when no cross-element math (LARS/LAMB norms) exists.
     fused_elementwise = False
+
+    # True when ``fused_update`` implements the float32 master copy that
+    # ``multi_precision`` keeps beside a half-precision weight.
+    fused_multi_precision = False
 
     def fused_hyperparams(self, indices):
         """Host-side per-step dynamic scalars for ``fused_update``:
@@ -241,11 +244,6 @@ class SGD(Optimizer):
         super().__init__(**kwargs)
         self.momentum = momentum
         self.lazy_update = lazy_update
-        # aggregated multi-tensor updates (reference sgd.py reads
-        # MXNET_OPTIMIZER_AGGREGATION_SIZE, default 4): N weights per
-        # multi_sgd_* dispatch — one fused XLA kernel pass instead of N
-        from .config import get as _cfg
-        self.aggregate_num = _cfg("MXNET_OPTIMIZER_AGGREGATION_SIZE")
 
     def create_state(self, index, weight):
         if self.momentum != 0.0:
@@ -296,6 +294,7 @@ class SGD(Optimizer):
             _invoke("mp_sgd_update", [weight, grad, w32], attrs, weight)
 
     fused_elementwise = True
+    fused_multi_precision = True
 
     def fused_update(self, params, grads, states, lr_t, wd_t):
         """Whole-pytree functional SGD step for the fused train step.
@@ -814,9 +813,6 @@ class LBSGD(SGD):
         kwargs.pop("multi_precision", None)
         super().__init__(momentum=momentum, **kwargs)
         self.eta = eta
-        # LARS scales lr per layer; the inherited multi_sgd_* aggregation
-        # would bypass that scaling — keep per-parameter updates
-        self.aggregate_num = 0
 
     def update(self, index, weight, grad, state):
         self._update_count(index)
@@ -902,7 +898,6 @@ class Updater:
         self.optimizer = optimizer
         self.states = {}
         self.states_synced = {}
-        self.aggregate_updates = optimizer.aggregate_num > 0
 
     def __call__(self, index, grad, weight):
         if isinstance(index, (list, tuple)):

@@ -101,9 +101,10 @@ _STEP_HOST_ARG_BYTES = REGISTRY.counter(
     "bytes of the leaves mxnet_step_host_arg_leaves counts, by step")
 _TRAINER_UPDATE_CALLS = REGISTRY.counter(
     "mxnet_trainer_update_calls_total",
-    "updater calls made by gluon.Trainer._update (one optimizer program "
-    "each, or one per aggregate_num tensors); counted only while "
-    "telemetry is enabled")
+    "optimizer programs launched by gluon.Trainer._update: one per "
+    "context for every dense tensor of the step, plus one per tensor that "
+    "took the per-tensor updater call; counted only while telemetry is "
+    "enabled")
 _CACHED_OP_AUX_OUTPUTS = REGISTRY.counter(
     "mxnet_cached_op_aux_outputs_total",
     "parameters a hybridized block's recorded forward mutated (BatchNorm's "

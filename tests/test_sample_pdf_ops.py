@@ -253,38 +253,51 @@ class TestAmpListsAreReal:
 
 
 class TestAggregatedOptimizer:
-    """multi_sgd_* aggregation (MXNET_OPTIMIZER_AGGREGATION_SIZE,
-    reference optimizer_op.cc:320 + sgd.py aggregate_num): training with
-    aggregated dispatches must match per-param updates exactly."""
+    """Multi-tensor updates: ``gluon.Trainer``'s one program over every
+    tensor and the list form of ``Updater.__call__`` (one ``multi_sgd_*``
+    dispatch, reference optimizer_op.cc:320) must both match per-param
+    updates exactly."""
 
-    def _train(self, monkeypatch, agg):
+    def _train(self, how):
         import mxnet_tpu as mxt
-        from mxnet_tpu import gluon, autograd
-        monkeypatch.setenv("MXNET_OPTIMIZER_AGGREGATION_SIZE", str(agg))
+        from mxnet_tpu import gluon, autograd, optimizer as opt
         mxt.random.seed(0)
         net = gluon.nn.Sequential()
-        net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dense(1))
+        net.add(gluon.nn.Dense(16, activation="relu", in_units=8),
+                gluon.nn.Dense(1, in_units=16))
         net.initialize(mxt.initializer.Xavier())
-        tr = gluon.Trainer(net.collect_params(), "sgd",
-                           {"learning_rate": 0.05, "momentum": 0.9,
-                            "wd": 1e-4})
+        kwargs = {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}
+        params = list(net.collect_params().values())
+        if how == "trainer":
+            tr = gluon.Trainer(params, "sgd", kwargs)
+        else:
+            sgd = opt.create("sgd", param_dict=dict(enumerate(params)),
+                             rescale_grad=1.0 / 32, **kwargs)
+            upd = opt.get_updater(sgd)
         rs = np.random.RandomState(3)
         X = nd.array(rs.randn(32, 8).astype(np.float32))
         Y = nd.array(rs.randn(32, 1).astype(np.float32))
         L = gluon.loss.L2Loss()
+        idx = list(range(len(params)))
         for _ in range(5):
             with autograd.record():
                 loss = L(net(X), Y)
             loss.backward()
-            tr.step(32)
-        return [p.data().asnumpy()
-                for p in net.collect_params().values()]
+            if how == "trainer":
+                tr.step(32)
+            elif how == "list":
+                upd(idx, [p.grad() for p in params],
+                    [p.data() for p in params])
+            else:
+                for i, p in enumerate(params):
+                    upd(i, p.grad(), p.data())
+        return [p.data().asnumpy() for p in params]
 
-    def test_aggregated_matches_sequential(self, monkeypatch):
-        pa = self._train(monkeypatch, 4)
-        pb = self._train(monkeypatch, 0)
-        for a, b in zip(pa, pb):
-            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    def test_aggregated_matches_sequential(self):
+        want = self._train("loop")
+        for how in ("trainer", "list"):
+            for a, b in zip(self._train(how), want):
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 class TestScalarRandomFamilyMoments:

@@ -241,13 +241,11 @@ def test_gluon_step_spans_share_one_step_id(enabled):
     assert all(ids == steps[0] for ids in steps)
     assert all(r["parent"] == "autograd/backward/walk"
                for r in _named("autograd/backward/dispatch"))
-    # 2 Dense layers x (weight, bias) = four tensors, aggregate_num of them
-    # per updater call: in the registry and in the update span's record
-    agg = getattr(trainer._optimizer, "aggregate_num", 0)
-    per_step = -(-4 // agg) if agg else 4
-    assert calls.value() - before == 3 * per_step
+    # 2 Dense layers x (weight, bias) = four tensors in one program a
+    # step: in the registry and in the update span's record
+    assert calls.value() - before == 3
     assert [r["counts"] for r in _named("gluon/trainer/update")] == \
-        [{"mxnet_trainer_update_calls_total": per_step}] * 3
+        [{"mxnet_trainer_update_calls_total": 1}] * 3
 
 
 def test_spmd_step_spans_and_counters(enabled):
