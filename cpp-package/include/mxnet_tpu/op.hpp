@@ -535,6 +535,11 @@ inline NDArray _contrib_rms_norm(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("_contrib_rms_norm", inputs, attrs).Invoke();
 }
+// operator _contrib_rotary_embedding — inputs: data, positions
+inline NDArray _contrib_rotary_embedding(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("_contrib_rotary_embedding", inputs, attrs).Invoke();
+}
 // operator _contrib_routed_experts — inputs: data, router_weight, w1, w3, w2
 inline std::vector<NDArray> _contrib_routed_experts(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
@@ -2179,6 +2184,11 @@ inline std::vector<NDArray> rmsprop_update(const std::vector<NDArray>& inputs,
 inline std::vector<NDArray> rmspropalex_update(const std::vector<NDArray>& inputs,
     const OpAttrs& attrs = {}) {
   return detail::Build("rmspropalex_update", inputs, attrs).InvokeMulti();
+}
+// operator rotary_embedding — inputs: data, positions
+inline NDArray rotary_embedding(const std::vector<NDArray>& inputs,
+    const OpAttrs& attrs = {}) {
+  return detail::Build("rotary_embedding", inputs, attrs).Invoke();
 }
 // operator round
 inline NDArray round(const std::vector<NDArray>& inputs,

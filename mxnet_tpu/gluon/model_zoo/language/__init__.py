@@ -9,3 +9,5 @@ from .solar_open2 import (KimiDeltaAttention, SolarDecoderLayer, SolarOpen2,
                           SparseExperts, balanced_bias, solar_open2)
 from .nemotron_h import *  # noqa: F401,F403
 from .nemotron_h import NemotronH, NemotronLayer, nemotron_h
+from .sdar_moe import *  # noqa: F401,F403
+from .sdar_moe import SDARDecoderLayer, SDARMoE, sdar_moe

@@ -134,23 +134,34 @@ class Mamba2Mixer(HybridBlock):
 
 
 class GroupedQueryAttention(HybridBlock):
-    """Causal self-attention with ``num_kv_heads`` key/value heads under
-    ``num_heads`` query heads, no bias and no positional encoding:
-    ``softmax(q kᵀ · scale) v`` through the flash kernel
-    (op ``_contrib_flash_attention``), then the output projection.  With
-    ``gate`` the heads' outputs are multiplied elementwise by
-    ``sigmoid(W_g h)`` before it."""
+    """Self-attention with ``num_kv_heads`` key/value heads under
+    ``num_heads`` query heads, no bias: ``softmax(q kᵀ · scale) v`` through
+    the flash kernel (op ``_contrib_flash_attention``), then the output
+    projection.  With ``gate`` the heads' outputs are multiplied
+    elementwise by ``sigmoid(W_g h)`` before it.
+
+    By default causal, with no positional encoding.  ``qk_norm`` (an
+    epsilon) norms every query and key head by an RMSNorm with a learned
+    weight of ``head_dim``; ``rotary`` (the base θ) then turns them by the
+    positions the block is CALLED with, ``block(h, positions)``
+    (op ``_contrib_rotary_embedding``).  ``mask`` and ``mask_block`` name
+    the kernel's mask (``ops.pallas_attention.Mask``); they are plain
+    attributes that a model may set between traces, and a
+    ``block_diffusion`` mask takes its ``half`` from the sequence it is
+    traced at."""
 
     # the flash kernel's tiles: (512, 64) query rows against (512, 64)
     # keys keep the grid at 8 × 8 steps a head at 4096 positions
     BLOCK = 512
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
-                 scale, gate=False, prefix=None, params=None):
+                 scale, gate=False, rotary=None, qk_norm=None, mask="causal",
+                 mask_block=1, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._heads, self._kv_heads = num_heads, num_kv_heads
         self._head_dim, self._scale = head_dim, float(scale)
-        self._hidden = hidden_size
+        self._hidden, self._rotary = hidden_size, rotary
+        self.mask, self.mask_block = mask, mask_block
         with self.name_scope():
             if gate:
                 self.g_weight = self.params.get(
@@ -163,19 +174,35 @@ class GroupedQueryAttention(HybridBlock):
                 "v_weight", shape=(num_kv_heads * head_dim, hidden_size))
             self.o_weight = self.params.get(
                 "o_weight", shape=(hidden_size, num_heads * head_dim))
+            self.q_norm = self.k_norm = None
+            if qk_norm is not None:
+                self.q_norm = RMSNorm(head_dim, qk_norm, prefix="q_norm_")
+                self.k_norm = RMSNorm(head_dim, qk_norm, prefix="k_norm_")
 
-    def hybrid_forward(self, F, h, q_weight, k_weight, v_weight, o_weight,
-                       g_weight=None):
-        def heads(w, n):   # (batch, T, n·d) -> (batch, n, T, d)
-            y = _dense(F, h, w, n * self._head_dim)
-            return F.transpose(
-                F.reshape(y, shape=(0, 0, n, self._head_dim)),
-                axes=(0, 2, 1, 3))
+    def hybrid_forward(self, F, h, positions=None, *, q_weight, k_weight,
+                       v_weight, o_weight, g_weight=None):
+        def heads(w, n, norm=None, turned=False):
+            """(batch, T, n·d) -> (batch, n, T, d)"""
+            y = F.reshape(_dense(F, h, w, n * self._head_dim),
+                          shape=(0, 0, n, self._head_dim))
+            if norm is not None:
+                with jax.named_scope("qk_norm"):
+                    y = norm(y)
+            y = F.transpose(y, axes=(0, 2, 1, 3))
+            if turned and self._rotary is not None:
+                with jax.named_scope("rope"):
+                    y = F.contrib.rotary_embedding(y, positions,
+                                                   base=self._rotary)
+            return y
 
+        # a block_diffusion mask's two copies are the halves of the sequence
+        half = h.shape[1] // 2 if self.mask == "block_diffusion" else 0
         with jax.named_scope("granite/attention"):
             out = F.contrib.flash_attention(
-                heads(q_weight, self._heads), heads(k_weight, self._kv_heads),
-                heads(v_weight, self._kv_heads), causal=True,
+                heads(q_weight, self._heads, self.q_norm, turned=True),
+                heads(k_weight, self._kv_heads, self.k_norm, turned=True),
+                heads(v_weight, self._kv_heads),
+                mask=self.mask, mask_block=self.mask_block, mask_half=half,
                 sm_scale=self._scale, block_q=self.BLOCK, block_k=self.BLOCK)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))

@@ -146,7 +146,9 @@ class SparseExperts(HybridBlock):
     ``_contrib_routed_experts``: nothing is dropped), and the shared expert
     is added.  ``form`` is the experts' (and the shared expert's):
     ``"gated_silu"``, three matrices, or ``"relu2"``, two; the shared
-    expert is ``shared_width`` wide (by default ``shared_experts × width``);
+    expert is ``shared_width`` wide (by default ``shared_experts × width``;
+    with ``shared_experts`` 0 there is none); ``score_function`` is the
+    router's, ``"sigmoid"`` or ``"softmax"`` over all experts;
     ``scope`` is the ``jax.named_scope`` its parts are traced under.
     Returns ``(y, load, rows)``: the assignments each held expert received
     and the rows the grouped products ran.
@@ -163,14 +165,16 @@ class SparseExperts(HybridBlock):
                  first_expert, top_k, shared_experts=1, scaling=1.0,
                  norm_topk=True, tile=256, form="gated_silu",
                  shared_width=None, select_bias=False, scope="solar/moe",
-                 prefix=None, params=None):
+                 score_function="sigmoid", prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._traced_as = scope
         self._attrs = dict(
             experts_total=experts_total, top_k=top_k,
             first_expert=first_expert, routed_scaling_factor=scaling,
             norm_topk_prob=norm_topk, tile=tile, expert_form=form,
-            select_bias=select_bias)
+            select_bias=select_bias, score_function=score_function)
+        if shared_width is None:
+            shared_width = shared_experts * width
         shared = {"gated_silu": GatedMLP, "relu2": Relu2MLP}[form]
         with self.name_scope():
             self.router_weight = self.params.get(
@@ -185,9 +189,8 @@ class SparseExperts(HybridBlock):
             if select_bias:
                 self.select_bias = self.params.get(
                     "select_bias", shape=(experts_total,), grad_req="null")
-            self.shared = shared(hidden_size,
-                                 shared_width or shared_experts * width,
-                                 prefix="shared_")
+            self.shared = shared(hidden_size, shared_width,
+                                 prefix="shared_") if shared_width else None
 
     def hybrid_forward(self, F, h, router_weight, w1, w2, w3=None,
                        select_bias=None):
@@ -196,6 +199,8 @@ class SparseExperts(HybridBlock):
         with jax.named_scope(self._traced_as):      # the op's own scopes nest
             y, load, rows, *counts = F.contrib.routed_experts(
                 *inputs, **self._attrs)
+        if self.shared is None:
+            return (y, load, rows, *counts)
         with jax.named_scope(self._traced_as + "/shared"):
             shared = self.shared(h)
         with jax.named_scope(self._traced_as + "/combine"):

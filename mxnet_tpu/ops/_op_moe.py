@@ -6,7 +6,8 @@ No reference analog: MXNet 1.x has no routed layer.  ``parallel/moe.py``
 is a top-1 router with a capacity; this op is the layer a deployment with
 experts over several chips runs on each of them, without the exchange:
 
-    s = sigmoid(W_r h)  over all ``experts_total`` outputs (float32, highest)
+    s = sigmoid(W_r h)  over all ``experts_total`` outputs (float32, highest),
+        or softmax(W_r h) over them (``score_function``)
     the chosen: top-k of s, or of s + b with a selection bias b (which
         chooses and never weighs: Wang et al. arXiv:2408.15664)
     w_e = s_e / Σ_chosen s · scaling   (``norm_topk``)
@@ -144,8 +145,13 @@ def _walk_bwd(form, top_k, tile, saved, dy):
 _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
-                   scaling=1.0, norm_topk=True, tile=256, select_bias=None):
+                   scaling=1.0, norm_topk=True, tile=256, select_bias=None,
+                   score_function="sigmoid"):
     """The held experts' part of a routed layer.  h (..., hidden);
     router_w (experts_total, hidden); w1 (E_here, width, hidden); w2
     (E_here, hidden, width); w3 like w1 for gated SiLU experts, None for
@@ -157,21 +163,25 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
     (experts_total,) the chosen are the top k of scores + bias, weighed by
     their scores alone, and a fourth output counts the assignments to each
     of ALL ``experts_total`` experts (the rule that balances the bias
-    needs the absent experts' load too).  No assignment is dropped
+    needs the absent experts' load too).  ``score_function`` is what
+    turns the router's outputs into scores: ``sigmoid``, each expert
+    alone, or ``softmax`` over all of them.  No assignment is dropped
     whatever the imbalance (see the module's head)."""
     total, hidden = router_w.shape
     n_held = w1.shape[0]
     if not (0 <= first_expert and first_expert + n_held <= total
-            and 1 <= top_k <= total and tile >= 1):
+            and 1 <= top_k <= total and tile >= 1
+            and score_function in SCORES):
         raise MXNetError(
             f"routed_experts: experts {first_expert}..{first_expert + n_held}"
-            f" of {total}, top {top_k}, tile {tile}")
+            f" of {total}, top {top_k}, tile {tile}, scores "
+            f"{score_function!r}")
     form, ups = ("relu2", (w1,)) if w3 is None else ("gated_silu", (w1, w3))
     x = h.reshape(-1, hidden)
     with jax.named_scope("routed_experts/router"):
         # in float32 at the highest precision: near-ties among 320 scores
         # must fall the same way wherever the product is computed
-        scores = jax.nn.sigmoid(jnp.matmul(
+        scores = SCORES[score_function](jnp.matmul(
             x.astype(jnp.float32), router_w.astype(jnp.float32).T,
             precision=lax.Precision.HIGHEST))
         if select_bias is None:     # the values top_k returns ARE the weights
@@ -229,4 +239,5 @@ def _routed_experts(attrs, h, router_w, *rest):
         int(attrs["top_k"]), int(attrs.get("first_expert", 0)),
         float(attrs.get("routed_scaling_factor", 1.0)),
         bool(attrs.get("norm_topk_prob", True)),
-        int(attrs.get("tile", 256)), given.get("select_bias"))
+        int(attrs.get("tile", 256)), given.get("select_bias"),
+        str(attrs.get("score_function", "sigmoid")))

@@ -46,6 +46,36 @@ def _rms_norm(attrs, x, gamma, *maybe_gate):
     return (h * gamma.astype(jnp.float32)).astype(x.dtype)
 
 
+# --- rotary positions ---------------------------------------------------------
+@register("_contrib_rotary_embedding", alias=("rotary_embedding",),
+          input_names=("data", "positions"))
+def _rotary_embedding(attrs, x, positions):
+    """Rotary position embedding over the whole head, rotate-half form (Su
+    et al. arXiv:2104.09864 as GPT-NeoX lays it out): with ``d`` the
+    trailing axis, ``θ_i = base^(−2i/d)`` for ``i < d/2`` and
+    ``[x1, x2]`` the head's two halves,
+
+        out = [x1 cos(pθ) − x2 sin(pθ),  x2 cos(pθ) + x1 sin(pθ)]
+
+    ``x`` (batch, heads, T, d); ``positions`` (T,) or (batch, T), an INPUT
+    and not ``0..T−1``: positions may repeat (block-diffusion training
+    lays two copies of a sequence side by side under the same positions).
+    Angles, sines and cosines are taken in float32."""
+    base = float(attrs.get("base", 10000.0))
+    d = x.shape[-1]
+    if d % 2 or positions.shape[-1] != x.shape[-2]:
+        raise MXNetError(f"rotary_embedding: data {x.shape}, positions "
+                         f"{positions.shape}")
+    inv_freq = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    if angle.ndim == 3:                 # (batch, T, d/2): over the heads
+        angle = angle[:, None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 # --- causal depthwise convolution over time -----------------------------------
 @register("_contrib_causal_conv1d", alias=("causal_conv1d",),
           input_names=("data", "weight", "bias"))

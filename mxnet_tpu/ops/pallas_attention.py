@@ -11,8 +11,13 @@ Design (canonical TPU flash pattern):
   grid = (batch·heads, S/BLOCK_Q, S/BLOCK_K); the innermost grid axis is
   sequential on TPU, so f32 scratch (acc, running max m, running sum l)
   persists across the K sweep — initialised at k==0, finalised (acc/l)
-  at the last k block.  Causal masking compares global q/k indices from
-  broadcasted_iota; fully-masked k blocks are skipped with @pl.when.
+  at the last k block.  What a query may see is ONE description, a
+  ``Mask``, that the three kernels, their index maps and the XLA
+  reference share: an element predicate over global q/k ids (compared
+  from broadcasted_iota) and a tile predicate; empty tiles are skipped
+  with @pl.when and their index maps point at a tile the sweep needs
+  anyway, so that a skipped grid step copies nothing in.  ``causal`` is
+  one kind of it (docs/kernels.md has the others and how to add one).
 
 Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q`` (a
 divisor of its head count); query head ``i`` reads key/value head
@@ -24,9 +29,8 @@ with ``lse = m + log(l)``, one float32 a query row, and whose backward is
 two more Pallas kernels over the same tiles.  Both recompute a tile's
 scores as the forward computes them, so ``P = exp(S - lse)`` is the
 forward's softmax, and form ``dS = P * (dO vᵀ - delta)`` with
-``delta = rowsum(dO * out)``; scores stay in VMEM and causal tiles above
-the diagonal are skipped, their index maps clamped so that a skipped
-grid step copies nothing in.
+``delta = rowsum(dO * out)``; scores stay in VMEM and the mask's empty
+tiles are skipped as in the forward.
   ``mx_flash_attention_bwd_dq``: grid (batch·heads, S/BLOCK_Q, S/BLOCK_K),
   ``dQ += dS k`` in float32 scratch across the key sweep.
   ``mx_flash_attention_bwd_dkv``: grid (batch·kv heads, S/BLOCK_K, group,
@@ -40,24 +44,154 @@ exercise the identical code path.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..telemetry import record_flash_attention_bwd_lowered
+from ..telemetry import (record_flash_attention_bwd_lowered,
+                         record_flash_attention_tiles)
 from ._pallas_rows import per_platform
 from .registry import register
 
 _NEG_INF = -1e30
 
 
+@dataclasses.dataclass(frozen=True)
+class Mask:
+    """Which keys a query may see, by global position ids (a query's and a
+    key's index on the sequence axis).  The one description the kernels,
+    their tile skipping and ``_reference_attention`` share; no (S, S)
+    array is ever built from it.
+
+    ``none``: every key.  ``causal``: ``k <= q``.  ``block_causal``: with
+    ``b(i) = i // block``, ``b(k) <= b(q)`` (a block sees itself whole and
+    what lies before it).  ``block_diffusion``: the sequence is two copies
+    of ``half`` positions, ids below ``half`` the clean copy and the rest
+    the noisy one, ``b(i) = (i mod half) // block``; a clean query sees the
+    clean keys with ``b(k) <= b(q)``, a noisy query the noisy keys of its
+    own block and the clean keys with ``b(k) < b(q)``, and no clean query
+    sees a noisy key (block-diffusion training: Arriola et al.
+    arXiv:2503.09573).
+
+    ``allowed`` and ``tile`` are written over ``//``, comparisons,
+    ``&``/``|`` and the three helpers below alone, so they take traced
+    scalars inside a kernel or an index map, iota arrays, and numpy arrays
+    on the host alike."""
+
+    kind: str = "none"
+    block: int = 1
+    half: int = 0
+
+    KINDS = ("none", "causal", "block_causal", "block_diffusion")
+
+    def __post_init__(self):
+        if self.kind not in self.KINDS or self.block < 1 or (
+                self.kind in ("none", "causal") and self.block != 1) or (
+                self.kind == "block_diffusion"
+                and (self.half < 1 or self.half % self.block)):
+            raise ValueError(f"flash_attention: no such mask: {self}")
+
+    @classmethod
+    def of(cls, spec):
+        """A ``Mask`` from what a caller may pass as ``causal``: a Mask, or
+        a bool (``causal`` or ``none``)."""
+        if isinstance(spec, cls):
+            return spec
+        return cls("causal" if spec else "none")
+
+    def _blocks(self, ids):
+        return ids if self.block == 1 else ids // self.block
+
+    def allowed(self, q_ids, k_ids):
+        """Elementwise: may query ``q_ids`` see key ``k_ids``; None for
+        the mask that allows everything."""
+        if self.kind == "none":
+            return None
+        if self.kind != "block_diffusion":
+            return self._blocks(k_ids) <= self._blocks(q_ids)
+        q_noisy, k_noisy = q_ids >= self.half, k_ids >= self.half
+        qb = self._blocks(q_ids - q_noisy * self.half)
+        kb = self._blocks(k_ids - k_noisy * self.half)
+        return ((~k_noisy & (kb <= qb) & ~(q_noisy & (kb == qb)))
+                | (q_noisy & k_noisy & (kb == qb)))
+
+    def tile(self, q0, q1, k0, k1):
+        """``(some, every)``: whether any, and whether every, pair of the
+        queries ``q0..q1`` and keys ``k0..k1`` (inclusive ids) is allowed.
+        A tile with ``some`` false is empty and is never computed."""
+        if self.kind == "none":
+            return True, True
+        if self.kind != "block_diffusion":
+            return (self._blocks(k0) <= self._blocks(q1),
+                    self._blocks(k1) <= self._blocks(q0))
+        t, blocks = self.half, self._blocks
+        # the clean and the noisy part of each range, as positions; a
+        # part that is not there has lo > hi and is guarded by has_*
+        has_qc, has_qn, has_kc, has_kn = q0 < t, q1 >= t, k0 < t, k1 >= t
+        qc1, kc1 = _least(q1, t - 1), _least(k1, t - 1)
+        qn0, kn0 = _most(q0, t) - t, _most(k0, t) - t
+        qn1, kn1 = q1 - t, k1 - t
+        cc, nc, nn = has_qc & has_kc, has_qn & has_kc, has_qn & has_kn
+        some = ((cc & (blocks(k0) <= blocks(qc1)))
+                | (nc & (blocks(k0) < blocks(qn1)))
+                | (nn & (blocks(kn0) <= blocks(qn1))
+                   & (blocks(qn0) <= blocks(kn1))))
+        every = (_no(has_qc & has_kn)
+                 & (_no(cc) | (blocks(kc1) <= blocks(q0)))
+                 & (_no(nc) | (blocks(kc1) < blocks(qn0)))
+                 & (_no(nn) | ((blocks(kn0) == blocks(qn1))
+                               & (blocks(qn0) == blocks(kn1)))))
+        return some, every
+
+    def tile_counts(self, s, bq, bk):
+        """``{"empty", "partial", "full"}``: the (query tile, key tile)
+        pairs of one head at ``s`` positions, on the host."""
+        s_pad = _round_up(s, math.lcm(bq, bk))
+        q0 = np.arange(0, s_pad, bq)[:, None]
+        k0 = np.arange(0, s_pad, bk)[None, :]
+        some, every = (np.broadcast_to(a, (q0.size, k0.size)) for a in
+                       self.tile(q0, q0 + bq - 1, k0, k0 + bk - 1))
+        # a tile that holds padded keys is masked there whatever the kind
+        every = every & (k0 + bk <= s)
+        return {"empty": int((~some).sum()), "full": int(every.sum()),
+                "partial": int((some & ~every).sum())}
+
+
+def _least(a, b):
+    return (jnp if isinstance(a, jax.Array) else np).minimum(a, b)
+
+
+def _most(a, b):
+    return (jnp if isinstance(a, jax.Array) else np).maximum(a, b)
+
+
+def _no(a):
+    return (jnp if isinstance(a, jax.Array) else np).logical_not(a)
+
+
+def _tile_runs(mask, q_start, block_q, k_start, block_k):
+    """Whether the kernels compute this tile (a Python True for the mask
+    that allows everything)."""
+    return mask.tile(q_start, q_start + block_q - 1, k_start,
+                     k_start + block_k - 1)[0]
+
+
+def _visible(mask, q_ids, k_ids, s_actual):
+    """The element mask of a tile: padded keys, and what ``mask`` bars."""
+    seen = k_ids < s_actual
+    allowed = mask.allowed(q_ids, k_ids)
+    return seen if allowed is None else seen & allowed
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                  acc_ref, m_ref, l_ref, *,
-                 block_q, block_k, s_actual, sm_scale, causal):
+                 block_q, block_k, s_actual, sm_scale, mask):
     """One (q-block, k-block) grid step of online-softmax attention."""
     kb = pl.program_id(2)
     n_kb = pl.num_programs(2)
@@ -71,12 +205,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
     q_start = pl.program_id(1) * block_q
     k_start = kb * block_k
 
-    # causal: a k block strictly above the diagonal contributes nothing
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
-
-    @pl.when(run)
+    # an empty tile (causal: a k block strictly above the diagonal)
+    # contributes nothing.  A row whose keys so far were all barred has
+    # m = -1e30 and sums garbage; its first visible key brings a finite m
+    # and the correction exp(-1e30 - m) = 0 wipes that, and every row
+    # sees its own key at the latest
+    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # (BQ, D)
         k = k_ref[0].astype(jnp.float32)            # (BK, D)
@@ -88,10 +222,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
             jnp.int32, (block_q, block_k), 0)
         k_ids = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        mask = k_ids < s_actual                      # padded keys
-        if causal:
-            mask &= k_ids <= q_ids
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(_visible(mask, q_ids, k_ids, s_actual), s, _NEG_INF)
 
         m_prev = m_ref[:, :1]                        # (BQ, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -139,9 +270,32 @@ def _pad_seq(s_pad, *arrays):
                          + [(0, 0)] * (a.ndim - 3)) for a in arrays)
 
 
+def _anchored(mask, bq, bk):
+    """The index maps' rule for an empty tile: the block index of the
+    swept axis where the tile is computed, else that of a tile the sweep
+    computes anyway, so that consecutive skipped steps (and the computed
+    one beside them) name one block and nothing is copied in.  A key
+    sweep is anchored at the key tile of the query tile's last row, a
+    query sweep at the query tile of the key tile's first row: both hold
+    a query's own key, which every kind allows.
+
+    Returns ``(key_block(qi, ki), query_block(ki, qi))``."""
+    if mask.kind == "none":
+        return (lambda qi, ki: ki), (lambda ki, qi: qi)
+
+    def runs(qi, ki):
+        return _tile_runs(mask, qi * bq, bq, ki * bk, bk)
+
+    return (lambda qi, ki: jnp.where(runs(qi, ki), ki,
+                                     (qi * bq + bq - 1) // bk),
+            lambda ki, qi: jnp.where(runs(qi, ki), qi, ki * bk // bq))
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
                                              "block_q", "block_k"))
 def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
+    """``causal``, here and below: a bool or a ``Mask``."""
+    mask = Mask.of(causal)
     b, h, s, d = q.shape
     group = h // k.shape[1]       # query heads per key/value head
     bq, bk, s_pad = _tiles(s, block_q, block_k)
@@ -153,8 +307,9 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
 
     kernel = functools.partial(
         _attn_kernel, block_q=bq, block_k=bk, s_actual=s,
-        sm_scale=sm_scale, causal=causal)
+        sm_scale=sm_scale, mask=mask)
     grid = (bh, s_pad // bq, s_pad // bk)
+    key_block, _ = _anchored(mask, bq, bk)
     scratch_shapes = [
         pltpu.VMEM((bq, d), jnp.float32),       # acc
         pltpu.VMEM((bq, 128), jnp.float32),     # running max (lane-bcast)
@@ -163,8 +318,8 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
 
     q_spec = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
     # flat q index = batch * h + head, so // group is batch * h_kv + kv head
-    kv_spec = pl.BlockSpec((1, bk, d),
-                           lambda bh_, qi, ki: (bh_ // group, ki, 0))
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda bh_, qi, ki: (bh_ // group, key_block(qi, ki), 0))
     stat_spec = pl.BlockSpec((1, bq, 128), lambda bh_, qi, ki: (bh_, qi, 0))
 
     def call(interpret, qf, kf, vf):
@@ -203,15 +358,15 @@ def _check_heads(q, k, v):
 def _reference_attention(q, k, v, causal, sm_scale):
     """Plain XLA attention: the (S, S) scores in HBM.  k, v: (batch, h_kv,
     S, d) with h_kv dividing q's head count."""
+    mask = Mask.of(causal)
     b, h, s, d = q.shape
     h_kv = k.shape[1]
     qg = q.astype(jnp.float32).reshape(b, h_kv, h // h_kv, s, d)
     logits = jnp.einsum("bkgqd,bksd->bkgqs", qg,
                         k.astype(jnp.float32)) * sm_scale
-    if causal:
-        qi = jnp.arange(s)[:, None]
-        ki = jnp.arange(s)[None, :]
-        logits = jnp.where(ki <= qi, logits, _NEG_INF)
+    allowed = mask.allowed(jnp.arange(s)[:, None], jnp.arange(s)[None, :])
+    if allowed is not None:
+        logits = jnp.where(allowed, logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
     return out.reshape(b, h, s, d).astype(q.dtype)
@@ -250,7 +405,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 
     q: (batch, heads, seq, head_dim); k, v the same or with fewer heads
     (grouped queries: a divisor of q's head count).  sm_scale defaults
-    to 1/sqrt(head_dim).
+    to 1/sqrt(head_dim).  ``causal`` is a bool or a ``Mask`` (static:
+    the kernels are built for it).
 
     ``sm_scale`` is a STATIC kernel parameter (baked into the pallas
     grid function), so it must be a python scalar, never a traced
@@ -262,14 +418,18 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
 
 def _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     _check_heads(q, k, v)
-    return _flash_fwd(q, k, v, causal=causal,
+    mask = Mask.of(causal)
+    # a fact about the program, taken as it is traced
+    record_flash_attention_tiles(mask.kind, mask.tile_counts(
+        q.shape[2], *_tiles(q.shape[2], block_q, block_k)[:2]))
+    return _flash_fwd(q, k, v, causal=mask,
                       sm_scale=_static_sm_scale(sm_scale, q.shape[-1]),
                       block_q=block_q, block_k=block_k)
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
     out, m, l = _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)
-    # every row sees a key (its own when causal), so l > 0
+    # every row sees a key (its own, under every kind of mask), so l > 0
     return out, (q, k, v, out, m + jnp.log(l))
 
 
@@ -278,7 +438,7 @@ _NN = (((1,), (0,)), ((), ()))    # a b
 
 
 def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
-               s_actual, sm_scale, causal):
+               s_actual, sm_scale, mask):
     """``P`` and ``P * (dP - delta)`` of one tile, laid out (query, key)
     for ``q_axis`` 0 and (key, query) for 1; ``lse`` and ``delta`` hold
     one value a query and broadcast along the key axis.  The scores are
@@ -294,10 +454,8 @@ def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
     q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                1 - q_axis)
-    mask = k_ids < s_actual                          # padded keys
-    if causal:
-        mask &= k_ids <= q_ids
-    p = jnp.exp(jnp.where(mask, s, _NEG_INF) - lse)
+    p = jnp.exp(jnp.where(_visible(mask, q_ids, k_ids, s_actual), s,
+                          _NEG_INF) - lse)
     # Mosaic's product of float32 operands is one bfloat16 pass, and what
     # dO loses to it is one error for a query's whole row of dP, which the
     # sums over the keys do not average out: a float32 dO goes in as two
@@ -313,7 +471,7 @@ def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, block_q, block_k, s_actual, sm_scale, causal):
+                   acc_ref, *, block_q, block_k, s_actual, sm_scale, mask):
     """One (q-block, k-block) grid step of ``dQ = scale · Σ_k dS k``."""
     kb = pl.program_id(2)
 
@@ -323,17 +481,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     q_start = pl.program_id(1) * block_q
     k_start = kb * block_k
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
 
-    @pl.when(run)
+    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
     def _compute():
         k = k_ref[0]
         _, ds = _tile_p_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
             delta_ref[0][:, :1], q_start, k_start, q_axis=0,
-            s_actual=s_actual, sm_scale=sm_scale, causal=causal)
+            s_actual=s_actual, sm_scale=sm_scale, mask=mask)
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
@@ -344,7 +499,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, block_q, block_k, s_actual,
-                    sm_scale, causal):
+                    sm_scale, mask):
     """One (k-block, query head of the group, q-block) grid step of
     ``dV = Σ Pᵀ dO`` and ``dK = scale · Σ dSᵀ q``, the tile transposed."""
     g, qb = pl.program_id(2), pl.program_id(3)
@@ -356,17 +511,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     q_start = qb * block_q
     k_start = pl.program_id(1) * block_k
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
 
-    @pl.when(run)
+    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
     def _compute():
         q, do = q_ref[0], do_ref[0]
         p, ds = _tile_p_ds(
             q, k_ref[0], v_ref[0], do, lse_ref[0, 0], delta_ref[0, 0],
             q_start, k_start, q_axis=1, s_actual=s_actual, sm_scale=sm_scale,
-            causal=causal)
+            mask=mask)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
         dk_acc[:] += jax.lax.dot_general(
@@ -383,6 +535,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     """``(dq, dk, dv)`` with the sequence still padded to the tiles: rows
     past ``s`` are zero and the caller cuts them off."""
+    mask = Mask.of(causal)
     b, h, s, d = q.shape
     h_kv = k.shape[1]
     group = h // h_kv
@@ -395,17 +548,16 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     qf, dof = q.reshape(bh, s_pad, d), do.reshape(bh, s_pad, d)
     kf, vf = (a.reshape(b * h_kv, s_pad, d) for a in (k, v))
     params = dict(block_q=bq, block_k=bk, s_actual=s, sm_scale=sm_scale,
-                  causal=causal)
+                  mask=mask)
     n_qb, n_kb = s_pad // bq, s_pad // bk
+    # a skipped step names a block the sweep needs anyway
+    key_block, query_block = _anchored(mask, bq, bk)
 
     # dq: statistics one value a row, broadcast over a lane tile as the
-    # forward writes its own; a skipped step keeps the last block needed
-    def last_kb(qi, ki):
-        return jnp.minimum(ki, (qi * bq + bq - 1) // bk) if causal else ki
-
+    # forward writes its own
     q_spec = pl.BlockSpec((1, bq, d), lambda i, qi, ki: (i, qi, 0))
     kv_spec = pl.BlockSpec(
-        (1, bk, d), lambda i, qi, ki: (i // group, last_kb(qi, ki), 0))
+        (1, bk, d), lambda i, qi, ki: (i // group, key_block(qi, ki), 0))
     col_spec = pl.BlockSpec((1, bq, 128), lambda i, qi, ki: (i, qi, 0))
     cols = [jnp.broadcast_to(a.reshape(bh, s_pad, 1), (bh, s_pad, 128))
             for a in (lse, delta)]
@@ -425,17 +577,14 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     dq = per_platform(dq_call, qf, kf, vf, dof, *cols)
 
     # dk, dv: flat query head = (batch · h_kv + kv head) · group + g
-    def first_qb(ki, qi):
-        return jnp.maximum(qi, ki * bk // bq) if causal else qi
-
     q_spec = pl.BlockSpec(
-        (1, bq, d), lambda i, ki, g, qi: (i * group + g, first_qb(ki, qi), 0))
+        (1, bq, d), lambda i, ki, g, qi: (i * group + g, query_block(ki, qi), 0))
     kv_spec = pl.BlockSpec((1, bk, d), lambda i, ki, g, qi: (i, ki, 0))
     # one row of bq statistics a block: the block's last two dimensions are
     # the array's, whatever bq is
     row_spec = pl.BlockSpec(
         (1, 1, 1, bq),
-        lambda i, ki, g, qi: (i * group + g, first_qb(ki, qi), 0, 0))
+        lambda i, ki, g, qi: (i * group + g, query_block(ki, qi), 0, 0))
     rows = [a.reshape(bh, n_qb, 1, bq) for a in (lse, delta)]
 
     def dkv_call(interpret, *operands):
@@ -472,9 +621,12 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @register("_contrib_flash_attention", alias=("flash_attention",))
 def _contrib_flash_attention(attrs, q, k, v):
-    causal = bool(attrs.get("causal", False))
+    # ``causal=True`` is the alias of ``mask="causal"``
+    mask = Mask(str(attrs["mask"]), int(attrs.get("mask_block", 1)),
+                int(attrs.get("mask_half", 0))) if "mask" in attrs \
+        else Mask.of(bool(attrs.get("causal", False)))
     sm_scale = attrs.get("sm_scale")
     sm_scale = float(sm_scale) if sm_scale is not None else None
-    return flash_attention(q, k, v, causal, sm_scale,
+    return flash_attention(q, k, v, mask, sm_scale,
                            int(attrs.get("block_q", 128)),
                            int(attrs.get("block_k", 128)))

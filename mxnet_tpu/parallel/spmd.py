@@ -245,6 +245,11 @@ class TrainStep:
         for x, y in data:
             loss = step(x, y)        # params/opt state live sharded on device
 
+    With a third example array, ``example_batch=(x, y, w)``, every call is
+    ``step(x, y, w)`` and ``w`` reaches a Gluon loss as its
+    ``sample_weight`` (a weight a position, zero where a position carries
+    no loss), sharded like the other two.
+
     The whole step is ONE pjit'd XLA program; gradient reduction over 'dp'
     and (with param_axis='fsdp') parameter all-gathers are XLA collectives.
     """
@@ -284,7 +289,15 @@ class TrainStep:
             raise MXNetError("mesh must be a parallel.DeviceMesh")
         self.mesh = mesh
         self.block = block
-        x_ex, y_ex = example_batch
+        x_ex, _y_ex, *w_ex = example_batch
+        # how many batch arrays a call takes: data, label and, where the
+        # example had one, the loss's sample weight
+        self._n_batch = 2 + len(w_ex)
+        if len(w_ex) > 1 or (w_ex and
+                             not hasattr(loss_fn, "hybrid_forward")):
+            raise MXNetError(
+                "example_batch is (data, label) or (data, label, "
+                "sample_weight), the last for a gluon loss")
         fb = functionalize(block, x_ex)
         apply_fn, param_arrays, names = fb
         if dtype is not None:
@@ -335,11 +348,11 @@ class TrainStep:
             tuple(jax.device_put(s, sh) for s in opt_init(a))
             for a, sh in zip(self._train_params, train_sh))
 
-        def loss_raw(pred, label):
+        def loss_raw(pred, label, *weight):
             if hasattr(loss_fn, "hybrid_forward"):
                 from ..context import current_context
-                l = loss_fn(NDArray(pred, current_context()),
-                            NDArray(label, current_context()))
+                l = loss_fn(*(NDArray(a, current_context())
+                              for a in (pred, label, *weight)))
                 return l._data.mean()
             return loss_fn(pred, label)
 
@@ -356,7 +369,7 @@ class TrainStep:
         whole_remat = self.remat and not remat_layers
 
         def make_step(grad_sync):
-            def step(key, train_params, aux_params, opt_state, x, y):
+            def step(key, train_params, aux_params, opt_state, x, y, *w):
                 def fwd(tps, x_):
                     ps = merge_params(train_idx, aux_idx, tps, aux_params)
                     with _ag.train_mode(), remat_scope(remat_layers) as sc:
@@ -372,7 +385,7 @@ class TrainStep:
                 def compute_loss(tps):
                     pred, mutated = fwd(tps, x)
                     with jax.named_scope("step/loss"):
-                        return loss_raw(pred, y), mutated
+                        return loss_raw(pred, y, *w), mutated
 
                 (loss, mutated), grads = jax.value_and_grad(
                     compute_loss, has_aux=True)(train_params)
@@ -407,8 +420,8 @@ class TrainStep:
             # XLA inserts the dp psum for grads and fsdp all-gathers
             self._step = jax.jit(
                 make_step(None),
-                in_shardings=(None, train_sh, aux_sh, state_sh,
-                              batch_sh, batch_sh),
+                in_shardings=(None, train_sh, aux_sh, state_sh)
+                + (batch_sh,) * self._n_batch,
                 donate_argnums=(1, 2, 3))
         else:
             # explicit-collective formulation: the same step body runs as
@@ -446,8 +459,8 @@ class TrainStep:
             smapped = shard_map(
                 make_step(grad_sync), mesh=mesh.jax_mesh,
                 in_specs=(P(), tuple(P() for _ in self._train_idx),
-                          tuple(P() for _ in self._aux_idx), state_spec,
-                          P(batch_axis), P(batch_axis)),
+                          tuple(P() for _ in self._aux_idx), state_spec)
+                + (P(batch_axis),) * self._n_batch,
                 out_specs=(tuple(P() for _ in self._train_idx),
                            tuple(P() for _ in self._aux_idx),
                            state_spec, P()),
@@ -460,19 +473,27 @@ class TrainStep:
         return merge_params(self._train_idx, self._aux_idx,
                             self._train_params, self._aux_params)
 
-    def __call__(self, x, y):
-        """Run one step; returns scalar loss (host float on .item())."""
+    def __call__(self, x, y, w=None):
+        """Run one step on ``(x, y)`` or, for a step built with a third
+        example array, ``(x, y, sample_weight)``; returns scalar loss
+        (host float on .item())."""
         from .. import telemetry as _telemetry
+        batch = (x, y) if w is None else (x, y, w)
+        if len(batch) != self._n_batch:
+            raise MXNetError(f"this step takes {self._n_batch} batch "
+                             f"arrays, {len(batch)} given")
         _telemetry.next_step()   # the spans below share this step's id
         with _telemetry.span("spmd/step"):
             with _telemetry.span("spmd/step/shard_batch"):
-                xs, ys = (a if isinstance(a, jax.Array)
-                          else shard_batch(self.mesh, a) for a in (x, y))
+                placed = tuple(a if isinstance(a, jax.Array)
+                               else shard_batch(self.mesh, a) for a in batch)
                 _telemetry.record_io_stage_bytes(sum(
-                    s.nbytes for a, s in ((x, xs), (y, ys)) if s is not a))
+                    s.nbytes for a, s in zip(batch, placed) if s is not a))
+                if isinstance(w, np.ndarray):
+                    _telemetry.record_loss_weights(w)
             with _telemetry.span("spmd/step/prepare"):
                 args = (_random.next_key(), self._train_params,
-                        self._aux_params, self.opt_state, xs, ys)
+                        self._aux_params, self.opt_state, *placed)
                 host_args = _telemetry.host_arg_stats(
                     args, set(self.mesh.jax_mesh.devices.flat)) \
                     if _telemetry.enabled() else None

@@ -156,6 +156,21 @@ _FLASH_BWD_LOWERED = REGISTRY.counter(
     "traced, by the implementation it put in the program: impl=pallas "
     "(the mx_flash_attention_bwd_* kernels) or impl=xla; one per attention "
     "layer a traced train step, none when a cached program runs")
+_FLASH_TILES = REGISTRY.gauge(
+    "mxnet_flash_attention_tiles",
+    "(query tile, key tile) pairs a head of the last traced "
+    "ops.pallas_attention.flash_attention call, by its mask's kind and by "
+    "kind=empty (skipped: not computed, nothing copied in), partial "
+    "(computed and masked inside) or full")
+_DIFFUSION_POSITIONS = REGISTRY.counter(
+    "mxnet_diffusion_positions_total",
+    "positions of the per-position loss weights handed to "
+    "parallel.spmd.TrainStep as its third batch array (block-diffusion "
+    "training: the noisy copy's positions)")
+_DIFFUSION_MASKED = REGISTRY.counter(
+    "mxnet_diffusion_masked_positions_total",
+    "of those, the positions whose weight is not zero: the masked ones, "
+    "which carry loss")
 _MOE_ASSIGNMENTS = REGISTRY.gauge(
     "mxnet_moe_assignments_held",
     "(token, expert) assignments the routed-expert layers sent to the "
@@ -279,6 +294,20 @@ def record_flash_attention_bwd_lowered(impl):
     """Account one trace of flash attention's backward rule; ``impl`` is
     ``pallas`` or ``xla``."""
     _FLASH_BWD_LOWERED.inc(1, labels={"impl": impl})
+
+
+def record_flash_attention_tiles(mask, counts):
+    """Record the tile pairs of one traced flash attention call: ``mask``
+    its mask's kind, ``counts`` ``{"empty", "partial", "full"}``."""
+    for kind, n in counts.items():
+        _FLASH_TILES.set(n, labels={"mask": mask, "kind": kind})
+
+
+def record_loss_weights(weights):
+    """Account the per-position loss weights of one train step from inside
+    ``spmd/step/shard_batch``: a host array as the caller handed it."""
+    count_in_span(_DIFFUSION_POSITIONS, int(weights.size))
+    count_in_span(_DIFFUSION_MASKED, int((weights != 0).sum()))
 
 
 def record_moe_load(load, rows, steps=1, bias=None):

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu.ops.pallas_attention import flash_attention
+from mxnet_tpu.ops.pallas_attention import Mask, flash_attention
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +36,7 @@ def one_chip():
 
 
 # (query heads, key/value heads, positions, head size, dtype, block_q,
-# block_k, causal)
+# block_k, causal: a bool or a Mask)
 @pytest.mark.parametrize("h,h_kv,s,d,dtype,block_q,block_k,causal", [
     (32, 2, 8192, 128, "float32", 512, 512, True),     # Nemotron's layer
     (8, 1, 8192, 128, "float32", 512, 512, True),      # Solar's
@@ -50,6 +50,11 @@ def one_chip():
     (2, 2, 256, 32, "float32", 64, 128, True),
     (2, 2, 512, 32, "float32", 256, 128, False),
     (2, 2, 256, 32, "float32", 96, 128, True),         # no lane multiple
+    # SDAR's layer: [x0 ; xt] of 8192 tokens under the block-diffusion mask
+    (32, 4, 16384, 128, "float32", 512, 512,
+     Mask("block_diffusion", 4, 8192)),
+    (4, 2, 272, 16, "float32", 128, 128, Mask("block_diffusion", 4, 136)),
+    (4, 2, 200, 16, "bfloat16", 128, 64, Mask("block_causal", 4)),
 ])
 def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
                                                 dtype, block_q, block_k,

@@ -171,3 +171,66 @@ def test_gluon_moe_dense_with_in_units_initializes_fully():
     assert layer.w2.data().shape == (2, 4, 6)
     assert layer.b2.data().shape == (2, 6)
     assert layer.gate_weight.data().shape == (6, 2)
+
+
+# -- the routed-expert op's score function (ops/_op_moe.py) -----------------------
+def _routed_loop(h, router, w1, w3, w2, top_k, first, score):
+    """Every token through every held expert, then a mask: the plain way."""
+    scores = score(np.asarray(h, np.float64) @ np.asarray(router,
+                                                          np.float64).T)
+    order = np.argsort(-scores, axis=-1, kind="stable")[:, :top_k]
+    y = np.zeros(h.shape)
+    for n in range(h.shape[0]):
+        chosen = scores[n, order[n]]
+        for e, weight in zip(order[n], chosen / chosen.sum()):
+            if first <= e < first + w1.shape[0]:
+                a, b = w1[e - first] @ h[n], w3[e - first] @ h[n]
+                y[n] += weight * (w2[e - first] @ (a / (1 + np.exp(-a)) * b))
+    return y, order
+
+
+@pytest.mark.parametrize("score_function", ["sigmoid", "softmax"])
+def test_routed_experts_score_function(score_function):
+    """``sigmoid`` scores each expert alone, ``softmax`` all of them
+    together: the same walk over the same tiles, other weights (and, away
+    from ties, the same choice: both are monotone in the router's
+    outputs)."""
+    from mxnet_tpu.ops._op_moe import routed_experts
+    rng = np.random.default_rng(7)
+    h = rng.standard_normal((21, 16)).astype(np.float32)
+    router = rng.standard_normal((16, 16)).astype(np.float32)
+    w1, w3 = (rng.standard_normal((4, 12, 16)).astype(np.float32) * 0.3
+              for _ in range(2))
+    w2 = rng.standard_normal((4, 16, 12)).astype(np.float32) * 0.3
+    score = {"sigmoid": lambda z: 1 / (1 + np.exp(-z)),
+             "softmax": lambda z: np.exp(z - z.max(-1, keepdims=True))
+             / np.exp(z - z.max(-1, keepdims=True)).sum(-1, keepdims=True)
+             }[score_function]
+    want, order = _routed_loop(h, router, w1, w3, w2, 3, 4, score)
+    with jax.default_matmul_precision("highest"):
+        y, load, _rows = routed_experts(
+            jnp.asarray(h), jnp.asarray(router), jnp.asarray(w1),
+            jnp.asarray(w3), jnp.asarray(w2), 3, 4, tile=4,
+            score_function=score_function)
+        other, other_load, _ = routed_experts(
+            jnp.asarray(h), jnp.asarray(router), jnp.asarray(w1),
+            jnp.asarray(w3), jnp.asarray(w2), 3, 4, tile=4,
+            score_function="softmax" if score_function == "sigmoid"
+            else "sigmoid")
+    np.testing.assert_allclose(y, want, rtol=1e-4,
+                               atol=1e-5 * float(np.abs(want).max()))
+    np.testing.assert_array_equal(
+        load, [(order == e).sum() for e in range(4, 8)])
+    # the choice is the same, the weights are not
+    np.testing.assert_array_equal(load, other_load)
+    assert np.abs(np.asarray(y) - np.asarray(other)).max() > 1e-3
+
+
+def test_routed_experts_refuse_an_unknown_score_function():
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    h, router = nd.ones((5, 8)), nd.ones((4, 8))
+    w1 = w3 = nd.ones((2, 6, 8))
+    with pytest.raises(mx.MXNetError, match="scores 'tanh'"):
+        nd.contrib.routed_experts(h, router, w1, w3, nd.ones((2, 8, 6)),
+                                  top_k=2, score_function="tanh")

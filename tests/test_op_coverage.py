@@ -638,6 +638,7 @@ EXEMPT = {
     "_contrib_ssd_scan": "test_granite_hybrid.py",
     "_contrib_kda_scan": "test_solar_open2.py",
     "_contrib_routed_experts": "test_solar_open2.py",
+    "_contrib_rotary_embedding": "test_sdar_moe.py",
     "_contrib_boolean_mask": "test_op_gap_r4.py",
     "_contrib_arange_like": "test_contrib_ops2.py",
     "Crop": "test_spatial_ops.py",
