@@ -7,17 +7,23 @@ This kernel computes softmax(q·kᵀ)·v with the online-softmax recurrence:
 scores never leave VMEM, HBM traffic is O(S·D) instead of O(S²), and the
 MXU sees (BLOCK_Q × D) @ (D × BLOCK_K) tiles.
 
-Design (canonical TPU flash pattern):
-  grid = (batch·heads, S/BLOCK_Q, S/BLOCK_K); the innermost grid axis is
-  sequential on TPU, so f32 scratch (acc, running max m, running sum l)
-  persists across the K sweep — initialised at k==0, finalised (acc/l)
-  at the last k block.  What a query may see is ONE description, a
-  ``Mask``, that the three kernels, their index maps and the XLA
-  reference share: an element predicate over global q/k ids (compared
-  from broadcasted_iota) and a tile predicate; empty tiles are skipped
-  with @pl.when and their index maps point at a tile the sweep needs
-  anyway, so that a skipped grid step copies nothing in.  ``causal`` is
-  one kind of it (docs/kernels.md has the others and how to add one).
+Design (canonical TPU flash pattern, over a table of tiles):
+  What a query may see is ONE description, a ``Mask``: an element
+  predicate over global q/k ids and a tile predicate.  The mask, the
+  length and the tiles of a call are static, so the host knows every
+  (query tile, key tile) pair's kind before the program is lowered:
+  ``tile_table`` lists the COMPUTED pairs in sweep order, each with
+  whether it opens and closes its sweep and whether it is FULL (no
+  barred pair, no padded key).  The table goes to each ``pallas_call`` as
+  its scalar-prefetch operand (SMEM); grid = (batch·heads, table's length),
+  the index maps read a step's tiles from it, and the innermost grid
+  axis is sequential on TPU, so f32 scratch (acc, running max m, running
+  sum l) persists across a query tile's key sweep: initialised on the
+  sweep's first entry, finalised (acc/l) on its last.  An empty tile has
+  no grid step; a full tile runs no mask arithmetic; a partial one masks
+  by the element predicate (compared from broadcasted_iota), which the
+  XLA reference shares.  ``causal`` is one kind of mask (docs/kernels.md
+  has the others and how to add one).
 
 Grouped queries: ``k`` and ``v`` may carry fewer heads than ``q`` (a
 divisor of its head count); query head ``i`` reads key/value head
@@ -29,12 +35,13 @@ with ``lse = m + log(l)``, one float32 a query row, and whose backward is
 two more Pallas kernels over the same tiles.  Both recompute a tile's
 scores as the forward computes them, so ``P = exp(S - lse)`` is the
 forward's softmax, and form ``dS = P * (dO vᵀ - delta)`` with
-``delta = rowsum(dO * out)``; scores stay in VMEM and the mask's empty
-tiles are skipped as in the forward.
-  ``mx_flash_attention_bwd_dq``: grid (batch·heads, S/BLOCK_Q, S/BLOCK_K),
+``delta = rowsum(dO * out)``; scores stay in VMEM and the tables hold
+the forward's tiles.
+  ``mx_flash_attention_bwd_dq``: the forward's grid and table,
   ``dQ += dS k`` in float32 scratch across the key sweep.
-  ``mx_flash_attention_bwd_dkv``: grid (batch·kv heads, S/BLOCK_K, group,
-  S/BLOCK_Q); the tile is laid out (key, query) so that ``dV += Pᵀ dO``
+  ``mx_flash_attention_bwd_dkv``: grid (batch·kv heads, table's length),
+  the table ordered by key tile, then query head of the group, then
+  query tile; the tile is laid out (key, query) so that ``dV += Pᵀ dO``
   and ``dK += dSᵀ q`` need no transpose, and a key/value head's
   gradients accumulate over its whole group of query heads in scratch.
 
@@ -55,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry import (record_flash_attention_bwd_lowered,
+                         record_flash_attention_grid_steps,
                          record_flash_attention_tiles)
 from ._pallas_rows import per_platform
 from .registry import register
@@ -66,7 +74,7 @@ _NEG_INF = -1e30
 class Mask:
     """Which keys a query may see, by global position ids (a query's and a
     key's index on the sequence axis).  The one description the kernels,
-    their tile skipping and ``_reference_attention`` share; no (S, S)
+    their tile tables and ``_reference_attention`` share; no (S, S)
     array is ever built from it.
 
     ``none``: every key.  ``causal``: ``k <= q``.  ``block_causal``: with
@@ -79,10 +87,10 @@ class Mask:
     sees a noisy key (block-diffusion training: Arriola et al.
     arXiv:2503.09573).
 
-    ``allowed`` and ``tile`` are written over ``//``, comparisons,
-    ``&``/``|`` and the three helpers below alone, so they take traced
-    scalars inside a kernel or an index map, iota arrays, and numpy arrays
-    on the host alike."""
+    ``allowed`` is written over ``//``, comparisons and ``&``/``|``
+    alone, so it takes the iota arrays of a partial tile inside a kernel
+    and numpy arrays alike; ``tile`` runs on the host only, over numpy
+    values, as a call's tile table is built."""
 
     kind: str = "none"
     block: int = 1
@@ -123,8 +131,9 @@ class Mask:
 
     def tile(self, q0, q1, k0, k1):
         """``(some, every)``: whether any, and whether every, pair of the
-        queries ``q0..q1`` and keys ``k0..k1`` (inclusive ids) is allowed.
-        A tile with ``some`` false is empty and is never computed."""
+        queries ``q0..q1`` and keys ``k0..k1`` (inclusive ids, numpy
+        values) is allowed.  A tile with ``some`` false is empty and has
+        no grid step."""
         if self.kind == "none":
             return True, True
         if self.kind != "block_diffusion":
@@ -134,52 +143,89 @@ class Mask:
         # the clean and the noisy part of each range, as positions; a
         # part that is not there has lo > hi and is guarded by has_*
         has_qc, has_qn, has_kc, has_kn = q0 < t, q1 >= t, k0 < t, k1 >= t
-        qc1, kc1 = _least(q1, t - 1), _least(k1, t - 1)
-        qn0, kn0 = _most(q0, t) - t, _most(k0, t) - t
+        qc1, kc1 = np.minimum(q1, t - 1), np.minimum(k1, t - 1)
+        qn0, kn0 = np.maximum(q0, t) - t, np.maximum(k0, t) - t
         qn1, kn1 = q1 - t, k1 - t
         cc, nc, nn = has_qc & has_kc, has_qn & has_kc, has_qn & has_kn
         some = ((cc & (blocks(k0) <= blocks(qc1)))
                 | (nc & (blocks(k0) < blocks(qn1)))
                 | (nn & (blocks(kn0) <= blocks(qn1))
                    & (blocks(qn0) <= blocks(kn1))))
-        every = (_no(has_qc & has_kn)
-                 & (_no(cc) | (blocks(kc1) <= blocks(q0)))
-                 & (_no(nc) | (blocks(kc1) < blocks(qn0)))
-                 & (_no(nn) | ((blocks(kn0) == blocks(qn1))
-                               & (blocks(qn0) == blocks(kn1)))))
+        no = np.logical_not
+        every = (no(has_qc & has_kn)
+                 & (no(cc) | (blocks(kc1) <= blocks(q0)))
+                 & (no(nc) | (blocks(kc1) < blocks(qn0)))
+                 & (no(nn) | ((blocks(kn0) == blocks(qn1))
+                              & (blocks(qn0) == blocks(kn1)))))
         return some, every
 
-    def tile_counts(self, s, bq, bk):
-        """``{"empty", "partial", "full"}``: the (query tile, key tile)
-        pairs of one head at ``s`` positions, on the host."""
+    def tile_kinds(self, s, bq, bk):
+        """``(some, every)`` of every (query tile, key tile) pair of one
+        head at ``s`` positions: two (query tiles, key tiles) arrays."""
         s_pad = _round_up(s, math.lcm(bq, bk))
         q0 = np.arange(0, s_pad, bq)[:, None]
         k0 = np.arange(0, s_pad, bk)[None, :]
         some, every = (np.broadcast_to(a, (q0.size, k0.size)) for a in
                        self.tile(q0, q0 + bq - 1, k0, k0 + bk - 1))
         # a tile that holds padded keys is masked there whatever the kind
-        every = every & (k0 + bk <= s)
+        return some, every & (k0 + bk <= s)
+
+    def tile_counts(self, s, bq, bk):
+        """``{"empty", "partial", "full"}``: the (query tile, key tile)
+        pairs of one head at ``s`` positions, on the host."""
+        some, every = self.tile_kinds(s, bq, bk)
         return {"empty": int((~some).sum()), "full": int(every.sum()),
                 "partial": int((some & ~every).sum())}
 
 
-def _least(a, b):
-    return (jnp if isinstance(a, jax.Array) else np).minimum(a, b)
+# A table entry is one int32 word: three flags (the tile opens its sweep:
+# zero the accumulators; closes it: write the output; holds no barred pair
+# and no padded key), then the query tile, the key tile and the query head
+# of the group.  One word a step keeps a call's tables small in SMEM
+# (1 MiB on a v5e: 262,144 steps)
+_FIRST, _LAST, _FULL = 1, 2, 4
+_TILE_BITS, _HEAD_BITS = 10, 8
 
 
-def _most(a, b):
-    return (jnp if isinstance(a, jax.Array) else np).maximum(a, b)
+def _unpack(word):
+    """``(query tile, key tile, head of the group, flags)`` of table
+    entries: a numpy array on the host, a scalar read from SMEM in a
+    kernel or an index map."""
+    tile = (1 << _TILE_BITS) - 1
+    return ((word >> 3) & tile, (word >> (3 + _TILE_BITS)) & tile,
+            word >> (3 + 2 * _TILE_BITS), word & 7)
 
 
-def _no(a):
-    return (jnp if isinstance(a, jax.Array) else np).logical_not(a)
-
-
-def _tile_runs(mask, q_start, block_q, k_start, block_k):
-    """Whether the kernels compute this tile (a Python True for the mask
-    that allows everything)."""
-    return mask.tile(q_start, q_start + block_q - 1, k_start,
-                     k_start + block_k - 1)[0]
+@functools.lru_cache(maxsize=128)
+def tile_table(mask, s, bq, bk, by_key=False, group=1):
+    """The computed tiles of a call, in the order its grid walks them:
+    one packed int32 a step (``_unpack``).  ``by_key`` false (forward,
+    ``bwd_dq``): a head's tiles by query tile, then key tile ascending: a
+    sweep is a query tile's keys.  ``by_key`` true (``bwd_dkv``): a
+    key/value head's tiles by key tile, then the ``group`` query heads
+    that read it, then query tile ascending: a sweep is a key tile's
+    queries over its whole group.  Built from ``Mask.tile`` alone, on the
+    host."""
+    some, every = mask.tile_kinds(s, bq, bk)
+    if max(some.shape) > 1 << _TILE_BITS or group > 1 << _HEAD_BITS:
+        raise ValueError(
+            f"flash_attention: {some.shape} tiles of ({bq}, {bk}) over a "
+            f"group of {group} heads are more than a table entry can name "
+            f"({1 << _TILE_BITS} a side, {1 << _HEAD_BITS} heads): take "
+            "larger blocks")
+    if by_key:
+        some, every = some.T, every.T
+    swept = np.broadcast_to(some[:, None, :],
+                            (some.shape[0], group, some.shape[1]))
+    outer, head, inner = np.nonzero(swept)      # row-major: the sweep order
+    turns = outer[1:] != outer[:-1]
+    flags = (_FIRST * np.r_[True, turns] + _LAST * np.r_[turns, True]
+             + _FULL * every[outer, inner])
+    q_tile, k_tile = (inner, outer) if by_key else (outer, inner)
+    table = (flags | q_tile << 3 | k_tile << (3 + _TILE_BITS)
+             | head << (3 + 2 * _TILE_BITS)).astype(np.int32)
+    table.setflags(write=False)
+    return table
 
 
 def _visible(mask, q_ids, k_ids, s_actual):
@@ -189,40 +235,44 @@ def _visible(mask, q_ids, k_ids, s_actual):
     return seen if allowed is None else seen & allowed
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
-                 acc_ref, m_ref, l_ref, *,
-                 block_q, block_k, s_actual, sm_scale, mask):
-    """One (q-block, k-block) grid step of online-softmax attention."""
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+def _step(table_ref, block_q, block_k):
+    """This grid step's entry of the table: the first position of its
+    query and key tile, and whether it is the sweep's first, its last, and
+    a full tile."""
+    q_tile, k_tile, _, flags = _unpack(table_ref[pl.program_id(1)])
+    return (q_tile * block_q, k_tile * block_k, (flags & _FIRST) != 0,
+            (flags & _LAST) != 0, (flags & _FULL) != 0)
 
-    @pl.when(kb == 0)
+
+def _attn_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref,
+                 l_out_ref, acc_ref, m_ref, l_ref, *, block_q, block_k,
+                 s_actual, sm_scale, mask):
+    """One computed (q-block, k-block) tile of online-softmax attention."""
+    q_start, k_start, first, last, full = _step(table_ref, block_q, block_k)
+
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_start = pl.program_id(1) * block_q
-    k_start = kb * block_k
-
-    # an empty tile (causal: a k block strictly above the diagonal)
-    # contributes nothing.  A row whose keys so far were all barred has
-    # m = -1e30 and sums garbage; its first visible key brings a finite m
-    # and the correction exp(-1e30 - m) = 0 wipes that, and every row
-    # sees its own key at the latest
-    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
-    def _compute():
+    # A row whose keys so far were all barred has m = -1e30 and sums
+    # garbage; its first visible key brings a finite m and the correction
+    # exp(-1e30 - m) = 0 wipes that, and every row sees its own key at
+    # the latest
+    def compute(masked):
         q = q_ref[0].astype(jnp.float32)            # (BQ, D)
         k = k_ref[0].astype(jnp.float32)            # (BK, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (BQ, BK)
-
-        q_ids = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_ids = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(_visible(mask, q_ids, k_ids, s_actual), s, _NEG_INF)
+        if masked:
+            q_ids = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_ids = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(_visible(mask, q_ids, k_ids, s_actual), s,
+                          _NEG_INF)
 
         m_prev = m_ref[:, :1]                        # (BQ, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -237,7 +287,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(kb == n_kb - 1)
+    pl.when(full)(functools.partial(compute, False))
+    pl.when(~full)(functools.partial(compute, True))
+
+    @pl.when(last)
     def _finalize():
         # padded q rows have l == 0; emit 0 there rather than NaN
         l = l_ref[:, :1]
@@ -270,25 +323,33 @@ def _pad_seq(s_pad, *arrays):
                          + [(0, 0)] * (a.ndim - 3)) for a in arrays)
 
 
-def _anchored(mask, bq, bk):
-    """The index maps' rule for an empty tile: the block index of the
-    swept axis where the tile is computed, else that of a tile the sweep
-    computes anyway, so that consecutive skipped steps (and the computed
-    one beside them) name one block and nothing is copied in.  A key
-    sweep is anchored at the key tile of the query tile's last row, a
-    query sweep at the query tile of the key tile's first row: both hold
-    a query's own key, which every kind allows.
+def _table_call(kernel, table, heads, in_specs, out_specs, out_shape,
+                scratch_shapes, name, operands):
+    """One ``pallas_call`` on the grid ``(heads, the table's length)``:
+    ``table`` is its scalar-prefetch operand (SMEM), which an index map
+    gets after the two grid indices and the kernel before its blocks."""
+    def call(interpret, *operands):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(heads, table.size),
+                in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch_shapes),
+            out_shape=out_shape,
+            interpret=interpret,
+            name=name,
+        )(jnp.asarray(table), *operands)
 
-    Returns ``(key_block(qi, ki), query_block(ki, qi))``."""
-    if mask.kind == "none":
-        return (lambda qi, ki: ki), (lambda ki, qi: qi)
+    return per_platform(call, *operands)
 
-    def runs(qi, ki):
-        return _tile_runs(mask, qi * bq, bq, ki * bk, bk)
 
-    return (lambda qi, ki: jnp.where(runs(qi, ki), ki,
-                                     (qi * bq + bq - 1) // bk),
-            lambda ki, qi: jnp.where(runs(qi, ki), qi, ki * bk // bq))
+def _tile_of(axis):
+    """An index map's reader: the query (0), key (1) tile or the head of
+    the group (2) of grid step ``t``."""
+    return lambda t, table: _unpack(table[t])[axis]
+
+
+_q_tile, _k_tile, _head = _tile_of(0), _tile_of(1), _tile_of(2)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sm_scale",
@@ -305,40 +366,23 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     kf = k.reshape(bh // group, s_pad, d)
     vf = v.reshape(bh // group, s_pad, d)
 
-    kernel = functools.partial(
-        _attn_kernel, block_q=bq, block_k=bk, s_actual=s,
-        sm_scale=sm_scale, mask=mask)
-    grid = (bh, s_pad // bq, s_pad // bk)
-    key_block, _ = _anchored(mask, bq, bk)
-    scratch_shapes = [
-        pltpu.VMEM((bq, d), jnp.float32),       # acc
-        pltpu.VMEM((bq, 128), jnp.float32),     # running max (lane-bcast)
-        pltpu.VMEM((bq, 128), jnp.float32),     # running sum (lane-bcast)
-    ]
-
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh_, qi, ki: (bh_, qi, 0))
+    q_spec = pl.BlockSpec((1, bq, d), lambda i, *t: (i, _q_tile(*t), 0))
     # flat q index = batch * h + head, so // group is batch * h_kv + kv head
-    kv_spec = pl.BlockSpec(
-        (1, bk, d), lambda bh_, qi, ki: (bh_ // group, key_block(qi, ki), 0))
-    stat_spec = pl.BlockSpec((1, bq, 128), lambda bh_, qi, ki: (bh_, qi, 0))
-
-    def call(interpret, qf, kf, vf):
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=(q_spec, stat_spec, stat_spec),
-            out_shape=(
-                jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
-                jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
-            ),
-            scratch_shapes=scratch_shapes,
-            interpret=interpret,
-            name="mx_flash_attention_fwd",
-        )(qf, kf, vf)
-
-    out, m_out, l_out = per_platform(call, qf, kf, vf)
+    kv_spec = pl.BlockSpec((1, bk, d),
+                           lambda i, *t: (i // group, _k_tile(*t), 0))
+    stat_spec = pl.BlockSpec((1, bq, 128), lambda i, *t: (i, _q_tile(*t), 0))
+    out, m_out, l_out = _table_call(
+        functools.partial(_attn_kernel, block_q=bq, block_k=bk, s_actual=s,
+                          sm_scale=sm_scale, mask=mask),
+        tile_table(mask, s, bq, bk), bh,
+        [q_spec, kv_spec, kv_spec], (q_spec, stat_spec, stat_spec),
+        (jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
+         jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
+         jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32)),
+        [pltpu.VMEM((bq, d), jnp.float32),       # acc
+         pltpu.VMEM((bq, 128), jnp.float32),     # running max (lane-bcast)
+         pltpu.VMEM((bq, 128), jnp.float32)],    # running sum (lane-bcast)
+        "mx_flash_attention_fwd", (qf, kf, vf))
     out = out.reshape(b, h, s_pad, d)[:, :, :s, :]
     m_out = m_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
     l_out = l_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
@@ -416,12 +460,23 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     return _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
 
 
+def _record_grid_steps(kernel, mask, s, block_q, block_k, by_key=False,
+                       group=1):
+    """Set the gauge of the grid steps a query head ``kernel`` is built
+    with: the length of the table its ``pallas_call`` gets."""
+    bq, bk, _ = _tiles(s, block_q, block_k)
+    record_flash_attention_grid_steps(
+        mask.kind, kernel,
+        tile_table(mask, s, bq, bk, by_key, group).size // group)
+
+
 def _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     _check_heads(q, k, v)
     mask = Mask.of(causal)
-    # a fact about the program, taken as it is traced
+    # facts about the program, taken as it is traced
     record_flash_attention_tiles(mask.kind, mask.tile_counts(
         q.shape[2], *_tiles(q.shape[2], block_q, block_k)[:2]))
+    _record_grid_steps("fwd", mask, q.shape[2], block_q, block_k)
     return _flash_fwd(q, k, v, causal=mask,
                       sm_scale=_static_sm_scale(sm_scale, q.shape[-1]),
                       block_q=block_q, block_k=block_k)
@@ -438,12 +493,12 @@ _NN = (((1,), (0,)), ((), ()))    # a b
 
 
 def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
-               s_actual, sm_scale, mask):
+               masked, s_actual, sm_scale, mask):
     """``P`` and ``P * (dP - delta)`` of one tile, laid out (query, key)
     for ``q_axis`` 0 and (key, query) for 1; ``lse`` and ``delta`` hold
     one value a query and broadcast along the key axis.  The scores are
     the forward kernel's: float32 operands, the scale after the product,
-    the same mask."""
+    the same mask on a tile that is not full (``masked``)."""
     def over_heads(of_q, of_k):
         """(query, key) or (key, query) products over the head dimension."""
         return jax.lax.dot_general(
@@ -451,11 +506,13 @@ def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
             preferred_element_type=jnp.float32)
 
     s = over_heads(q.astype(jnp.float32), k.astype(jnp.float32)) * sm_scale
-    q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-    k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                               1 - q_axis)
-    p = jnp.exp(jnp.where(_visible(mask, q_ids, k_ids, s_actual), s,
-                          _NEG_INF) - lse)
+    if masked:
+        q_ids = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   q_axis)
+        k_ids = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   1 - q_axis)
+        s = jnp.where(_visible(mask, q_ids, k_ids, s_actual), s, _NEG_INF)
+    p = jnp.exp(s - lse)
     # Mosaic's product of float32 operands is one bfloat16 pass, and what
     # dO loses to it is one error for a query's whole row of dP, which the
     # sums over the keys do not average out: a float32 dO goes in as two
@@ -470,61 +527,60 @@ def _tile_p_ds(q, k, v, do, lse, delta, q_start, k_start, *, q_axis,
     return p, p * (dp - delta)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, block_q, block_k, s_actual, sm_scale, mask):
-    """One (q-block, k-block) grid step of ``dQ = scale · Σ_k dS k``."""
-    kb = pl.program_id(2)
+def _bwd_dq_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, acc_ref, *, block_q, block_k, s_actual,
+                   sm_scale, mask):
+    """One computed (q-block, k-block) tile of ``dQ = scale · Σ_k dS k``."""
+    q_start, k_start, first, last, full = _step(table_ref, block_q, block_k)
 
-    @pl.when(kb == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q_start = pl.program_id(1) * block_q
-    k_start = kb * block_k
-
-    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
-    def _compute():
+    def compute(masked):
         k = k_ref[0]
         _, ds = _tile_p_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
-            delta_ref[0][:, :1], q_start, k_start, q_axis=0,
+            delta_ref[0][:, :1], q_start, k_start, q_axis=0, masked=masked,
             s_actual=s_actual, sm_scale=sm_scale, mask=mask)
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    pl.when(full)(functools.partial(compute, False))
+    pl.when(~full)(functools.partial(compute, True))
+
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = (acc_ref[:] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, block_q, block_k, s_actual,
-                    sm_scale, mask):
-    """One (k-block, query head of the group, q-block) grid step of
+def _bwd_dkv_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
+                    block_k, s_actual, sm_scale, mask):
+    """One computed (k-block, query head of the group, q-block) tile of
     ``dV = Σ Pᵀ dO`` and ``dK = scale · Σ dSᵀ q``, the tile transposed."""
-    g, qb = pl.program_id(2), pl.program_id(3)
+    q_start, k_start, first, last, full = _step(table_ref, block_q, block_k)
 
-    @pl.when((g == 0) & (qb == 0))
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qb * block_q
-    k_start = pl.program_id(1) * block_k
-
-    @pl.when(_tile_runs(mask, q_start, block_q, k_start, block_k))
-    def _compute():
+    def compute(masked):
         q, do = q_ref[0], do_ref[0]
         p, ds = _tile_p_ds(
             q, k_ref[0], v_ref[0], do, lse_ref[0, 0], delta_ref[0, 0],
-            q_start, k_start, q_axis=1, s_actual=s_actual, sm_scale=sm_scale,
-            mask=mask)
+            q_start, k_start, q_axis=1, masked=masked, s_actual=s_actual,
+            sm_scale=sm_scale, mask=mask)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when((g == pl.num_programs(2) - 1) & (qb == pl.num_programs(3) - 1))
+    pl.when(full)(functools.partial(compute, False))
+    pl.when(~full)(functools.partial(compute, True))
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -549,59 +605,44 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     kf, vf = (a.reshape(b * h_kv, s_pad, d) for a in (k, v))
     params = dict(block_q=bq, block_k=bk, s_actual=s, sm_scale=sm_scale,
                   mask=mask)
-    n_qb, n_kb = s_pad // bq, s_pad // bk
-    # a skipped step names a block the sweep needs anyway
-    key_block, query_block = _anchored(mask, bq, bk)
 
-    # dq: statistics one value a row, broadcast over a lane tile as the
-    # forward writes its own
-    q_spec = pl.BlockSpec((1, bq, d), lambda i, qi, ki: (i, qi, 0))
-    kv_spec = pl.BlockSpec(
-        (1, bk, d), lambda i, qi, ki: (i // group, key_block(qi, ki), 0))
-    col_spec = pl.BlockSpec((1, bq, 128), lambda i, qi, ki: (i, qi, 0))
+    # dq: the forward's table; statistics one value a row, broadcast over a
+    # lane tile as the forward writes its own
+    q_spec = pl.BlockSpec((1, bq, d), lambda i, *t: (i, _q_tile(*t), 0))
+    kv_spec = pl.BlockSpec((1, bk, d),
+                           lambda i, *t: (i // group, _k_tile(*t), 0))
+    col_spec = pl.BlockSpec((1, bq, 128), lambda i, *t: (i, _q_tile(*t), 0))
     cols = [jnp.broadcast_to(a.reshape(bh, s_pad, 1), (bh, s_pad, 128))
             for a in (lse, delta)]
+    dq = _table_call(
+        functools.partial(_bwd_dq_kernel, **params),
+        tile_table(mask, s, bq, bk), bh,
+        [q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec], q_spec,
+        jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
+        [pltpu.VMEM((bq, d), jnp.float32)],
+        "mx_flash_attention_bwd_dq", (qf, kf, vf, dof, *cols))
 
-    def dq_call(interpret, *operands):
-        return pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, **params),
-            grid=(bh, n_qb, n_kb),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            interpret=interpret,
-            name="mx_flash_attention_bwd_dq",
-        )(*operands)
-
-    dq = per_platform(dq_call, qf, kf, vf, dof, *cols)
-
-    # dk, dv: flat query head = (batch · h_kv + kv head) · group + g
+    # dk, dv: a key tile's sweep runs over its whole group of query heads
+    # (flat query head = (batch · h_kv + kv head) · group + head of the
+    # group), so its block and both accumulators stay where they are
     q_spec = pl.BlockSpec(
-        (1, bq, d), lambda i, ki, g, qi: (i * group + g, query_block(ki, qi), 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda i, ki, g, qi: (i, ki, 0))
+        (1, bq, d), lambda i, *t: (i * group + _head(*t), _q_tile(*t), 0))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda i, *t: (i, _k_tile(*t), 0))
     # one row of bq statistics a block: the block's last two dimensions are
     # the array's, whatever bq is
     row_spec = pl.BlockSpec(
         (1, 1, 1, bq),
-        lambda i, ki, g, qi: (i * group + g, query_block(ki, qi), 0, 0))
-    rows = [a.reshape(bh, n_qb, 1, bq) for a in (lse, delta)]
-
-    def dkv_call(interpret, *operands):
-        return pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, **params),
-            grid=(b * h_kv, n_kb, group, n_qb),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=(kv_spec, kv_spec),
-            out_shape=(jax.ShapeDtypeStruct(kf.shape, k.dtype),
-                       jax.ShapeDtypeStruct(vf.shape, v.dtype)),
-            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)],
-            interpret=interpret,
-            name="mx_flash_attention_bwd_dkv",
-        )(*operands)
-
-    dk, dv = per_platform(dkv_call, qf, kf, vf, dof, *rows)
+        lambda i, *t: (i * group + _head(*t), _q_tile(*t), 0, 0))
+    rows = [a.reshape(bh, s_pad // bq, 1, bq) for a in (lse, delta)]
+    dk, dv = _table_call(
+        functools.partial(_bwd_dkv_kernel, **params),
+        tile_table(mask, s, bq, bk, True, group), b * h_kv,
+        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        (kv_spec, kv_spec),
+        (jax.ShapeDtypeStruct(kf.shape, k.dtype),
+         jax.ShapeDtypeStruct(vf.shape, v.dtype)),
+        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
+        "mx_flash_attention_bwd_dkv", (qf, kf, vf, dof, *rows))
     return (dq.reshape(b, h, s_pad, d), dk.reshape(b, h_kv, s_pad, d),
             dv.reshape(b, h_kv, s_pad, d))
 
@@ -609,6 +650,10 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
     record_flash_attention_bwd_lowered("pallas")
+    mask, s = Mask.of(causal), q.shape[2]
+    _record_grid_steps("bwd_dq", mask, s, block_q, block_k)
+    _record_grid_steps("bwd_dkv", mask, s, block_q, block_k, True,
+                       q.shape[1] // k.shape[1])
     grads = _flash_bwd(
         q, k, v, out, lse, g, causal=causal,
         sm_scale=_static_sm_scale(sm_scale, q.shape[-1]),

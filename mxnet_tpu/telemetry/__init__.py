@@ -160,8 +160,15 @@ _FLASH_TILES = REGISTRY.gauge(
     "mxnet_flash_attention_tiles",
     "(query tile, key tile) pairs a head of the last traced "
     "ops.pallas_attention.flash_attention call, by its mask's kind and by "
-    "kind=empty (skipped: not computed, nothing copied in), partial "
-    "(computed and masked inside) or full")
+    "kind=empty (not in the grid), partial (computed and masked inside) "
+    "or full (computed with no mask arithmetic)")
+_FLASH_GRID_STEPS = REGISTRY.gauge(
+    "mxnet_flash_attention_grid_steps",
+    "grid steps a query head that a kernel of the last traced "
+    "ops.pallas_attention.flash_attention call was built with, by its "
+    "mask's kind and by kernel=fwd, bwd_dq or bwd_dkv: the length of the "
+    "kernel's tile table, which equals partial + full of "
+    "mxnet_flash_attention_tiles (no grid step for an empty tile)")
 _DIFFUSION_POSITIONS = REGISTRY.counter(
     "mxnet_diffusion_positions_total",
     "positions of the per-position loss weights handed to "
@@ -301,6 +308,12 @@ def record_flash_attention_tiles(mask, counts):
     its mask's kind, ``counts`` ``{"empty", "partial", "full"}``."""
     for kind, n in counts.items():
         _FLASH_TILES.set(n, labels={"mask": mask, "kind": kind})
+
+
+def record_flash_attention_grid_steps(mask, kernel, steps):
+    """Record the grid steps a query head that one of a traced flash
+    attention call's kernels (``fwd``, ``bwd_dq``, ``bwd_dkv``) walks."""
+    _FLASH_GRID_STEPS.set(steps, labels={"mask": mask, "kernel": kernel})
 
 
 def record_loss_weights(weights):

@@ -1,7 +1,8 @@
 """The flash attention kernels, forward and backward, compiled by Mosaic for
 a DESCRIBED TPU v5e (no chip attached): what the interpreter cannot show,
-a block shape, a layout or a VMEM budget the compiler refuses.  Nothing
-runs; the shapes are the three token cells' and the suite's awkward ones.
+a block shape, a layout, a VMEM budget or a tile table SMEM cannot hold
+that the compiler refuses.  Nothing runs; the shapes are the four token
+cells' and the suite's awkward ones.
 All compiles live in this one file and the topology is described inside a
 fixture, so only the worker that is given the file loads the TPU's
 library."""
@@ -55,6 +56,14 @@ def one_chip():
      Mask("block_diffusion", 4, 8192)),
     (4, 2, 272, 16, "float32", 128, 128, Mask("block_diffusion", 4, 136)),
     (4, 2, 200, 16, "bfloat16", 128, 64, Mask("block_causal", 4)),
+    # the tile tables in SMEM: bq != bk with tiles that straddle the two
+    # copies; no empty tile, so the table is the whole rectangle: the
+    # suite's largest (3 x 4 of (128, 96)), the cells' length (256 steps),
+    # and 65,536 steps, a quarter of the chip's SMEM
+    (4, 2, 272, 16, "float32", 64, 128, Mask("block_diffusion", 4, 136)),
+    (1, 1, 256, 32, "float32", 128, 96, False),
+    (32, 2, 8192, 128, "float32", 512, 512, False),
+    (2, 1, 32768, 64, "float32", 128, 128, False),
 ])
 def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
                                                 dtype, block_q, block_k,

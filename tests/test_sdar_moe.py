@@ -24,8 +24,10 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon, nd, telemetry
 from mxnet_tpu.gluon.model_zoo.language import SparseExperts
-from mxnet_tpu.ops.pallas_attention import (Mask, _reference_attention,
-                                            flash_attention)
+from mxnet_tpu.ops.pallas_attention import (_FIRST, _FULL, _LAST, Mask,
+                                            _reference_attention, _tiles,
+                                            _unpack, flash_attention,
+                                            reference_attention, tile_table)
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.spmd import TrainStep
 
@@ -171,6 +173,129 @@ def test_tile_counts_at_the_timed_shape():
                                      ki * 128 + 127)[0])
                       for ki in range(3)] for qi in range(6)])
     assert (some | ~needed).all()
+
+
+# -- (a') the tile tables the three kernels walk -------------------------------------
+TILINGS = [(128, 128), (64, 128), (128, 64)]
+
+
+def _brute_force(mask, s, bq, bk):
+    """``(some, full)`` of every (query tile, key tile) pair, an element at
+    a time: ``Mask.allowed`` over the padded ids, and no padded key."""
+    bq, bk, s_pad = _tiles(s, bq, bk)
+    ids = np.arange(s_pad)
+    allowed = mask.allowed(ids[:, None], ids[None, :])
+    allowed = np.ones((s_pad, s_pad), bool) if allowed is None else allowed
+    by_tile = allowed.reshape(s_pad // bq, bq, s_pad // bk, bk)
+    full = by_tile.all(axis=(1, 3)) & ((np.arange(s_pad // bk) + 1) * bk <= s)
+    return by_tile.any(axis=(1, 3)), full, (bq, bk)
+
+
+@pytest.mark.parametrize("by_key,group", [(False, 1), (True, 1), (True, 2)])
+@pytest.mark.parametrize("blocks", TILINGS)
+@pytest.mark.parametrize("mask,s", MASKS, ids=lambda v: str(v))
+def test_tile_table_is_the_brute_force_classification(mask, s, blocks,
+                                                      by_key, group):
+    some, full, (bq, bk) = _brute_force(mask, s, *blocks)
+    q_tile, k_tile, head, flags = _unpack(
+        tile_table(mask, s, bq, bk, by_key, group))
+    # the computed tiles, each once a head of the group, in sweep order
+    want = [(qi, ki, g) for ki in range(some.shape[1]) for g in range(group)
+            for qi in range(some.shape[0]) if some[qi, ki]] if by_key else \
+        [(qi, ki, 0) for qi, ki in zip(*np.nonzero(some))]
+    assert list(zip(q_tile, k_tile, head)) == want
+    np.testing.assert_array_equal((flags & _FULL) != 0, full[q_tile, k_tile])
+    # every query tile and every key tile has an entry: each output block
+    # of each kernel is written
+    assert set(q_tile) == set(range(some.shape[0]))
+    assert set(k_tile) == set(range(some.shape[1]))
+    # the flags bracket each sweep: first on its first entry alone, last
+    # on its last alone
+    swept = k_tile if by_key else q_tile
+    turns = swept[1:] != swept[:-1]
+    np.testing.assert_array_equal((flags & _FIRST) != 0, np.r_[True, turns])
+    np.testing.assert_array_equal((flags & _LAST) != 0, np.r_[turns, True])
+    assert (np.diff(swept) >= 0).all()
+    assert mask.tile_counts(s, bq, bk) == {
+        "empty": int((~some).sum()), "full": int(full.sum()),
+        "partial": int((some & ~full).sum())}
+
+
+def test_tile_table_at_the_timed_shapes():
+    """288 steps a head where the rectangle had 1,024 (SDAR), 136 of 256
+    (Nemotron, Solar), 36 of 64 (granite); ``bwd_dkv`` walks a key tile's
+    sweep once a head of the group; a table no entry can name is refused."""
+    sdar = Mask("block_diffusion", 4, 8192)
+    assert tile_table(sdar, 16384, 512, 512).size == 288
+    assert tile_table(sdar, 16384, 512, 512, True, 8).size == 288 * 8
+    assert tile_table(Mask("causal"), 8192, 512, 512).size == 136
+    assert tile_table(Mask("causal"), 4096, 512, 512, True, 4).size == 36 * 4
+    assert tile_table(Mask("none"), 200, 128, 128).size == 4
+    flags = _unpack(tile_table(sdar, 16384, 512, 512))[3]
+    assert ((flags & _FULL) != 0).sum() == 240
+    with pytest.raises(ValueError, match="more than a table entry can name"):
+        tile_table(Mask("causal"), 1025 * 8, 8, 8)
+    with pytest.raises(ValueError, match="more than a table entry can name"):
+        tile_table(Mask("causal"), 256, 128, 128, True, 257)
+
+
+@pytest.mark.parametrize("mask,s", MASKS, ids=lambda v: str(v))
+def test_table_walk_matches_the_reference_over_a_group_of_four(mask, s):
+    """bq != bk, a padded tail, four query heads on ONE key/value head:
+    values and the three gradients against ``reference_attention``."""
+    rng = np.random.default_rng(39)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((2, h, s, 16)),
+                               jnp.float32) for h in (4, 1, 1, 4))
+    got, vjp = jax.vjp(lambda *a: flash_attention(*a, mask, None, 128, 64),
+                       q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(lambda *a: reference_attention(*a, mask),
+                                q, k, v)
+        grads = ref_vjp(do)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip("qkv", vjp(do), grads):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", TILINGS[:2])
+@pytest.mark.parametrize("mask,s", MASKS, ids=lambda v: str(v))
+def test_no_grid_step_is_taken_for_an_empty_tile(mask, s, blocks):
+    """The engagement gauge: each kernel's grid steps a query head, as the
+    call is traced, equal the mask's partial + full tiles; and the grids
+    of the three ``pallas_call``s in the program are those tables' long."""
+    q, k, v, do = _qkv(s)
+
+    def both(q, k, v, do):
+        out, vjp = jax.vjp(lambda *a: flash_attention(*a, mask, None,
+                                                      *blocks), q, k, v)
+        return (out,) + vjp(do)
+
+    jaxpr = jax.make_jaxpr(both)(q, k, v, do)
+    counts = mask.tile_counts(s, *_tiles(s, *blocks)[:2])
+    computed = counts["partial"] + counts["full"]
+    gauge = telemetry.REGISTRY.get("mxnet_flash_attention_grid_steps")
+    assert {kernel: gauge.value({"mask": mask.kind, "kernel": kernel})
+            for kernel in ("fwd", "bwd_dq", "bwd_dkv")} == {
+        "fwd": computed, "bwd_dq": computed, "bwd_dkv": computed}
+    grids = {}
+    for name, grid in _pallas_grids(jaxpr.jaxpr):
+        grids.setdefault(name, set()).add(grid)
+    # batch 2: 8 query heads, 4 key/value heads with a group of 2 each
+    assert grids == {"mx_flash_attention_fwd": {(8, computed)},
+                     "mx_flash_attention_bwd_dq": {(8, computed)},
+                     "mx_flash_attention_bwd_dkv": {(4, 2 * computed)}}
+
+
+def _pallas_grids(jaxpr):
+    """``(kernel's name, grid)`` of every ``pallas_call`` under ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_grids(sub)
 
 
 def test_masks_that_cannot_be_are_refused():
