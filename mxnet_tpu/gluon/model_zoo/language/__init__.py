@@ -11,3 +11,6 @@ from .nemotron_h import *  # noqa: F401,F403
 from .nemotron_h import NemotronH, NemotronLayer, nemotron_h
 from .sdar_moe import *  # noqa: F401,F403
 from .sdar_moe import SDARDecoderLayer, SDARMoE, sdar_moe
+from .zaya import *  # noqa: F401,F403
+from .zaya import (CompressedConvAttention, Zaya, ZayaDecoderLayer,
+                   ZayaRouter, zaya)

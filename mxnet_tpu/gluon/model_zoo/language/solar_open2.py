@@ -159,13 +159,22 @@ class SparseExperts(HybridBlock):
     assignments to each of all ``experts_total`` experts: what the rule
     that balances the bias reads (``balanced_bias``).  The block reads
     the bias and does not write it: whoever owns the step applies the
-    rule, outside any rematerialisation boundary."""
+    rule, outside any rematerialisation boundary.  ``bias_init`` is the
+    bias's initializer (by default the one the block is initialised with).
+
+    ``router`` is by default one matrix inside the op (``router_weight``).
+    Given a block's constructor, ``router(prefix=...)``, that block is the
+    router: called ``router(h, *state)`` with whatever else the experts
+    were called with, it returns the logits ``(..., experts_total)`` and
+    its state after them, which the experts return after their own
+    outputs (a router that carries a state from layer to layer)."""
 
     def __init__(self, hidden_size, width, experts_total, experts_held,
                  first_expert, top_k, shared_experts=1, scaling=1.0,
                  norm_topk=True, tile=256, form="gated_silu",
                  shared_width=None, select_bias=False, scope="solar/moe",
-                 score_function="sigmoid", prefix=None, params=None):
+                 score_function="sigmoid", router=None, bias_init=None,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._traced_as = scope
         self._attrs = dict(
@@ -177,8 +186,13 @@ class SparseExperts(HybridBlock):
             shared_width = shared_experts * width
         shared = {"gated_silu": GatedMLP, "relu2": Relu2MLP}[form]
         with self.name_scope():
-            self.router_weight = self.params.get(
-                "router_weight", shape=(experts_total, hidden_size))
+            if router is None:
+                self.router = None
+                self.router_weight = self.params.get(
+                    "router_weight", shape=(experts_total, hidden_size))
+            else:
+                self.router = router(prefix="router_")
+                self._attrs["router"] = "logits"
             self.w1 = self.params.get(
                 "w1", shape=(experts_held, width, hidden_size))
             if form == "gated_silu":
@@ -188,23 +202,27 @@ class SparseExperts(HybridBlock):
                 "w2", shape=(experts_held, hidden_size, width))
             if select_bias:
                 self.select_bias = self.params.get(
-                    "select_bias", shape=(experts_total,), grad_req="null")
+                    "select_bias", shape=(experts_total,), grad_req="null",
+                    init=bias_init)
             self.shared = shared(hidden_size, shared_width,
                                  prefix="shared_") if shared_width else None
 
-    def hybrid_forward(self, F, h, router_weight, w1, w2, w3=None,
-                       select_bias=None):
-        inputs = [v for v in (h, router_weight, w1, w3, w2, select_bias)
+    def hybrid_forward(self, F, h, *state, w1, w2, router_weight=None,
+                       w3=None, select_bias=None):
+        routing = router_weight     # the matrix, or a block's logits
+        if self.router is not None:
+            with jax.named_scope(self._traced_as + "/router"):
+                routing, *state = self.router(h, *state)
+        inputs = [v for v in (h, routing, w1, w3, w2, select_bias)
                   if v is not None]
         with jax.named_scope(self._traced_as):      # the op's own scopes nest
-            y, load, rows, *counts = F.contrib.routed_experts(
-                *inputs, **self._attrs)
+            y, *notes = F.contrib.routed_experts(*inputs, **self._attrs)
         if self.shared is None:
-            return (y, load, rows, *counts)
+            return (y, *notes, *state)
         with jax.named_scope(self._traced_as + "/shared"):
             shared = self.shared(h)
         with jax.named_scope(self._traced_as + "/combine"):
-            return (y + shared, load, rows, *counts)
+            return (y + shared, *notes, *state)
 
 
 def balanced_bias(F, bias, counts, rate):

@@ -7,7 +7,9 @@ is a top-1 router with a capacity; this op is the layer a deployment with
 experts over several chips runs on each of them, without the exchange:
 
     s = sigmoid(W_r h)  over all ``experts_total`` outputs (float32, highest),
-        or softmax(W_r h) over them (``score_function``)
+        or softmax(W_r h) over them (``score_function``); or of logits a
+        router OUTSIDE the op computed (``router`` ``logits``: a network
+        with state of its own, not one matrix)
     the chosen: top-k of s, or of s + b with a selection bias b (which
         chooses and never weighs: Wang et al. arXiv:2408.15664)
     w_e = s_e / Σ_chosen s · scaling   (``norm_topk``)
@@ -151,7 +153,7 @@ SCORES = {"sigmoid": jax.nn.sigmoid,
 
 def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
                    scaling=1.0, norm_topk=True, tile=256, select_bias=None,
-                   score_function="sigmoid"):
+                   score_function="sigmoid", router="weight"):
     """The held experts' part of a routed layer.  h (..., hidden);
     router_w (experts_total, hidden); w1 (E_here, width, hidden); w2
     (E_here, hidden, width); w3 like w1 for gated SiLU experts, None for
@@ -165,25 +167,34 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
     of ALL ``experts_total`` experts (the rule that balances the bias
     needs the absent experts' load too).  ``score_function`` is what
     turns the router's outputs into scores: ``sigmoid``, each expert
-    alone, or ``softmax`` over all of them.  No assignment is dropped
-    whatever the imbalance (see the module's head)."""
-    total, hidden = router_w.shape
+    alone, or ``softmax`` over all of them.  With ``router`` ``logits``
+    the second argument is not the router's matrix but its outputs,
+    (..., experts_total), computed by whoever calls: no product is made
+    here, everything after the scores is the same.  No assignment is
+    dropped whatever the imbalance (see the module's head)."""
+    hidden = h.shape[-1]
+    total = router_w.shape[-1 if router == "logits" else 0]
     n_held = w1.shape[0]
     if not (0 <= first_expert and first_expert + n_held <= total
             and 1 <= top_k <= total and tile >= 1
-            and score_function in SCORES):
+            and score_function in SCORES and router in ("weight", "logits")
+            and (router == "weight"
+                 or router_w.shape[:-1] == h.shape[:-1])):
         raise MXNetError(
             f"routed_experts: experts {first_expert}..{first_expert + n_held}"
             f" of {total}, top {top_k}, tile {tile}, scores "
-            f"{score_function!r}")
+            f"{score_function!r}, router {router!r} {router_w.shape} for "
+            f"data {h.shape}")
     form, ups = ("relu2", (w1,)) if w3 is None else ("gated_silu", (w1, w3))
     x = h.reshape(-1, hidden)
     with jax.named_scope("routed_experts/router"):
         # in float32 at the highest precision: near-ties among 320 scores
         # must fall the same way wherever the product is computed
-        scores = SCORES[score_function](jnp.matmul(
-            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
-            precision=lax.Precision.HIGHEST))
+        scores = SCORES[score_function](
+            router_w.astype(jnp.float32).reshape(-1, total)
+            if router == "logits" else jnp.matmul(
+                x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+                precision=lax.Precision.HIGHEST))
         if select_bias is None:     # the values top_k returns ARE the weights
             chosen, expert = lax.top_k(scores, top_k)
         else:
@@ -208,14 +219,16 @@ def routed_experts(h, router_w, w1, w3, w2, top_k, first_expert,
 
 
 def _input_names(attrs):
-    """``expert_form`` ``relu2`` has no ``w3``; ``select_bias`` adds the
-    bias as the last input (and the count over all experts as the last
+    """``router`` ``logits`` takes the router's outputs where its weight
+    stood; ``expert_form`` ``relu2`` has no ``w3``; ``select_bias`` adds
+    the bias as the last input (and the count over all experts as the last
     output)."""
     form = attrs.get("expert_form", "gated_silu")
     if form not in FORMS:
         raise MXNetError(f"routed_experts: expert_form {form!r} is not one "
                          f"of {sorted(FORMS)}")
-    return (("data", "router_weight", "w1")
+    logits = str(attrs.get("router", "weight")) == "logits"
+    return (("data", "router_logits" if logits else "router_weight", "w1")
             + (("w3",) if form == "gated_silu" else ()) + ("w2",)
             + (("select_bias",) if attrs.get("select_bias", False) else ()))
 
@@ -227,17 +240,18 @@ def _routed_experts(attrs, h, router_w, *rest):
     names = _input_names(attrs)[2:]
     if len(rest) != len(names):
         raise MXNetError(f"routed_experts: inputs {names} expected after "
-                         f"the router's weight, {len(rest)} given")
+                         f"the router's, {len(rest)} given")
     given = dict(zip(names, rest))
-    total = int(attrs.get("experts_total", router_w.shape[0]))
-    if total != router_w.shape[0]:
-        raise MXNetError(f"routed_experts: the router has "
-                         f"{router_w.shape[0]} outputs, experts_total "
-                         f"{total}")
+    router = str(attrs.get("router", "weight"))
+    width = router_w.shape[-1 if router == "logits" else 0]
+    total = int(attrs.get("experts_total", width))
+    if total != width:
+        raise MXNetError(f"routed_experts: the router has {width} outputs, "
+                         f"experts_total {total}")
     return routed_experts(
         h, router_w, given["w1"], given.get("w3"), given["w2"],
         int(attrs["top_k"]), int(attrs.get("first_expert", 0)),
         float(attrs.get("routed_scaling_factor", 1.0)),
         bool(attrs.get("norm_topk_prob", True)),
         int(attrs.get("tile", 256)), given.get("select_bias"),
-        str(attrs.get("score_function", "sigmoid")))
+        str(attrs.get("score_function", "sigmoid")), router)

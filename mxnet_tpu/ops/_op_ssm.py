@@ -1,6 +1,6 @@
 """State-space sequence ops: the chunked selective scan of Mamba-2 (state
-space duality, Dao & Gu arXiv:2405.21060 §6), the causal depthwise 1-D
-convolution in front of it, and RMSNorm (plain and SiLU-gated).
+space duality, Dao & Gu arXiv:2405.21060 §6), the causal 1-D convolution
+in front of it (depthwise, or grouped), and RMSNorm (plain and SiLU-gated).
 
 No reference analog: MXNet 1.x has no state-space layer.  The scan is
 written in the chunked form so that XLA places its work on the MXU:
@@ -50,22 +50,28 @@ def _rms_norm(attrs, x, gamma, *maybe_gate):
 @register("_contrib_rotary_embedding", alias=("rotary_embedding",),
           input_names=("data", "positions"))
 def _rotary_embedding(attrs, x, positions):
-    """Rotary position embedding over the whole head, rotate-half form (Su
-    et al. arXiv:2104.09864 as GPT-NeoX lays it out): with ``d`` the
-    trailing axis, ``θ_i = base^(−2i/d)`` for ``i < d/2`` and
-    ``[x1, x2]`` the head's two halves,
+    """Rotary position embedding, rotate-half form (Su et al.
+    arXiv:2104.09864 as GPT-NeoX lays it out): with ``d`` the number of
+    LEADING channels of a head that are turned (``rotary_dim``, by default
+    the whole trailing axis), ``θ_i = base^(−2i/d)`` for ``i < d/2`` and
+    ``[x1, x2]`` the two halves of those ``d`` channels,
 
         out = [x1 cos(pθ) − x2 sin(pθ),  x2 cos(pθ) + x1 sin(pθ)]
 
-    ``x`` (batch, heads, T, d); ``positions`` (T,) or (batch, T), an INPUT
-    and not ``0..T−1``: positions may repeat (block-diffusion training
-    lays two copies of a sequence side by side under the same positions).
-    Angles, sines and cosines are taken in float32."""
+    and the channels after them pass untouched (a partial rotary factor).
+    ``x`` (batch, heads, T, head size); ``positions`` (T,) or (batch, T),
+    an INPUT and not ``0..T−1``: positions may repeat (block-diffusion
+    training lays two copies of a sequence side by side under the same
+    positions).  Angles, sines and cosines are taken in float32."""
     base = float(attrs.get("base", 10000.0))
-    d = x.shape[-1]
-    if d % 2 or positions.shape[-1] != x.shape[-2]:
-        raise MXNetError(f"rotary_embedding: data {x.shape}, positions "
-                         f"{positions.shape}")
+    d = int(attrs.get("rotary_dim", x.shape[-1]))
+    if d % 2 or not 0 < d <= x.shape[-1] \
+            or positions.shape[-1] != x.shape[-2]:
+        raise MXNetError(f"rotary_embedding: data {x.shape}, rotary_dim "
+                         f"{d}, positions {positions.shape}")
+    if d < x.shape[-1]:
+        turned = _rotary_embedding({"base": base}, x[..., :d], positions)
+        return jnp.concatenate([turned, x[..., d:]], axis=-1)
     inv_freq = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions.astype(jnp.float32)[..., None] * inv_freq
     if angle.ndim == 3:                 # (batch, T, d/2): over the heads
@@ -76,20 +82,51 @@ def _rotary_embedding(attrs, x, positions):
                            axis=-1).astype(x.dtype)
 
 
-# --- causal depthwise convolution over time -----------------------------------
+# --- causal convolution over time: depthwise, or grouped ------------------------
 @register("_contrib_causal_conv1d", alias=("causal_conv1d",),
           input_names=("data", "weight", "bias"))
 def _causal_conv1d(attrs, x, weight, bias):
     """``y[b, t, c] = bias[c] + Σ_k weight[c, k] · x[b, t − (K−1) + k, c]``
     with zeros before the sequence: ``x`` (batch, time, channels),
     ``weight`` (channels, K) as a depthwise ``Conv1d`` stores it (its last
-    tap multiplies the current step)."""
+    tap multiplies the current step).
+
+    A 3-d ``weight`` (channels, channels/groups, K), as a grouped
+    ``Conv1d`` stores it, mixes the channels of a group: output channel
+    ``c`` of group ``g`` reads that group's ``channels/groups`` input
+    channels at every tap,
+
+        y[b, t, c] = bias[c] + Σ_k Σ_i weight[c, i, k]
+                               · x[b, t − (K−1) + k, g · channels/groups + i]
+
+    ``groups`` is read from the shapes."""
+    if weight.ndim == 3:
+        return _grouped_causal_conv1d(x, weight, bias)
     k = weight.shape[1]
     t = x.shape[1]
     xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
     y = bias.astype(x.dtype)
     for j in range(k):
         y = y + xp[:, j:j + t, :] * weight[:, j].astype(x.dtype)
+    return y
+
+
+def _grouped_causal_conv1d(x, weight, bias):
+    """K shifted grouped products, one a tap: each is a batched matrix
+    product over the groups, (time, in a group) × (in a group, out a
+    group), which XLA places on the MXU like any other."""
+    channels, per_group, k = weight.shape
+    if x.shape[-1] != channels or channels % per_group:
+        raise MXNetError(f"causal_conv1d: data {x.shape}, grouped weight "
+                         f"{weight.shape}")
+    groups, t = channels // per_group, x.shape[1]
+    xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
+    w = weight.astype(x.dtype).reshape(groups, per_group, per_group, k)
+    y = bias.astype(x.dtype)
+    for j in range(k):
+        tap = xp[:, j:j + t, :].reshape(x.shape[:2] + (groups, per_group))
+        y = y + jnp.einsum("btgi,goi->btgo", tap, w[..., j]
+                           ).reshape(x.shape)
     return y
 
 

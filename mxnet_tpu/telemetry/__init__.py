@@ -198,6 +198,17 @@ _MOE_BIAS = REGISTRY.gauge(
     "mean |b| of the routers' selection biases (all layers, all experts) "
     "as the last recorded step left them: how far the balancing rule has "
     "moved the choice from the scores (0 for a router without a bias)")
+_CCA_LATENT = REGISTRY.gauge(
+    "mxnet_cca_latent_channels",
+    "channels of the latent the last traced compressed convolutional "
+    "attention layer works in, by part=q (query heads x head size) or kv "
+    "(key/value heads x head size): what the projections go to, the "
+    "convolutions mix and the kernel reads, against the hidden size")
+_ROUTER_EDA_GAMMA = REGISTRY.gauge(
+    "mxnet_router_eda_gamma_abs_mean",
+    "mean |gamma| over the layers of a router that carries its state from "
+    "layer to layer (exponential depth averaging, r <- W m + gamma r), as "
+    "the last recorded step left it: 0 while every layer routes alone")
 _COLLECTIVE_BYTES = REGISTRY.counter(
     "mxnet_collective_bytes_total",
     "logical payload bytes moved by gradient-synchronization "
@@ -338,6 +349,19 @@ def record_moe_load(load, rows, steps=1, bias=None):
     _MOE_LOAD_SKEW.set(
         float((load.max(axis=1)[busy] / mean[busy]).max()) if busy.any()
         else 0.0)
+
+
+def record_cca_latent_channels(q, kv):
+    """Record the latent widths of one traced compressed convolutional
+    attention call."""
+    _CCA_LATENT.set(int(q), labels={"part": "q"})
+    _CCA_LATENT.set(int(kv), labels={"part": "kv"})
+
+
+def record_router_eda_gamma(gamma):
+    """Record the depth-averaging coefficients of a model's routers from
+    host copies: ``gamma`` (layers, 1)."""
+    _ROUTER_EDA_GAMMA.set(float(abs(gamma).mean()))
 
 
 def record_data_wait(seconds):

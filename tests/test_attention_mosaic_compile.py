@@ -41,6 +41,7 @@ def one_chip():
 @pytest.mark.parametrize("h,h_kv,s,d,dtype,block_q,block_k,causal", [
     (32, 2, 8192, 128, "float32", 512, 512, True),     # Nemotron's layer
     (8, 1, 8192, 128, "float32", 512, 512, True),      # Solar's
+    (8, 2, 8192, 128, "float32", 512, 512, True),      # ZAYA1's CCA layer
     (32, 8, 4096, 64, "float32", 512, 512, True),      # granite's
     (32, 2, 8192, 128, "bfloat16", 512, 512, True),
     (8, 8, 1024, 128, "bfloat16", 128, 128, True),     # chip_smoke's

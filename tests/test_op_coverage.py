@@ -227,6 +227,26 @@ CASES = {
             np.pad(x, [(0, 0), (2, 0), (0, 0)])[:, j:j + 3] * w[:, j]
             for j in range(3)),
         grad=True),
+    # the same operator (by its alias) with a GROUPED weight (channels,
+    # channels a group, K): two groups of two channels, two taps
+    "causal_conv1d": C(
+        [A234, np.linspace(-1, 1, 16, dtype=np.float32).reshape(4, 2, 2),
+         np.linspace(-.5, .5, 4, dtype=np.float32)],
+        lambda x, w, b: b + sum(
+            np.einsum("btgi,goi->btgo",
+                      np.pad(x, [(0, 0), (1, 0), (0, 0)])[:, j:j + 3]
+                      .reshape(2, 3, 2, 2), w.reshape(2, 2, 2, 2)[..., j]
+                      ).reshape(2, 3, 4) for j in range(2)),
+        grad=True),
+    # the first rotary_dim channels of a head turned, the rest passed
+    "rotary_embedding": C(
+        [np.linspace(-2, 2, 24, dtype=np.float32).reshape(1, 2, 3, 4),
+         np.arange(3, dtype=np.float32)],
+        lambda x, p: np.concatenate(
+            [x[..., :1] * np.cos(p)[:, None] - x[..., 1:2] * np.sin(p)[:, None],
+             x[..., 1:2] * np.cos(p)[:, None] + x[..., :1] * np.sin(p)[:, None],
+             x[..., 2:]], -1),
+        attrs={"rotary_dim": 2, "base": 100.0}, grad=False),
     "softmax_cross_entropy": C(
         [A34, np.array([0, 1, 2], np.float32)],
         lambda x, y: np.array(
