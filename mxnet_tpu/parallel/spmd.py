@@ -156,15 +156,37 @@ def data_parallel_shardings(mesh, params, batch_axis="dp",
     return param_shardings, batch_sharding
 
 
+def _placeable(array):
+    """``(data, on_host)``: the array as ``jax.device_put`` should take it.
+
+    An ``NDArray`` or a ``jax.Array`` already lives on a device and is
+    resharded from there.  Anything else stays in host memory, cast there
+    to the dtype ``jnp.asarray`` would give (float64 -> float32, int64 ->
+    int32 without x64): ``device_put`` then slices it on the host and
+    sends each device only its own rows, so nothing lands whole on one
+    device to be sliced there and copied on."""
+    if isinstance(array, NDArray):
+        return array._data, False
+    if isinstance(array, jax.Array):
+        return array, False
+    host = np.asarray(array)
+    dtype = jax.dtypes.canonicalize_dtype(host.dtype)
+    return (host if host.dtype == dtype else host.astype(dtype)), True
+
+
 def shard_batch(mesh, array, axis="dp"):
-    """Place a host batch onto the mesh, sharded along its leading dim."""
-    data = array._data if isinstance(array, NDArray) else jnp.asarray(array)
+    """Place a batch onto the mesh, sharded along its leading dim: a host
+    array shard by shard from host memory, a device array resharded."""
+    from .. import telemetry as _telemetry
+    data, on_host = _placeable(array)
+    _telemetry.record_spmd_batch_array("host" if on_host else "device")
     return jax.device_put(data, mesh.sharding(axis))
 
 
 def replicate(mesh, array):
-    data = array._data if isinstance(array, NDArray) else jnp.asarray(array)
-    return jax.device_put(data, mesh.replicated())
+    """Place a copy of the array on every device of the mesh: from host
+    memory to each device for a host array."""
+    return jax.device_put(_placeable(array)[0], mesh.replicated())
 
 
 # -- functional optimizers ---------------------------------------------------

@@ -83,6 +83,11 @@ _IO_STAGE = REGISTRY.histogram(
     "host time spent staging a DataBatch host->device (io.stage_batch)")
 _IO_STAGE_BYTES = REGISTRY.counter(
     "mxnet_io_stage_bytes_total", "bytes staged host->device by io")
+_SPMD_BATCH_ARRAYS = REGISTRY.counter(
+    "mxnet_spmd_batch_arrays_total",
+    "arrays parallel.spmd.shard_batch placed on a mesh, by path: host "
+    "(shard by shard from host memory, each device sent its own rows) or "
+    "device (an array already on a device, resharded)")
 _IO_STAGE_WINDOWS = REGISTRY.counter(
     "mxnet_io_stage_windows_total",
     "scanned-fit windows staged by io.stage_super_batch, by when: "
@@ -258,6 +263,12 @@ def record_io_stage_bytes(nbytes):
     in the record of the span open around the copy."""
     if nbytes:
         count_in_span(_IO_STAGE_BYTES, int(nbytes))
+
+
+def record_spmd_batch_array(path):
+    """Account one array ``shard_batch`` placed, by ``path`` (host or
+    device); it lands in the record of ``spmd/step/shard_batch``."""
+    count_in_span(_SPMD_BATCH_ARRAYS, 1, {"path": path})
 
 
 def record_io_stage_window(when):

@@ -158,6 +158,93 @@ def test_shard_batch_placement():
     assert len(xs.sharding.device_set) == 8
 
 
+def _batch_arrays(path):
+    from mxnet_tpu import telemetry
+    return telemetry.REGISTRY.get("mxnet_spmd_batch_arrays_total").value(
+        {"path": path})
+
+
+def _no_device_slicing(*_a, **_k):
+    raise AssertionError("a host batch went through shard_device_array")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64", "int64"])
+@pytest.mark.parametrize("dp", [2, 4, 8])
+def test_shard_batch_host_array_goes_shard_by_shard(dp, dtype, monkeypatch):
+    """A host batch is sliced on the host: each device holds exactly its
+    own rows, in the dtype jnp.asarray gives, and no device program
+    slices a whole copy of the batch (jax's shard_device_array)."""
+    import jax.numpy as jnp
+    from jax._src import array as jax_array
+    _need_devices(dp)
+    mesh = make_mesh(devices=jax.devices()[:dp], dp=dp)
+    x = (np.random.randn(4 * dp, 3, 5) * 100).astype(dtype)
+    monkeypatch.setattr(jax_array, "shard_device_array", _no_device_slicing)
+    host, device = _batch_arrays("host"), _batch_arrays("device")
+    xs = shard_batch(mesh, x)
+    assert _batch_arrays("host") - host == 1
+    assert _batch_arrays("device") == device
+    assert xs.dtype == jnp.asarray(x).dtype
+    assert len(xs.addressable_shards) == dp
+    for shard in xs.addressable_shards:
+        rows = shard.index[0]
+        assert rows.stop - rows.start == 4
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      x[rows].astype(xs.dtype))
+    np.testing.assert_array_equal(np.asarray(xs), x.astype(xs.dtype))
+
+
+def test_shard_batch_device_array_is_resharded():
+    """An NDArray on one device keeps the device path: resharded onto the
+    mesh, counted as path=device."""
+    _need_devices(4)
+    mesh = make_mesh(devices=jax.devices()[:4], dp=4)
+    x = mx.nd.array(np.arange(32, dtype=np.float32).reshape(8, 4))
+    host, device = _batch_arrays("host"), _batch_arrays("device")
+    xs = shard_batch(mesh, x)
+    assert _batch_arrays("device") - device == 1
+    assert _batch_arrays("host") == host
+    assert {s.device for s in xs.addressable_shards} == \
+        set(jax.devices()[:4])
+    for shard in xs.addressable_shards:
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      x.asnumpy()[shard.index])
+
+
+def test_host_fed_step_equals_preplaced_step():
+    """TrainStep on dp=4 fed host arrays (placed shard by shard from host
+    memory) and fed the same batches placed the old way (jnp.asarray onto
+    one device, then resharded) trains bit for bit alike."""
+    import jax.numpy as jnp
+    _need_devices(4)
+    rng = np.random.RandomState(3)
+    batches = [(rng.randn(16, 16), (np.arange(16) % 10).astype(np.float32))
+               for _ in range(3)]
+    example = tuple(mx.nd.array(a) for a in batches[0])
+
+    def run(place):
+        mx.random.seed(11)
+        np.random.seed(11)
+        mesh = make_mesh(devices=jax.devices()[:4], dp=4)
+        net = _make_net()
+        net(example[0])
+        for p in net.collect_params().values():
+            p.data()[:] = mx.nd.random.uniform(-0.1, 0.1, p.shape)
+        step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                         {"learning_rate": 0.1, "momentum": 0.9}, mesh,
+                         example_batch=example)
+        losses = [float(step(*(place(mesh, a) for a in b)))
+                  for b in batches]
+        return losses, [np.asarray(p) for p in step.params]
+
+    host_l, host_p = run(lambda mesh, a: a)
+    old_l, old_p = run(lambda mesh, a: jax.device_put(
+        jnp.asarray(a), mesh.sharding("dp")))
+    assert host_l == old_l
+    for a, b in zip(host_p, old_p):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_sync_to_block():
     mesh = DeviceMesh({"dp": 1}, devices=jax.devices()[:1])
     net = _make_net()

@@ -271,7 +271,8 @@ def test_spmd_step_spans_and_counters(enabled):
     assert len(_named("spmd/step")) == 2
     assert all(r["parent"] == "spmd/step" for n in names for r in _named(n))
     assert [r["counts"] for r in _named("spmd/step/shard_batch")] == \
-        [{"mxnet_io_stage_bytes_total": x.nbytes + y.nbytes}] * 2
+        [{"mxnet_io_stage_bytes_total": x.nbytes + y.nbytes,
+          "mxnet_spmd_batch_arrays_total": 2}] * 2
     for rec in _named("spmd/step/dispatch"):
         assert rec["counts"]["mxnet_step_host_arg_leaves"] == 0
         assert rec["counts"]["mxnet_step_host_arg_bytes"] == 0
