@@ -29,6 +29,7 @@ from .. import random as _random
 from ..base import MXNetError, np_dtype
 from ..context import Context, cpu, current_context
 from ..ndarray import NDArray
+from ..ops.pallas_attention import FLASH_RESIDUALS, traced_calls
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 from .utils import _indent
 
@@ -121,22 +122,28 @@ jax.tree_util.register_pytree_node(
 
 # thread-local: the layers a trace is to checkpoint one by one (remat_scope)
 _REMAT = threading.local()
+# what a boundary keeps of its layer besides the inputs: the flash kernel's
+# out and lse, by name (a layer with no flash call keeps nothing more)
+_KEEP_FLASH = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
 
 
 class _RematScope:
-    """The layers to checkpoint while a forward is traced, and how many
-    of them were (``boundaries``)."""
+    """The layers to checkpoint while a forward is traced, how many of
+    them were (``boundaries``) and how many named residuals those keep
+    (``saved_residuals``: ``FLASH_RESIDUALS`` of each flash call inside)."""
 
     def __init__(self, layers):
         self._layers = {id(b) for b in layers}
         self.boundaries = 0
+        self.saved_residuals = 0
 
     def __contains__(self, block):
         return id(block) in self._layers
 
     def call(self, layer, args):
         """``layer(*args)`` under ``jax.checkpoint``: the layer's inputs
-        and parameters are all that the backward keeps of it; everything
+        and parameters, and the ``out`` and ``lse`` of each flash kernel
+        call, are all that the backward keeps of it; everything else
         inside is computed again there.  The parameters go in as
         arguments, swapped into the layer for the call as ``_build_jit``
         does for the whole block."""
@@ -167,19 +174,24 @@ class _RematScope:
                 for d, a in zip(holders, saved):
                     d._data = a
 
-        outs = jax.checkpoint(pure)(tuple(d._data for d in holders),
-                                    tuple(a._data for a in flat))
+        calls = traced_calls()
+        outs = jax.checkpoint(pure, policy=_KEEP_FLASH)(
+            tuple(d._data for d in holders), tuple(a._data for a in flat))
         self.boundaries += 1
+        self.saved_residuals += len(FLASH_RESIDUALS) * (traced_calls() - calls)
         return _regroup([NDArray(o, ctx) for o in outs], out_fmt[0])[0]
 
 
 @contextlib.contextmanager
 def remat_scope(layers):
     """While a forward is traced inside this scope, each call of one of
-    ``layers`` is a rematerialisation boundary (``jax.checkpoint`` with
-    nothing saveable inside).  Yields the scope; its ``boundaries`` counts
-    the calls that were wrapped.  ``parallel.spmd.TrainStep(remat=True)``
-    opens it over the ``remat_layers`` a block declares."""
+    ``layers`` is a rematerialisation boundary (``jax.checkpoint`` that
+    saves the flash kernel's ``out`` and ``lse`` by name and nothing else
+    inside, so the backward recomputes the layer but not that kernel).
+    Yields the scope; its ``boundaries`` counts the calls that were
+    wrapped and ``saved_residuals`` the named residuals they keep.
+    ``parallel.spmd.TrainStep(remat=True)`` opens it over the
+    ``remat_layers`` a block declares."""
     prev = getattr(_REMAT, "scope", None)
     _REMAT.scope = scope = _RematScope(layers)
     try:

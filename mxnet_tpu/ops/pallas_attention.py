@@ -44,6 +44,9 @@ the forward's tiles.
   query tile; the tile is laid out (key, query) so that ``dV += Pᵀ dO``
   and ``dK += dSᵀ q`` need no transpose, and a key/value head's
   gradients accumulate over its whole group of query heads in scratch.
+``out`` and ``lse`` carry the names ``FLASH_RESIDUALS``, which a
+rematerialisation boundary's policy keeps: its backward recomputes q, k
+and v but not the forward kernel.
 
 Where the program is lowered for anything but a tpu the same kernel runs
 under the Pallas interpreter (_pallas_rows.per_platform), so unit tests
@@ -54,10 +57,12 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -68,6 +73,21 @@ from ._pallas_rows import per_platform
 from .registry import register
 
 _NEG_INF = -1e30
+
+# the names of the forward rule's ``out`` and ``lse``: a rematerialisation
+# boundary keeps these two (``gluon.block.remat_scope``,
+# ``parallel.spmd.remat_wrap``), so its backward reads them and does not run
+# the forward kernel again
+FLASH_RESIDUALS = ("mx_flash_out", "mx_flash_lse")
+_TRACED = threading.local()
+
+
+def traced_calls():
+    """``flash_attention`` calls this thread has staged into a jaxpr so far.
+    A rematerialisation boundary stages its layer, so the difference across
+    one is the calls inside it; a call differentiated outside every boundary
+    runs its forward rule alone and is not counted."""
+    return getattr(_TRACED, "calls", 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,6 +477,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
     array — the old ``float(sm_scale)`` host conversion would silently
     concretize a tracer inside jit/shard_map bodies.
     """
+    _TRACED.calls = traced_calls() + 1
     return _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)[0]
 
 
@@ -484,8 +505,13 @@ def _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k):
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
     out, m, l = _checked_fwd(q, k, v, causal, sm_scale, block_q, block_k)
-    # every row sees a key (its own, under every kind of mask), so l > 0
-    return out, (q, k, v, out, m + jnp.log(l))
+    # named for a boundary's policy (FLASH_RESIDUALS), the identity outside
+    # one; the primal output is the saved ``out`` itself, so the output
+    # projection's pullback reads it too.  Every row sees a key (its own,
+    # under every kind of mask), so l > 0
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(m + jnp.log(l), FLASH_RESIDUALS[1])
+    return out, (q, k, v, out, lse)
 
 
 _NT = (((1,), (1,)), ((), ()))    # a bᵀ: contract both last dimensions

@@ -20,6 +20,7 @@ from .. import random as _random
 from ..base import MXNetError
 from ..ndarray import NDArray
 from ..ops import registry as _registry
+from ..ops.pallas_attention import FLASH_RESIDUALS, traced_calls
 from .mesh import DeviceMesh
 
 
@@ -248,12 +249,18 @@ def _matmul_conv_saveable(prim, *_args, **_params):
                                          "conv_general_dilated")
 
 
+_MIRROR_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    _matmul_conv_saveable,
+    jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS))
+
+
 def remat_wrap(fwd):
     """Wrap a forward fn with rematerialization (parity:
     MXNET_BACKWARD_DO_MIRROR, src/nnvm/gradient.cc mirror fn): activation
-    memory shrinks to the matmul/conv outputs; elementwise intermediates
-    are recomputed during backward."""
-    return jax.checkpoint(fwd, policy=_matmul_conv_saveable)
+    memory shrinks to the matmul/conv outputs and the flash kernel's
+    ``out`` and ``lse`` (so its forward is not run again); elementwise
+    intermediates are recomputed during backward."""
+    return jax.checkpoint(fwd, policy=_MIRROR_POLICY)
 
 
 class TrainStep:
@@ -287,7 +294,8 @@ class TrainStep:
         for large-batch training that would otherwise spill HBM.  A block
         that declares ``remat_layers`` (a sequence model's decoder
         layers) gets a boundary per layer instead: only each layer's
-        input is kept and the layer is computed again in its backward.
+        input and its flash kernels' ``out`` and ``lse`` are kept, and the
+        rest of the layer is computed again in its backward.
         ``remat_boundaries`` holds how many boundaries the step program
         was traced with (0 until its first trace, and without remat).
 
@@ -394,11 +402,15 @@ class TrainStep:
             def step(key, train_params, aux_params, opt_state, x, y, *w):
                 def fwd(tps, x_):
                     ps = merge_params(train_idx, aux_idx, tps, aux_params)
+                    calls = traced_calls()
                     with _ag.train_mode(), remat_scope(remat_layers) as sc:
                         outs, mutated = apply_fn(key, ps, (x_,))
-                    # a fact about the program: taken as it is traced
+                    # facts about the program: taken as it is traced
                     self.remat_boundaries = sc.boundaries or int(whole_remat)
-                    _telemetry.record_remat_boundaries(self.remat_boundaries)
+                    saved = sc.saved_residuals or whole_remat * len(
+                        FLASH_RESIDUALS) * (traced_calls() - calls)
+                    _telemetry.record_remat_boundaries(
+                        self.remat_boundaries, saved)
                     return outs[0], mutated
 
                 if whole_remat:

@@ -155,6 +155,12 @@ _REMAT_BOUNDARIES = REGISTRY.gauge(
     "rematerialisation boundaries (jax.checkpoint regions) the last "
     "traced parallel.spmd.TrainStep program holds: one per declared "
     "layer, 1 for a whole-forward wrap, 0 without remat")
+_REMAT_SAVED = REGISTRY.gauge(
+    "mxnet_step_remat_saved_residuals",
+    "named residuals the rematerialisation boundaries of the last traced "
+    "parallel.spmd.TrainStep program keep: the flash kernel's out and lse "
+    "(ops.pallas_attention.FLASH_RESIDUALS) of each call inside one, whose "
+    "forward kernel the backward then does not run again; 0 without remat")
 _FLASH_BWD_LOWERED = REGISTRY.counter(
     "mxnet_flash_attention_bwd_lowered_total",
     "times the backward rule of ops.pallas_attention.flash_attention was "
@@ -313,10 +319,12 @@ def record_scan_window(steps):
     _SCAN_WINDOW.set(int(steps))
 
 
-def record_remat_boundaries(n):
+def record_remat_boundaries(n, saved_residuals):
     """Record how many rematerialisation boundaries a train step program
-    was traced with (parallel.spmd.TrainStep)."""
+    was traced with (parallel.spmd.TrainStep), and how many named
+    residuals they keep."""
     _REMAT_BOUNDARIES.set(int(n))
+    _REMAT_SAVED.set(int(saved_residuals))
 
 
 def record_flash_attention_bwd_lowered(impl):
