@@ -1,12 +1,15 @@
 """Language model zoo: decoder-only sequence models built from
-``HybridBlock``s with every size given at construction."""
+``HybridBlock``s with every size given at construction.  The blocks two or
+more models share are in ``blocks``; each model file imports from it
+alone."""
+from .blocks import *  # noqa: F401,F403
+from .blocks import (GatedMLP, GroupedQueryAttention, Mamba2Mixer, Relu2MLP,
+                     SparseExperts, balanced_bias)
 from .granite import *  # noqa: F401,F403
-from .granite import (GatedMLP, GraniteHybrid, GroupedQueryAttention,
-                      HybridDecoderLayer, Mamba2Mixer, Relu2MLP,
-                      granite_hybrid)
+from .granite import GraniteHybrid, HybridDecoderLayer, granite_hybrid
 from .solar_open2 import *  # noqa: F401,F403
 from .solar_open2 import (KimiDeltaAttention, SolarDecoderLayer, SolarOpen2,
-                          SparseExperts, balanced_bias, solar_open2)
+                          solar_open2)
 from .nemotron_h import *  # noqa: F401,F403
 from .nemotron_h import NemotronH, NemotronLayer, nemotron_h
 from .sdar_moe import *  # noqa: F401,F403

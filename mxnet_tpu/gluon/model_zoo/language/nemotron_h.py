@@ -30,22 +30,19 @@ The model is built for ONE HOLDER'S SHARE of a deployment, as
 vocabulary rows it holds, the router and its bias keep all their outputs,
 what an absent expert would add is left out and nothing stands in for the
 absent chips.  Every size is given at construction; the layers are the
-block's ``remat_layers``.  Auxiliary state (no gradient, no optimizer; a
-train step carries it and its checkpoints hold it): each expert layer's
-``select_bias``, which the layer READS, inside its rematerialisation
-boundary, and the model WRITES after the layers, outside every boundary;
-``expert_load`` and ``expert_rows``, one row an expert layer, which every
-forward adds to.
+block's ``remat_layers``.  Each expert layer READS its ``select_bias``
+inside its rematerialisation boundary; the model WRITES it, and adds to
+``expert_load`` and ``expert_rows``, after the layers
+(``blocks.RoutedExpertState``).
 """
 from __future__ import annotations
 
 import jax
 
-from .... import autograd
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
-from .granite import GroupedQueryAttention, Mamba2Mixer, _dense
-from .solar_open2 import SparseExperts, balanced_bias
+from .blocks import (GroupedQueryAttention, Mamba2Mixer, RoutedExpertState,
+                     SparseExperts, dense)
 
 __all__ = ["NemotronLayer", "NemotronH", "nemotron_h"]
 
@@ -77,7 +74,7 @@ class NemotronLayer(HybridBlock):
             return x + self.mixer(h)
 
 
-class NemotronH(HybridBlock):
+class NemotronH(RoutedExpertState, HybridBlock):
     """Token ids ``(batch, T)`` to logits ``(batch, T, vocab_size)``.
 
     ``pattern`` names each layer's mixer by a character: ``M``, ``E`` or
@@ -100,7 +97,6 @@ class NemotronH(HybridBlock):
             raise ValueError(f"nemotron_h: pattern {pattern!r} has layers "
                              f"other than {KINDS!r}")
         self._vocab, self._hidden = vocab_size, hidden_size
-        self._bias_rate = float(bias_update_rate)
         mixers = {
             "M": lambda prefix: Mamba2Mixer(
                 hidden_size, mamba_heads, mamba_head_dim, mamba_state,
@@ -116,7 +112,6 @@ class NemotronH(HybridBlock):
                 shared_width=shared_width, select_bias=True,
                 scope="nemotron/moe", prefix=prefix),
         }
-        n_expert_layers = pattern.count("E")
         with self.name_scope():
             self.embed_weight = self.params.get(
                 "embed_weight", shape=(vocab_size, hidden_size))
@@ -129,14 +124,7 @@ class NemotronH(HybridBlock):
                                       prefix="final_norm_")
             self.head_weight = self.params.get(
                 "head_weight", shape=(vocab_size, hidden_size))
-            if n_expert_layers:
-                # auxiliary state, one row an expert layer
-                self.expert_load = self.params.get(
-                    "expert_load", shape=(n_expert_layers, experts_held),
-                    init="zeros", grad_req="null")
-                self.expert_rows = self.params.get(
-                    "expert_rows", shape=(n_expert_layers,), init="zeros",
-                    grad_req="null")
+            self._declare_expert_state(experts_held, bias_update_rate)
 
     @property
     def remat_layers(self):
@@ -147,6 +135,10 @@ class NemotronH(HybridBlock):
     @property
     def expert_layers(self):
         return [layer for layer in self.layers if layer.kind == "E"]
+
+    @property
+    def expert_blocks(self):
+        return [layer.mixer for layer in self.expert_layers]
 
     def hybrid_forward(self, F, ids, embed_weight, head_weight,
                        expert_load=None, expert_rows=None):
@@ -159,47 +151,10 @@ class NemotronH(HybridBlock):
                 notes.append(note)
             else:
                 x = layer(x)
-        if notes:
-            # outside the layers' remat boundaries: the step returns these
-            # as the forward's mutated state, with the loss, in the same
-            # program.  The two counts are added to (sums over the forwards
-            # made since they were zero, as SolarOpen2's)
-            loads, rows, counts = zip(*notes)
-            with jax.named_scope("step/aux_state"):
-                expert_load._set_data(
-                    (expert_load + F.stack(*loads, axis=0))._data)
-                expert_rows._set_data(
-                    (expert_rows + F.concat(*rows, dim=0))._data)
-                if autograd.is_training():
-                    # the layers above have read their bias; the next step
-                    # reads what is written here
-                    for layer, count in zip(self.expert_layers, counts):
-                        bias = layer.mixer.select_bias.data(ids.context)
-                        bias._set_data(balanced_bias(
-                            F, bias, count, self._bias_rate)._data)
+        self._write_expert_state(F, notes, expert_load, expert_rows,
+                                 ids.context)
         with jax.named_scope("nemotron/head"):
-            return _dense(F, self.final_norm(x), head_weight, self._vocab)
-
-    def record_expert_load(self, arrays=None, steps=1):
-        """Set the ``mxnet_moe_*`` gauges from the auxiliary state: the
-        two counts sum over the ``steps`` steps made since they were zero,
-        the selection biases are as the last step left them.  ``arrays``
-        is ``{parameter name: array}`` of a train step that owns the state
-        (``dict(zip(step.param_names, step.params))``), by default this
-        block's own parameters.  One read of a few small arrays, made when
-        somebody asks, never in the step.  Returns the two sums."""
-        import numpy as np
-
-        from .... import telemetry
-
-        def host(p):
-            return np.asarray(arrays[p.name]) if arrays is not None \
-                else p.data().asnumpy()
-
-        load, rows = host(self.expert_load), host(self.expert_rows)
-        telemetry.record_moe_load(load, rows, steps, bias=np.stack(
-            [host(layer.mixer.select_bias) for layer in self.expert_layers]))
-        return load, rows
+            return dense(F, self.final_norm(x), head_weight, self._vocab)
 
 
 def nemotron_h(config, **kwargs):

@@ -40,7 +40,7 @@ vocabulary rows it holds; the router keeps all its outputs, what an absent
 expert would add is left out and nothing stands in for the absent chips.
 Every size is given at construction; the layers are the block's
 ``remat_layers``; each layer's per-expert assignment count is added to
-auxiliary state (``expert_load``, ``expert_rows``).
+the auxiliary state of ``blocks.RoutedExpertState``.
 """
 from __future__ import annotations
 
@@ -50,8 +50,8 @@ import jax
 
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
-from .granite import GroupedQueryAttention, _dense
-from .solar_open2 import SolarOpen2, SparseExperts
+from .blocks import (GroupedQueryAttention, RoutedExpertState,
+                     SparseExperts, dense)
 
 __all__ = ["SDARDecoderLayer", "SDARMoE", "sdar_moe"]
 
@@ -78,7 +78,7 @@ class SDARDecoderLayer(HybridBlock):
         return x + y, load, rows
 
 
-class SDARMoE(HybridBlock):
+class SDARMoE(RoutedExpertState, HybridBlock):
     """Token ids to logits, in the ``layout`` the block stands in (see the
     module's head): ``training`` takes ``(batch, 2T)`` and returns
     ``(batch, T, vocab_size)``, ``denoising`` takes and returns
@@ -126,19 +126,17 @@ class SDARMoE(HybridBlock):
                                       prefix="final_norm_")
             self.head_weight = self.params.get(
                 "head_weight", shape=(vocab_size, hidden_size))
-            # auxiliary state, one row a layer: no gradient, no optimizer
-            self.expert_load = self.params.get(
-                "expert_load", shape=(num_layers, experts_held),
-                init="zeros", grad_req="null")
-            self.expert_rows = self.params.get(
-                "expert_rows", shape=(num_layers,), init="zeros",
-                grad_req="null")
+            self._declare_expert_state(experts_held)
 
     @property
     def remat_layers(self):
         """The blocks a train step with ``remat=True`` checkpoints one by
         one (``gluon.block.remat_scope``)."""
         return list(self.layers)
+
+    @property
+    def expert_blocks(self):
+        return [layer.moe for layer in self.layers]
 
     @contextlib.contextmanager
     def denoising(self):
@@ -171,25 +169,16 @@ class SDARMoE(HybridBlock):
             positions = F.arange(length, dtype="int32")
         x = F.Embedding(ids, embed_weight, input_dim=self._vocab,
                         output_dim=self._hidden)
-        loads, rows = [], []
+        notes = []
         for layer in self.layers:
-            x, load, row = layer(x, positions)
-            loads.append(load)
-            rows.append(row)
-        # outside the layers' remat boundaries, added and not overwritten,
-        # as SolarOpen2's
-        with jax.named_scope("step/aux_state"):
-            expert_load._set_data(
-                (expert_load + F.stack(*loads, axis=0))._data)
-            expert_rows._set_data(
-                (expert_rows + F.concat(*rows, dim=0))._data)
+            x, *note = layer(x, positions)
+            notes.append(note)
+        self._write_expert_state(F, notes, expert_load, expert_rows,
+                                 ids.context)
         if self.layout == "training":       # the noisy half carries the loss
             x = F.slice_axis(x, axis=1, begin=length // 2, end=None)
         with jax.named_scope("sdar/head"):
-            return _dense(F, self.final_norm(x), head_weight, self._vocab)
-
-    # the same auxiliary state under the same names: the same reading
-    record_expert_load = SolarOpen2.record_expert_load
+            return dense(F, self.final_norm(x), head_weight, self._vocab)
 
 
 def sdar_moe(config, **kwargs):

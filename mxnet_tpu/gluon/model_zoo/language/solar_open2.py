@@ -35,8 +35,7 @@ The router keeps all its outputs; what an absent expert or head would add
 is left out and nothing stands in for the absent chips (no all-reduce, no
 exchange).  Every size is given at construction; the decoder layers are the
 block's ``remat_layers``; each layer's per-expert assignment count is
-added to auxiliary state (``expert_load``, ``expert_rows``), written in the
-step the way BatchNorm writes its running statistics.
+added to the auxiliary state of ``blocks.RoutedExpertState``.
 """
 from __future__ import annotations
 
@@ -45,11 +44,11 @@ import jax
 from .... import initializer as init_mod
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
-from .granite import (GatedMLP, GroupedQueryAttention, MambaALog,
-                      MambaDtBias, Relu2MLP, _dense)
+from .blocks import (GroupedQueryAttention, MambaALog, MambaDtBias,
+                     RoutedExpertState, SparseExperts, dense)
 
-__all__ = ["KimiDeltaAttention", "SparseExperts", "balanced_bias",
-           "SolarDecoderLayer", "SolarOpen2", "solar_open2"]
+__all__ = ["KimiDeltaAttention", "SolarDecoderLayer", "SolarOpen2",
+           "solar_open2"]
 
 
 class KimiDeltaAttention(HybridBlock):
@@ -109,7 +108,7 @@ class KimiDeltaAttention(HybridBlock):
             return x * F.rsqrt(F.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
         with jax.named_scope("solar/kda/proj"):
-            q, k, v = (_dense(F, h, w, inner)
+            q, k, v = (dense(F, h, w, inner)
                        for w in (q_weight, k_weight, v_weight))
         with jax.named_scope("solar/kda/conv"):
             q, k, v = (heads(F.Activation(
@@ -121,117 +120,21 @@ class KimiDeltaAttention(HybridBlock):
             k = unit(k)
         with jax.named_scope("solar/kda/gates"):
             step = F.Activation(F.broadcast_add(
-                _dense(F, _dense(F, h, a_down_weight, self._rank),
+                dense(F, dense(F, h, a_down_weight, self._rank),
                        a_up_weight, inner),
                 F.reshape(dt_bias, shape=(1, 1, -1))), act_type="softrelu")
             g = F.broadcast_mul(
                 heads(step), -F.exp(F.reshape(A_log, shape=(1, 1, -1, 1))))
             beta = self._beta_scale * F.sigmoid(
-                _dense(F, h, beta_weight, self._heads))
-            gate = heads(F.sigmoid(_dense(
-                F, _dense(F, h, g_down_weight, self._rank), g_up_weight,
+                dense(F, h, beta_weight, self._heads))
+            gate = heads(F.sigmoid(dense(
+                F, dense(F, h, g_down_weight, self._rank), g_up_weight,
                 inner)))
         with jax.named_scope("solar/kda/scan"):
             o = F.contrib.kda_scan(q, k, v, g, beta, chunk_size=self._chunk)
         with jax.named_scope("solar/kda/out"):
             o = F.reshape(self.norm(o) * gate, shape=(0, 0, -1))
-            return _dense(F, o, o_weight, self._hidden)
-
-
-class SparseExperts(HybridBlock):
-    """One holder's share of a mixture of ``experts_total`` routed experts,
-    ``top_k`` a token: the router scores ALL experts, the experts
-    ``first_expert .. first_expert + experts_held − 1`` are held and
-    computed here for the rows routed to them (op
-    ``_contrib_routed_experts``: nothing is dropped), and the shared expert
-    is added.  ``form`` is the experts' (and the shared expert's):
-    ``"gated_silu"``, three matrices, or ``"relu2"``, two; the shared
-    expert is ``shared_width`` wide (by default ``shared_experts × width``;
-    with ``shared_experts`` 0 there is none); ``score_function`` is the
-    router's, ``"sigmoid"`` or ``"softmax"`` over all experts;
-    ``scope`` is the ``jax.named_scope`` its parts are traced under.
-    Returns ``(y, load, rows)``: the assignments each held expert received
-    and the rows the grouped products ran.
-
-    With ``select_bias`` the block holds a bias per expert
-    (``select_bias``, no gradient) that is added to the scores to CHOOSE
-    the top k and never weighs them, and returns a fourth output, the
-    assignments to each of all ``experts_total`` experts: what the rule
-    that balances the bias reads (``balanced_bias``).  The block reads
-    the bias and does not write it: whoever owns the step applies the
-    rule, outside any rematerialisation boundary.  ``bias_init`` is the
-    bias's initializer (by default the one the block is initialised with).
-
-    ``router`` is by default one matrix inside the op (``router_weight``).
-    Given a block's constructor, ``router(prefix=...)``, that block is the
-    router: called ``router(h, *state)`` with whatever else the experts
-    were called with, it returns the logits ``(..., experts_total)`` and
-    its state after them, which the experts return after their own
-    outputs (a router that carries a state from layer to layer)."""
-
-    def __init__(self, hidden_size, width, experts_total, experts_held,
-                 first_expert, top_k, shared_experts=1, scaling=1.0,
-                 norm_topk=True, tile=256, form="gated_silu",
-                 shared_width=None, select_bias=False, scope="solar/moe",
-                 score_function="sigmoid", router=None, bias_init=None,
-                 prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._traced_as = scope
-        self._attrs = dict(
-            experts_total=experts_total, top_k=top_k,
-            first_expert=first_expert, routed_scaling_factor=scaling,
-            norm_topk_prob=norm_topk, tile=tile, expert_form=form,
-            select_bias=select_bias, score_function=score_function)
-        if shared_width is None:
-            shared_width = shared_experts * width
-        shared = {"gated_silu": GatedMLP, "relu2": Relu2MLP}[form]
-        with self.name_scope():
-            if router is None:
-                self.router = None
-                self.router_weight = self.params.get(
-                    "router_weight", shape=(experts_total, hidden_size))
-            else:
-                self.router = router(prefix="router_")
-                self._attrs["router"] = "logits"
-            self.w1 = self.params.get(
-                "w1", shape=(experts_held, width, hidden_size))
-            if form == "gated_silu":
-                self.w3 = self.params.get(
-                    "w3", shape=(experts_held, width, hidden_size))
-            self.w2 = self.params.get(
-                "w2", shape=(experts_held, hidden_size, width))
-            if select_bias:
-                self.select_bias = self.params.get(
-                    "select_bias", shape=(experts_total,), grad_req="null",
-                    init=bias_init)
-            self.shared = shared(hidden_size, shared_width,
-                                 prefix="shared_") if shared_width else None
-
-    def hybrid_forward(self, F, h, *state, w1, w2, router_weight=None,
-                       w3=None, select_bias=None):
-        routing = router_weight     # the matrix, or a block's logits
-        if self.router is not None:
-            with jax.named_scope(self._traced_as + "/router"):
-                routing, *state = self.router(h, *state)
-        inputs = [v for v in (h, routing, w1, w3, w2, select_bias)
-                  if v is not None]
-        with jax.named_scope(self._traced_as):      # the op's own scopes nest
-            y, *notes = F.contrib.routed_experts(*inputs, **self._attrs)
-        if self.shared is None:
-            return (y, *notes, *state)
-        with jax.named_scope(self._traced_as + "/shared"):
-            shared = self.shared(h)
-        with jax.named_scope(self._traced_as + "/combine"):
-            return (y + shared, *notes, *state)
-
-
-def balanced_bias(F, bias, counts, rate):
-    """The selection bias after one step of the auxiliary-loss-free
-    balancing rule (Wang et al. arXiv:2408.15664): an expert that received
-    fewer assignments than the mean is raised by ``rate``, one that
-    received more is lowered: ``b + rate · sign(mean(c) − c)``."""
-    return bias + rate * F.sign(F.mean(counts, axis=-1, keepdims=True)
-                                - counts)
+            return dense(F, o, o_weight, self._hidden)
 
 
 class SolarDecoderLayer(HybridBlock):
@@ -260,7 +163,7 @@ class SolarDecoderLayer(HybridBlock):
         return x + y, load, rows
 
 
-class SolarOpen2(HybridBlock):
+class SolarOpen2(RoutedExpertState, HybridBlock):
     """Token ids ``(batch, T)`` to logits ``(batch, T, vocab_size)``.
 
     ``layer_types`` names each layer's mixer, ``"attention"`` or ``"kda"``.
@@ -292,7 +195,7 @@ class SolarOpen2(HybridBlock):
             return SparseExperts(
                 hidden_size, expert_width, experts_total, experts_held,
                 first_expert, top_k, shared_experts, routed_scaling,
-                norm_topk, expert_tile, prefix=prefix)
+                norm_topk, expert_tile, scope="solar/moe", prefix=prefix)
 
         with self.name_scope():
             self.embed_weight = self.params.get(
@@ -306,13 +209,7 @@ class SolarOpen2(HybridBlock):
                                       prefix="final_norm_")
             self.head_weight = self.params.get(
                 "head_weight", shape=(vocab_size, hidden_size))
-            # auxiliary state, one row a layer: no gradient, no optimizer
-            self.expert_load = self.params.get(
-                "expert_load", shape=(len(layer_types), experts_held),
-                init="zeros", grad_req="null")
-            self.expert_rows = self.params.get(
-                "expert_rows", shape=(len(layer_types),), init="zeros",
-                grad_req="null")
+            self._declare_expert_state(experts_held)
 
     @property
     def remat_layers(self):
@@ -320,45 +217,22 @@ class SolarOpen2(HybridBlock):
         one (``gluon.block.remat_scope``)."""
         return list(self.layers)
 
+    @property
+    def expert_blocks(self):
+        return [layer.moe for layer in self.layers]
+
     def hybrid_forward(self, F, ids, embed_weight, head_weight, expert_load,
                        expert_rows):
         x = F.Embedding(ids, embed_weight, input_dim=self._vocab,
                         output_dim=self._hidden)
-        loads, rows = [], []
+        notes = []
         for layer in self.layers:
-            x, load, row = layer(x)
-            loads.append(load)
-            rows.append(row)
-        # outside the layers' remat boundaries: the step returns these as
-        # the forward's mutated state, with the loss, in the same program.
-        # Added, not overwritten: the state is the sum over the forwards
-        # made since it was last zero (whole numbers, exact in float32 up
-        # to 2**24 an entry)
-        with jax.named_scope("step/aux_state"):
-            expert_load._set_data(
-                (expert_load + F.stack(*loads, axis=0))._data)
-            expert_rows._set_data(
-                (expert_rows + F.concat(*rows, dim=0))._data)
+            x, *note = layer(x)
+            notes.append(note)
+        self._write_expert_state(F, notes, expert_load, expert_rows,
+                                 ids.context)
         with jax.named_scope("solar/head"):
-            return _dense(F, self.final_norm(x), head_weight, self._vocab)
-
-    def record_expert_load(self, arrays=None, steps=1):
-        """Set the ``mxnet_moe_*`` gauges from the auxiliary state, which
-        the forward adds to: the sums over the ``steps`` steps made since
-        it was zero.  ``arrays`` is ``{parameter name: array}`` of a train
-        step that owns the state (``dict(zip(step.param_names,
-        step.params))``), by default this block's own parameters.  One read
-        of two small arrays, made when somebody asks, never in the step.
-        Returns the two sums."""
-        import numpy as np
-
-        from .... import telemetry
-        load, rows = (
-            np.asarray(arrays[p.name]) if arrays is not None
-            else p.data().asnumpy()
-            for p in (self.expert_load, self.expert_rows))
-        telemetry.record_moe_load(load, rows, steps)
-        return load, rows
+            return dense(F, self.final_norm(x), head_weight, self._vocab)
 
 
 def solar_open2(config, **kwargs):

@@ -33,7 +33,7 @@ heads over ``G`` key/value heads of ``d``, ``g = H/G``:
 
     after the layers, in training mode only:
         β ← β + u · sign(mean(c) − c),  c the step's assignments to each of
-        ALL experts (``balanced_bias``, as ``nemotron_h``)
+        ALL experts (``blocks.balanced_bias``, as ``nemotron_h``)
 
 The model is built for ONE HOLDER'S SHARE of a deployment, as
 ``solar_open2`` is: it is told which routed experts and how many vocabulary
@@ -41,23 +41,20 @@ rows it holds, the router and its bias keep all their outputs, what an
 absent expert would add is left out and nothing stands in for the absent
 chips.  Every size is given at construction; the layers are the block's
 ``remat_layers``, and TWO activations cross each of their boundaries: the
-hidden state and the router's state.  Auxiliary state (no gradient, no
-optimizer): each layer's ``select_bias``, read inside its boundary and
-written after the layers; ``expert_load`` and ``expert_rows``, one row a
-layer, which every forward adds to.
+hidden state and the router's state.  Each layer's ``select_bias`` is
+read inside its boundary and written after the layers, with
+``expert_load`` and ``expert_rows`` (``blocks.RoutedExpertState``).
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
 
-from .... import autograd
 from .... import initializer as init_mod
 from .... import ndarray as nd
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
-from .granite import _dense
-from .solar_open2 import SparseExperts, balanced_bias
+from .blocks import RoutedExpertState, SparseExperts, dense
 
 __all__ = ["CompressedConvAttention", "ZayaRouter", "ZayaDecoderLayer",
            "Zaya", "zaya"]
@@ -149,10 +146,10 @@ class CompressedConvAttention(HybridBlock):
                                axes=(0, 2, 1, 3))
 
         with jax.named_scope("zaya/attention/proj"):
-            q = _dense(F, a, q_weight, q_width)
-            k = _dense(F, a, k_weight, kv_width)
-            v_now = _dense(F, a, v1_weight, kv_width // 2)
-            v_before = _dense(F, a, v2_weight, kv_width // 2)
+            q = dense(F, a, q_weight, q_width)
+            k = dense(F, a, k_weight, kv_width)
+            v_now = dense(F, a, v1_weight, kv_width // 2)
+            v_before = dense(F, a, v2_weight, kv_width // 2)
         with jax.named_scope("zaya/attention/mix"):
             c = F.contrib.causal_conv1d(
                 F.contrib.causal_conv1d(F.concat(q, k, dim=-1),
@@ -190,7 +187,7 @@ class CompressedConvAttention(HybridBlock):
         with jax.named_scope("zaya/attention/out"):
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))
-            return _dense(F, out, o_weight, self._hidden)
+            return dense(F, out, o_weight, self._hidden)
 
 
 class ZayaRouter(HybridBlock):
@@ -227,19 +224,19 @@ class ZayaRouter(HybridBlock):
         def f32(v):
             return F.cast(v, dtype="float32")
 
-        def dense(x, weight, bias, units):
+        def affine(x, weight, bias, units):
             return F.FullyConnected(x, f32(weight), f32(bias), flatten=False,
                                     num_hidden=units)
 
         with jax.default_matmul_precision("highest"):
-            r = dense(f32(m), down_weight, down_bias, self._width) \
+            r = affine(f32(m), down_weight, down_bias, self._width) \
                 + F.broadcast_mul(f32(r), f32(gamma))
             z = f32(self.norm(r))
             for weight, bias in ((fc1_weight, fc1_bias),
                                  (fc2_weight, fc2_bias)):
-                z = F.LeakyReLU(dense(z, weight, bias, self._width),
+                z = F.LeakyReLU(affine(z, weight, bias, self._width),
                                 act_type="gelu")
-            return dense(z, out_weight, out_bias, self._total), r
+            return affine(z, out_weight, out_bias, self._total), r
 
 
 class ResidualScale(HybridBlock):
@@ -290,7 +287,7 @@ class ZayaDecoderLayer(HybridBlock):
         return self.moe_residual(x, y), r, load, rows, counts
 
 
-class Zaya(HybridBlock):
+class Zaya(RoutedExpertState, HybridBlock):
     """Token ids ``(batch, T)`` to logits ``(batch, T, vocab_size)``.
 
     ``experts_held`` and ``vocab_size`` are what this holder has of the
@@ -311,7 +308,6 @@ class Zaya(HybridBlock):
         super().__init__(prefix=prefix, params=params)
         self._vocab, self._hidden = vocab_size, hidden_size
         self._router_width = router_width
-        self._bias_rate = float(bias_update_rate)
 
         def attention(prefix):
             return CompressedConvAttention(
@@ -341,13 +337,7 @@ class Zaya(HybridBlock):
                         attention, experts, hidden_size, epsilon))
             self.final_norm = RMSNorm(hidden_size, epsilon,
                                       prefix="final_norm_")
-            # auxiliary state, one row a layer: no gradient, no optimizer
-            self.expert_load = self.params.get(
-                "expert_load", shape=(num_layers, experts_held),
-                init="zeros", grad_req="null")
-            self.expert_rows = self.params.get(
-                "expert_rows", shape=(num_layers,), init="zeros",
-                grad_req="null")
+            self._declare_expert_state(experts_held, bias_update_rate)
 
     @property
     def remat_layers(self):
@@ -355,53 +345,33 @@ class Zaya(HybridBlock):
         one (``gluon.block.remat_scope``)."""
         return list(self.layers)
 
+    @property
+    def expert_blocks(self):
+        return [layer.moe for layer in self.layers]
+
     def hybrid_forward(self, F, ids, embed_weight, expert_load, expert_rows):
         x = F.Embedding(ids, embed_weight, input_dim=self._vocab,
                         output_dim=self._hidden)
         r = F.zeros(ids.shape + (self._router_width,), ctx=ids.context)
-        loads, rows, counts = [], [], []
+        notes = []
         for layer in self.layers:
-            x, r, load, row, count = layer(x, r)
-            loads.append(load)
-            rows.append(row)
-            counts.append(count)
-        # outside the layers' remat boundaries, as NemotronH's: the two
-        # counts are added to, the biases the layers have read are written
-        # for the next step
-        with jax.named_scope("step/aux_state"):
-            expert_load._set_data(
-                (expert_load + F.stack(*loads, axis=0))._data)
-            expert_rows._set_data(
-                (expert_rows + F.concat(*rows, dim=0))._data)
-            if autograd.is_training():
-                for layer, count in zip(self.layers, counts):
-                    bias = layer.moe.select_bias.data(ids.context)
-                    bias._set_data(balanced_bias(
-                        F, bias, count, self._bias_rate)._data)
+            x, r, *note = layer(x, r)
+            notes.append(note)
+        self._write_expert_state(F, notes, expert_load, expert_rows,
+                                 ids.context)
         with jax.named_scope("zaya/head"):
-            return _dense(F, self.final_norm(x), embed_weight, self._vocab)
+            return dense(F, self.final_norm(x), embed_weight, self._vocab)
 
     def record_expert_load(self, arrays=None, steps=1):
-        """Set the ``mxnet_moe_*`` gauges and
-        ``mxnet_router_eda_gamma_abs_mean`` from the step's state: the two
-        counts sum over the ``steps`` steps made since they were zero, the
-        selection biases and the routers' ``gamma`` are as the last step
-        left them.  ``arrays`` is ``{parameter name: array}`` of a train
-        step that owns the state (``dict(zip(step.param_names,
-        step.params))``), by default this block's own parameters.  One
-        read of a few small arrays, made when somebody asks, never in the
-        step.  Returns the two sums."""
+        """``RoutedExpertState.record_expert_load``, and
+        ``mxnet_router_eda_gamma_abs_mean`` from the routers' ``gamma`` as
+        the last step left them."""
         from .... import telemetry
 
-        def host(p):
-            return np.asarray(arrays[p.name], np.float32) \
-                if arrays is not None else p.data().asnumpy()
-
-        load, rows = host(self.expert_load), host(self.expert_rows)
-        telemetry.record_moe_load(load, rows, steps, bias=np.stack(
-            [host(layer.moe.select_bias) for layer in self.layers]))
+        load, rows = super().record_expert_load(arrays, steps)
         telemetry.record_router_eda_gamma(np.stack(
-            [host(layer.moe.router.gamma) for layer in self.layers]))
+            [self._host(experts.router.gamma, arrays)
+             for experts in self.expert_blocks]))
         return load, rows
 
 
