@@ -3,7 +3,8 @@ the routed-expert auxiliary state every model with routed experts keeps.
 
 Here: ``dense`` (``x Wᵀ``, no bias); the Mamba-2 initializers
 (``MambaALog``, ``MambaDtBias``) and ``Mamba2Mixer``;
-``GroupedQueryAttention``; the two MLPs (``GatedMLP``, ``Relu2MLP``);
+``KimiDeltaAttention``; ``GroupedQueryAttention``;
+``MultiHeadLatentAttention``; the two MLPs (``GatedMLP``, ``Relu2MLP``);
 ``SparseExperts`` and the balancing rule of its selection bias
 (``balanced_bias``); ``RoutedExpertState``, the one owner of a model's
 ``expert_load`` and ``expert_rows``.
@@ -13,7 +14,8 @@ model's file, and only ``__init__.py`` imports the model files.  What one
 model alone uses stays in its file; a block a second model needs moves
 here in the change that needs it.  A block keeps the ``jax.named_scope``
 names it was first traced under (``granite/...``): the trace readers and
-the tests key on them.
+the tests key on them; a block two models trace under their own names
+takes its ``scope`` from the model.
 """
 from __future__ import annotations
 
@@ -28,7 +30,8 @@ from .... import ndarray as nd
 from ...block import HybridBlock
 from ...nn import RMSNorm
 
-__all__ = ["Mamba2Mixer", "GroupedQueryAttention", "GatedMLP", "Relu2MLP",
+__all__ = ["Mamba2Mixer", "KimiDeltaAttention", "GroupedQueryAttention",
+           "MultiHeadLatentAttention", "GatedMLP", "Relu2MLP",
            "SparseExperts", "balanced_bias"]
 
 
@@ -137,6 +140,109 @@ class Mamba2Mixer(HybridBlock):
             return dense(F, y, out_proj_weight, self._hidden)
 
 
+class KimiDeltaAttention(HybridBlock):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692) over
+    ``num_heads`` heads of ``head_dim``: the gated delta rule with a decay
+    per key channel (op ``_contrib_kda_scan``, chunks of ``chunk_size``)
+    between short causal convolutions and a per-head RMSNorm with a
+    low-rank sigmoid gate.  Per head of d:
+
+        q = l2norm(silu(conv(W_q h))) / sqrt(d);  k = l2norm(silu(conv(W_k h)))
+        v = silu(conv(W_v h))
+        g_t = −exp(A_log) · softplus(W_a↑ W_a↓ h_t + dt_bias)      ≤ 0
+        β_t = 2 · sigmoid(w_β · h_t)   (1 · without ``neg_eigval``)
+        S_t = (I − β_t k_t k_tᵀ) Diag(exp g_t) S_{t−1} + β_t k_t v_tᵀ
+        o_t = S_tᵀ q_t;  out = W_o (RMSNorm_head(o_t) ⊙ sigmoid(W_g↑ W_g↓ h_t))
+
+    The convolutions carry a bias unless ``conv_bias`` is false; ``scope``
+    names the ``jax.named_scope`` its parts are traced under."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
+                 low_rank=None, chunk_size=64, neg_eigval=True, epsilon=1e-5,
+                 conv_bias=True, scope="kda", prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads, self._head_dim = num_heads, head_dim
+        self._inner = inner = num_heads * head_dim
+        self._hidden, self._chunk = hidden_size, chunk_size
+        self._rank = rank = head_dim if low_rank is None else low_rank
+        self._beta_scale = 2.0 if neg_eigval else 1.0
+        self._traced_as = scope
+        conv_init = init_mod.Uniform(conv_kernel ** -0.5)
+        with self.name_scope():
+            for name in "qkv":
+                setattr(self, name + "_weight", self.params.get(
+                    name + "_weight", shape=(inner, hidden_size)))
+                setattr(self, name + "_conv_weight", self.params.get(
+                    name + "_conv_weight", shape=(inner, conv_kernel),
+                    init=conv_init))
+                if conv_bias:
+                    setattr(self, name + "_conv_bias", self.params.get(
+                        name + "_conv_bias", shape=(inner,), init="zeros"))
+            self.a_down_weight = self.params.get(
+                "a_down_weight", shape=(rank, hidden_size))
+            self.a_up_weight = self.params.get(
+                "a_up_weight", shape=(inner, rank))
+            self.A_log = self.params.get(
+                "A_log", shape=(num_heads,), init=MambaALog())
+            self.dt_bias = self.params.get(
+                "dt_bias", shape=(inner,), init=MambaDtBias())
+            self.beta_weight = self.params.get(
+                "beta_weight", shape=(num_heads, hidden_size))
+            self.g_down_weight = self.params.get(
+                "g_down_weight", shape=(rank, hidden_size))
+            self.g_up_weight = self.params.get(
+                "g_up_weight", shape=(inner, rank))
+            self.norm = RMSNorm(head_dim, epsilon, prefix="norm_")
+            self.o_weight = self.params.get(
+                "o_weight", shape=(hidden_size, inner))
+
+    def hybrid_forward(self, F, h, *, q_weight, q_conv_weight, k_weight,
+                       k_conv_weight, v_weight, v_conv_weight, a_down_weight,
+                       a_up_weight, A_log, dt_bias, beta_weight,
+                       g_down_weight, g_up_weight, o_weight,
+                       q_conv_bias=None, k_conv_bias=None, v_conv_bias=None):
+        inner, scope = self._inner, self._traced_as
+
+        def heads(x):      # (batch, T, H·d) -> (batch, T, H, d)
+            return F.reshape(x, shape=(0, 0, self._heads, self._head_dim))
+
+        def unit(x):       # each head's vector to length 1
+            return x * F.rsqrt(F.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+        def conv(x, w, b):
+            if b is None:
+                return F.contrib.causal_conv1d(x, w, no_bias=True)
+            return F.contrib.causal_conv1d(x, w, b)
+
+        with jax.named_scope(scope + "/proj"):
+            q, k, v = (dense(F, h, w, inner)
+                       for w in (q_weight, k_weight, v_weight))
+        with jax.named_scope(scope + "/conv"):
+            q, k, v = (heads(F.Activation(conv(x, w, b), act_type="silu"))
+                       for x, w, b in ((q, q_conv_weight, q_conv_bias),
+                                       (k, k_conv_weight, k_conv_bias),
+                                       (v, v_conv_weight, v_conv_bias)))
+            q = unit(q) * self._head_dim ** -0.5
+            k = unit(k)
+        with jax.named_scope(scope + "/gates"):
+            step = F.Activation(F.broadcast_add(
+                dense(F, dense(F, h, a_down_weight, self._rank),
+                      a_up_weight, inner),
+                F.reshape(dt_bias, shape=(1, 1, -1))), act_type="softrelu")
+            g = F.broadcast_mul(
+                heads(step), -F.exp(F.reshape(A_log, shape=(1, 1, -1, 1))))
+            beta = self._beta_scale * F.sigmoid(
+                dense(F, h, beta_weight, self._heads))
+            gate = heads(F.sigmoid(dense(
+                F, dense(F, h, g_down_weight, self._rank), g_up_weight,
+                inner)))
+        with jax.named_scope(scope + "/scan"):
+            o = F.contrib.kda_scan(q, k, v, g, beta, chunk_size=self._chunk)
+        with jax.named_scope(scope + "/out"):
+            o = F.reshape(self.norm(o) * gate, shape=(0, 0, -1))
+            return dense(F, o, o_weight, self._hidden)
+
+
 class GroupedQueryAttention(HybridBlock):
     """Self-attention with ``num_kv_heads`` key/value heads under
     ``num_heads`` query heads, no bias: ``softmax(q kᵀ · scale) v`` through
@@ -213,6 +319,84 @@ class GroupedQueryAttention(HybridBlock):
             if g_weight is not None:
                 out = out * F.sigmoid(dense(F, h, g_weight,
                                             self._heads * self._head_dim))
+            return dense(F, out, o_weight, self._hidden)
+
+
+class MultiHeadLatentAttention(HybridBlock):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+    with every head's keys and values expanded from ONE latent a token,
+    without positions (Kimi Linear's ``mla_use_nope``), causal, no bias.
+    ``h`` is (T, hidden), matrices are stored (out, in), H heads:
+
+        [q_nope ; q_pe] = W_q h                a head: nope_dim + rope_dim
+        [c_kv ; k_pe]   = W_kva h              kv_rank + rope_dim, k_pe ONE
+                                               for all heads
+        [k_nope ; v]    = W_kvb RMSNorm(c_kv)  a head: nope_dim + v_dim
+        q = [q_nope ; q_pe];  k = [k_nope ; k_pe]
+        out = W_o softmax(q kᵀ / sqrt(nope_dim + rope_dim) + causal) v
+
+    through the flash kernels, whose values keep their own head size
+    (op ``_contrib_flash_attention``).  ``scope`` names the
+    ``jax.named_scope`` the kernel call is traced under, with its parts
+    ``proj``, ``latent`` and ``out`` inside it."""
+
+    BLOCK = GroupedQueryAttention.BLOCK     # the flash kernel's tiles
+
+    def __init__(self, hidden_size, num_heads, nope_dim, rope_dim, v_dim,
+                 kv_rank, epsilon=1e-5, scope="mla", prefix=None,
+                 params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._heads, self._hidden = num_heads, hidden_size
+        self._nope, self._rope, self._v = nope_dim, rope_dim, v_dim
+        self._rank, self._traced_as = kv_rank, scope
+        with self.name_scope():
+            self.q_weight = self.params.get(
+                "q_weight", shape=(num_heads * (nope_dim + rope_dim),
+                                   hidden_size))
+            self.kv_a_weight = self.params.get(
+                "kv_a_weight", shape=(kv_rank + rope_dim, hidden_size))
+            self.latent_norm = RMSNorm(kv_rank, epsilon,
+                                       prefix="latent_norm_")
+            self.kv_b_weight = self.params.get(
+                "kv_b_weight", shape=(num_heads * (nope_dim + v_dim),
+                                      kv_rank))
+            self.o_weight = self.params.get(
+                "o_weight", shape=(hidden_size, num_heads * v_dim))
+
+    def hybrid_forward(self, F, h, q_weight, kv_a_weight, kv_b_weight,
+                       o_weight):
+        from .... import telemetry
+        heads, nope, rope, scope = (self._heads, self._nope, self._rope,
+                                    self._traced_as)
+        d_qk = nope + rope
+        telemetry.record_mla_latent_channels(self._rank, rope)
+
+        def in_heads(x):        # (batch, T, H, n) -> (batch, H, T, n)
+            return F.transpose(x, axes=(0, 2, 1, 3))
+
+        with jax.named_scope(scope + "/proj"):
+            q = in_heads(F.reshape(dense(F, h, q_weight, heads * d_qk),
+                                   shape=(0, 0, heads, d_qk)))
+            latent = dense(F, h, kv_a_weight, self._rank + rope)
+        with jax.named_scope(scope + "/latent"):
+            kv = F.reshape(dense(
+                F, self.latent_norm(F.slice_axis(
+                    latent, axis=-1, begin=0, end=self._rank)),
+                kv_b_weight, heads * (nope + self._v)),
+                shape=(0, 0, heads, nope + self._v))
+            k_pe = F.broadcast_to(F.reshape(
+                F.slice_axis(latent, axis=-1, begin=self._rank, end=None),
+                shape=(0, 0, 1, rope)), shape=(0, 0, heads, rope))
+            k = in_heads(F.concat(
+                F.slice_axis(kv, axis=-1, begin=0, end=nope), k_pe, dim=-1))
+            v = in_heads(F.slice_axis(kv, axis=-1, begin=nope, end=None))
+        with jax.named_scope(scope):
+            out = F.contrib.flash_attention(
+                q, k, v, mask="causal", sm_scale=d_qk ** -0.5,
+                block_q=self.BLOCK, block_k=self.BLOCK)
+        with jax.named_scope(scope + "/out"):
+            out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                            shape=(0, 0, -1))
             return dense(F, out, o_weight, self._hidden)
 
 

@@ -15,7 +15,7 @@ the convolutions':
         heads of d, no positions, causal,
         a = softmax(q kᵀ / sqrt(d)) v;   out = W_o (sigmoid(W_g h) ⊙ a)
 
-    KDA mixer, per head of d:
+    KDA mixer (``blocks.KimiDeltaAttention``), per head of d:
         q = l2norm(silu(conv(W_q h))) / sqrt(d);  k = l2norm(silu(conv(W_k h)))
         v = silu(conv(W_v h))
         g_t = −exp(A_log) · softplus(W_a↑ W_a↓ h_t + dt_bias)      in R^d, ≤ 0
@@ -41,100 +41,12 @@ from __future__ import annotations
 
 import jax
 
-from .... import initializer as init_mod
 from ...block import HybridBlock
 from ...nn import HybridSequential, RMSNorm
-from .blocks import (GroupedQueryAttention, MambaALog, MambaDtBias,
+from .blocks import (GroupedQueryAttention, KimiDeltaAttention,
                      RoutedExpertState, SparseExperts, dense)
 
-__all__ = ["KimiDeltaAttention", "SolarDecoderLayer", "SolarOpen2",
-           "solar_open2"]
-
-
-class KimiDeltaAttention(HybridBlock):
-    """Kimi Delta Attention over ``num_heads`` heads of ``head_dim``: the
-    gated delta rule with a decay per key channel (op
-    ``_contrib_kda_scan``, chunks of ``chunk_size``) between short causal
-    convolutions and a per-head RMSNorm with a low-rank sigmoid gate."""
-
-    def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
-                 low_rank=None, chunk_size=64, neg_eigval=True, epsilon=1e-5,
-                 prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._heads, self._head_dim = num_heads, head_dim
-        self._inner = inner = num_heads * head_dim
-        self._hidden, self._chunk = hidden_size, chunk_size
-        self._rank = rank = head_dim if low_rank is None else low_rank
-        self._beta_scale = 2.0 if neg_eigval else 1.0
-        conv_init = init_mod.Uniform(conv_kernel ** -0.5)
-        with self.name_scope():
-            for name in "qkv":
-                setattr(self, name + "_weight", self.params.get(
-                    name + "_weight", shape=(inner, hidden_size)))
-                setattr(self, name + "_conv_weight", self.params.get(
-                    name + "_conv_weight", shape=(inner, conv_kernel),
-                    init=conv_init))
-                setattr(self, name + "_conv_bias", self.params.get(
-                    name + "_conv_bias", shape=(inner,), init="zeros"))
-            self.a_down_weight = self.params.get(
-                "a_down_weight", shape=(rank, hidden_size))
-            self.a_up_weight = self.params.get(
-                "a_up_weight", shape=(inner, rank))
-            self.A_log = self.params.get(
-                "A_log", shape=(num_heads,), init=MambaALog())
-            self.dt_bias = self.params.get(
-                "dt_bias", shape=(inner,), init=MambaDtBias())
-            self.beta_weight = self.params.get(
-                "beta_weight", shape=(num_heads, hidden_size))
-            self.g_down_weight = self.params.get(
-                "g_down_weight", shape=(rank, hidden_size))
-            self.g_up_weight = self.params.get(
-                "g_up_weight", shape=(inner, rank))
-            self.norm = RMSNorm(head_dim, epsilon, prefix="norm_")
-            self.o_weight = self.params.get(
-                "o_weight", shape=(hidden_size, inner))
-
-    def hybrid_forward(self, F, h, q_weight, q_conv_weight, q_conv_bias,
-                       k_weight, k_conv_weight, k_conv_bias, v_weight,
-                       v_conv_weight, v_conv_bias, a_down_weight,
-                       a_up_weight, A_log, dt_bias, beta_weight,
-                       g_down_weight, g_up_weight, o_weight):
-        inner = self._inner
-
-        def heads(x):      # (batch, T, H·d) -> (batch, T, H, d)
-            return F.reshape(x, shape=(0, 0, self._heads, self._head_dim))
-
-        def unit(x):       # each head's vector to length 1
-            return x * F.rsqrt(F.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-        with jax.named_scope("solar/kda/proj"):
-            q, k, v = (dense(F, h, w, inner)
-                       for w in (q_weight, k_weight, v_weight))
-        with jax.named_scope("solar/kda/conv"):
-            q, k, v = (heads(F.Activation(
-                F.contrib.causal_conv1d(x, w, b), act_type="silu"))
-                for x, w, b in ((q, q_conv_weight, q_conv_bias),
-                                (k, k_conv_weight, k_conv_bias),
-                                (v, v_conv_weight, v_conv_bias)))
-            q = unit(q) * self._head_dim ** -0.5
-            k = unit(k)
-        with jax.named_scope("solar/kda/gates"):
-            step = F.Activation(F.broadcast_add(
-                dense(F, dense(F, h, a_down_weight, self._rank),
-                       a_up_weight, inner),
-                F.reshape(dt_bias, shape=(1, 1, -1))), act_type="softrelu")
-            g = F.broadcast_mul(
-                heads(step), -F.exp(F.reshape(A_log, shape=(1, 1, -1, 1))))
-            beta = self._beta_scale * F.sigmoid(
-                dense(F, h, beta_weight, self._heads))
-            gate = heads(F.sigmoid(dense(
-                F, dense(F, h, g_down_weight, self._rank), g_up_weight,
-                inner)))
-        with jax.named_scope("solar/kda/scan"):
-            o = F.contrib.kda_scan(q, k, v, g, beta, chunk_size=self._chunk)
-        with jax.named_scope("solar/kda/out"):
-            o = F.reshape(self.norm(o) * gate, shape=(0, 0, -1))
-            return dense(F, o, o_weight, self._hidden)
+__all__ = ["SolarDecoderLayer", "SolarOpen2", "solar_open2"]
 
 
 class SolarDecoderLayer(HybridBlock):
@@ -185,7 +97,8 @@ class SolarOpen2(RoutedExpertState, HybridBlock):
         mixers = {
             "kda": lambda prefix: KimiDeltaAttention(
                 hidden_size, kda_heads, kda_head_dim, kda_conv, kda_low_rank,
-                kda_chunk, kda_neg_eigval, epsilon, prefix=prefix),
+                kda_chunk, kda_neg_eigval, epsilon, scope="solar/kda",
+                prefix=prefix),
             "attention": lambda prefix: GroupedQueryAttention(
                 hidden_size, num_heads, num_kv_heads, head_dim,
                 head_dim ** -0.5, gate=attention_gate, prefix=prefix),

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ._op_nn import _wb_names
 from .registry import register
 from ..base import MXNetError
 
@@ -84,12 +85,13 @@ def _rotary_embedding(attrs, x, positions):
 
 # --- causal convolution over time: depthwise, or grouped ------------------------
 @register("_contrib_causal_conv1d", alias=("causal_conv1d",),
-          input_names=("data", "weight", "bias"))
-def _causal_conv1d(attrs, x, weight, bias):
+          input_names=_wb_names)
+def _causal_conv1d(attrs, x, weight, bias=None):
     """``y[b, t, c] = bias[c] + Σ_k weight[c, k] · x[b, t − (K−1) + k, c]``
     with zeros before the sequence: ``x`` (batch, time, channels),
     ``weight`` (channels, K) as a depthwise ``Conv1d`` stores it (its last
-    tap multiplies the current step).
+    tap multiplies the current step).  With ``no_bias`` there is no
+    ``bias`` input and no term for it.
 
     A 3-d ``weight`` (channels, channels/groups, K), as a grouped
     ``Conv1d`` stores it, mixes the channels of a group: output channel
@@ -105,9 +107,10 @@ def _causal_conv1d(attrs, x, weight, bias):
     k = weight.shape[1]
     t = x.shape[1]
     xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
-    y = bias.astype(x.dtype)
+    y = None if bias is None else bias.astype(x.dtype)
     for j in range(k):
-        y = y + xp[:, j:j + t, :] * weight[:, j].astype(x.dtype)
+        tap = xp[:, j:j + t, :] * weight[:, j].astype(x.dtype)
+        y = tap if y is None else y + tap
     return y
 
 
@@ -122,11 +125,11 @@ def _grouped_causal_conv1d(x, weight, bias):
     groups, t = channels // per_group, x.shape[1]
     xp = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
     w = weight.astype(x.dtype).reshape(groups, per_group, per_group, k)
-    y = bias.astype(x.dtype)
+    y = None if bias is None else bias.astype(x.dtype)
     for j in range(k):
         tap = xp[:, j:j + t, :].reshape(x.shape[:2] + (groups, per_group))
-        y = y + jnp.einsum("btgi,goi->btgo", tap, w[..., j]
-                           ).reshape(x.shape)
+        tap = jnp.einsum("btgi,goi->btgo", tap, w[..., j]).reshape(x.shape)
+        y = tap if y is None else y + tap
     return y
 
 

@@ -30,6 +30,11 @@ divisor of its head count); query head ``i`` reads key/value head
 ``i // (h_q / h_kv)``.  The forward kernel does that in the key/value
 blocks' index maps, so no head is ever repeated in HBM.
 
+A value head may be narrower or wider than a query/key head (multi-head
+latent attention: keys of 192 channels over values of 128): q, k, dQ and
+dK take the query/key size, v, the output, its accumulator, dO and dV
+the value's, and nothing is padded to the larger.
+
 Backward: custom_vjp whose forward rule keeps ``(q, k, v, out, lse)``
 with ``lse = m + log(l)``, one float32 a query row, and whose backward is
 two more Pallas kernels over the same tiles.  Both recompute a tile's
@@ -378,53 +383,57 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k):
     """``causal``, here and below: a bool or a ``Mask``."""
     mask = Mask.of(causal)
     b, h, s, d = q.shape
+    d_v = v.shape[-1]
     group = h // k.shape[1]       # query heads per key/value head
     bq, bk, s_pad = _tiles(s, block_q, block_k)
     q, k, v = _pad_seq(s_pad, q, k, v)
     bh = b * h
     qf = q.reshape(bh, s_pad, d)
     kf = k.reshape(bh // group, s_pad, d)
-    vf = v.reshape(bh // group, s_pad, d)
+    vf = v.reshape(bh // group, s_pad, d_v)
 
     q_spec = pl.BlockSpec((1, bq, d), lambda i, *t: (i, _q_tile(*t), 0))
+    o_spec = pl.BlockSpec((1, bq, d_v), lambda i, *t: (i, _q_tile(*t), 0))
     # flat q index = batch * h + head, so // group is batch * h_kv + kv head
-    kv_spec = pl.BlockSpec((1, bk, d),
-                           lambda i, *t: (i // group, _k_tile(*t), 0))
+    k_spec, v_spec = (pl.BlockSpec((1, bk, n),
+                                   lambda i, *t: (i // group, _k_tile(*t), 0))
+                      for n in (d, d_v))
     stat_spec = pl.BlockSpec((1, bq, 128), lambda i, *t: (i, _q_tile(*t), 0))
     out, m_out, l_out = _table_call(
         functools.partial(_attn_kernel, block_q=bq, block_k=bk, s_actual=s,
                           sm_scale=sm_scale, mask=mask),
         tile_table(mask, s, bq, bk), bh,
-        [q_spec, kv_spec, kv_spec], (q_spec, stat_spec, stat_spec),
-        (jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
+        [q_spec, k_spec, v_spec], (o_spec, stat_spec, stat_spec),
+        (jax.ShapeDtypeStruct((bh, s_pad, d_v), q.dtype),
          jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32),
          jax.ShapeDtypeStruct((bh, s_pad, 128), jnp.float32)),
-        [pltpu.VMEM((bq, d), jnp.float32),       # acc
+        [pltpu.VMEM((bq, d_v), jnp.float32),     # acc
          pltpu.VMEM((bq, 128), jnp.float32),     # running max (lane-bcast)
          pltpu.VMEM((bq, 128), jnp.float32)],    # running sum (lane-bcast)
         "mx_flash_attention_fwd", (qf, kf, vf))
-    out = out.reshape(b, h, s_pad, d)[:, :, :s, :]
+    out = out.reshape(b, h, s_pad, d_v)[:, :, :s, :]
     m_out = m_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
     l_out = l_out[:, :, 0].reshape(b, h, s_pad)[:, :, :s]
     return out, m_out, l_out
 
 
 def _check_heads(q, k, v):
-    if k.shape != v.shape or q.shape[1] % k.shape[1] or \
+    if k.shape[:3] != v.shape[:3] or q.shape[1] % k.shape[1] or \
             (q.shape[0], q.shape[2], q.shape[3]) != \
             (k.shape[0], k.shape[2], k.shape[3]):
         raise ValueError(
             f"flash_attention: q {q.shape} against k {k.shape}, v {v.shape}: "
-            "batch, length and head size must agree and the key/value head "
-            "count must divide the query head count")
+            "batch and length must agree, q and k their head size, k and v "
+            "their head count, and the key/value head count must divide the "
+            "query head count")
 
 
 def _reference_attention(q, k, v, causal, sm_scale):
-    """Plain XLA attention: the (S, S) scores in HBM.  k, v: (batch, h_kv,
-    S, d) with h_kv dividing q's head count."""
+    """Plain XLA attention: the (S, S) scores in HBM.  k: (batch, h_kv, S,
+    d) with h_kv dividing q's head count; v: (batch, h_kv, S, d_v)."""
     mask = Mask.of(causal)
     b, h, s, d = q.shape
-    h_kv = k.shape[1]
+    h_kv, d_v = k.shape[1], v.shape[-1]
     qg = q.astype(jnp.float32).reshape(b, h_kv, h // h_kv, s, d)
     logits = jnp.einsum("bkgqd,bksd->bkgqs", qg,
                         k.astype(jnp.float32)) * sm_scale
@@ -433,7 +442,7 @@ def _reference_attention(q, k, v, causal, sm_scale):
         logits = jnp.where(allowed, logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
-    return out.reshape(b, h, s, d).astype(q.dtype)
+    return out.reshape(b, h, s, d_v).astype(q.dtype)
 
 
 def _static_sm_scale(sm_scale, head_dim):
@@ -467,10 +476,11 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                     block_k=128):
     """softmax(q kᵀ / √d) v with O(S·D) memory.
 
-    q: (batch, heads, seq, head_dim); k, v the same or with fewer heads
-    (grouped queries: a divisor of q's head count).  sm_scale defaults
-    to 1/sqrt(head_dim).  ``causal`` is a bool or a ``Mask`` (static:
-    the kernels are built for it).
+    q: (batch, heads, seq, head_dim); k the same or with fewer heads
+    (grouped queries: a divisor of q's head count); v k's heads with a
+    head size of its own, ``d_v``, which the output takes: (batch, heads,
+    seq, d_v).  sm_scale defaults to 1/sqrt(head_dim).  ``causal`` is a
+    bool or a ``Mask`` (static: the kernels are built for it).
 
     ``sm_scale`` is a STATIC kernel parameter (baked into the pallas
     grid function), so it must be a python scalar, never a traced
@@ -619,7 +629,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     past ``s`` are zero and the caller cuts them off."""
     mask = Mask.of(causal)
     b, h, s, d = q.shape
-    h_kv = k.shape[1]
+    h_kv, d_v = k.shape[1], v.shape[-1]
     group = h // h_kv
     bq, bk, s_pad = _tiles(s, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
@@ -627,23 +637,26 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     # and dS is zero; padded keys are masked, so P is zero there
     q, k, v, do, lse, delta = _pad_seq(s_pad, q, k, v, do, lse, delta)
     bh = b * h
-    qf, dof = q.reshape(bh, s_pad, d), do.reshape(bh, s_pad, d)
-    kf, vf = (a.reshape(b * h_kv, s_pad, d) for a in (k, v))
+    qf, dof = q.reshape(bh, s_pad, d), do.reshape(bh, s_pad, d_v)
+    kf, vf = k.reshape(b * h_kv, s_pad, d), v.reshape(b * h_kv, s_pad, d_v)
     params = dict(block_q=bq, block_k=bk, s_actual=s, sm_scale=sm_scale,
                   mask=mask)
 
     # dq: the forward's table; statistics one value a row, broadcast over a
     # lane tile as the forward writes its own
-    q_spec = pl.BlockSpec((1, bq, d), lambda i, *t: (i, _q_tile(*t), 0))
-    kv_spec = pl.BlockSpec((1, bk, d),
-                           lambda i, *t: (i // group, _k_tile(*t), 0))
+    q_spec, do_spec = (pl.BlockSpec((1, bq, n),
+                                    lambda i, *t: (i, _q_tile(*t), 0))
+                       for n in (d, d_v))
+    k_spec, v_spec = (pl.BlockSpec((1, bk, n),
+                                   lambda i, *t: (i // group, _k_tile(*t), 0))
+                      for n in (d, d_v))
     col_spec = pl.BlockSpec((1, bq, 128), lambda i, *t: (i, _q_tile(*t), 0))
     cols = [jnp.broadcast_to(a.reshape(bh, s_pad, 1), (bh, s_pad, 128))
             for a in (lse, delta)]
     dq = _table_call(
         functools.partial(_bwd_dq_kernel, **params),
         tile_table(mask, s, bq, bk), bh,
-        [q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec], q_spec,
+        [q_spec, k_spec, v_spec, do_spec, col_spec, col_spec], q_spec,
         jax.ShapeDtypeStruct((bh, s_pad, d), q.dtype),
         [pltpu.VMEM((bq, d), jnp.float32)],
         "mx_flash_attention_bwd_dq", (qf, kf, vf, dof, *cols))
@@ -651,9 +664,12 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     # dk, dv: a key tile's sweep runs over its whole group of query heads
     # (flat query head = (batch · h_kv + kv head) · group + head of the
     # group), so its block and both accumulators stay where they are
-    q_spec = pl.BlockSpec(
-        (1, bq, d), lambda i, *t: (i * group + _head(*t), _q_tile(*t), 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda i, *t: (i, _k_tile(*t), 0))
+    q_spec, do_spec = (pl.BlockSpec(
+        (1, bq, n), lambda i, *t: (i * group + _head(*t), _q_tile(*t), 0))
+        for n in (d, d_v))
+    k_spec, v_spec = (pl.BlockSpec((1, bk, n),
+                                   lambda i, *t: (i, _k_tile(*t), 0))
+                      for n in (d, d_v))
     # one row of bq statistics a block: the block's last two dimensions are
     # the array's, whatever bq is
     row_spec = pl.BlockSpec(
@@ -663,14 +679,15 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, sm_scale, block_q, block_k):
     dk, dv = _table_call(
         functools.partial(_bwd_dkv_kernel, **params),
         tile_table(mask, s, bq, bk, True, group), b * h_kv,
-        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        (kv_spec, kv_spec),
+        [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        (k_spec, v_spec),
         (jax.ShapeDtypeStruct(kf.shape, k.dtype),
          jax.ShapeDtypeStruct(vf.shape, v.dtype)),
-        [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
+        [pltpu.VMEM((bk, d), jnp.float32),
+         pltpu.VMEM((bk, d_v), jnp.float32)],
         "mx_flash_attention_bwd_dkv", (qf, kf, vf, dof, *rows))
     return (dq.reshape(b, h, s_pad, d), dk.reshape(b, h_kv, s_pad, d),
-            dv.reshape(b, h_kv, s_pad, d))
+            dv.reshape(b, h_kv, s_pad, d_v))
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, g):

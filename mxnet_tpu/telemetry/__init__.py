@@ -215,6 +215,12 @@ _CCA_LATENT = REGISTRY.gauge(
     "attention layer works in, by part=q (query heads x head size) or kv "
     "(key/value heads x head size): what the projections go to, the "
     "convolutions mix and the kernel reads, against the hidden size")
+_MLA_LATENT = REGISTRY.gauge(
+    "mxnet_mla_latent_channels",
+    "channels of what the last traced multi-head latent attention layer "
+    "keeps a token, by part=kv (the joint key/value latent every head's "
+    "keys and values are expanded from) or rope (the key channels shared "
+    "by all heads): what a cache of the layer would hold")
 _ROUTER_EDA_GAMMA = REGISTRY.gauge(
     "mxnet_router_eda_gamma_abs_mean",
     "mean |gamma| over the layers of a router that carries its state from "
@@ -375,6 +381,13 @@ def record_cca_latent_channels(q, kv):
     attention call."""
     _CCA_LATENT.set(int(q), labels={"part": "q"})
     _CCA_LATENT.set(int(kv), labels={"part": "kv"})
+
+
+def record_mla_latent_channels(kv, rope):
+    """Record the latent widths of one traced multi-head latent attention
+    call."""
+    _MLA_LATENT.set(int(kv), labels={"part": "kv"})
+    _MLA_LATENT.set(int(rope), labels={"part": "rope"})
 
 
 def record_router_eda_gamma(gamma):

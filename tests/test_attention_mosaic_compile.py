@@ -1,7 +1,7 @@
 """The flash attention kernels, forward and backward, compiled by Mosaic for
 a DESCRIBED TPU v5e (no chip attached): what the interpreter cannot show,
 a block shape, a layout, a VMEM budget or a tile table SMEM cannot hold
-that the compiler refuses.  Nothing runs; the shapes are the four token
+that the compiler refuses.  Nothing runs; the shapes are the token
 cells' and the suite's awkward ones.
 All compiles live in this one file and the topology is described inside a
 fixture, so only the worker that is given the file loads the TPU's
@@ -36,10 +36,11 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-# (query heads, key/value heads, positions, head size, dtype, block_q,
-# block_k, causal: a bool or a Mask)
+# (query heads, key/value heads, positions, head size or (query/key, value
+# head size), dtype, block_q, block_k, causal: a bool or a Mask)
 @pytest.mark.parametrize("h,h_kv,s,d,dtype,block_q,block_k,causal", [
     (32, 2, 8192, 128, "float32", 512, 512, True),     # Nemotron's layer
+    (32, 32, 8192, (192, 128), "float32", 512, 512, True),  # Kimi's MLA
     (8, 1, 8192, 128, "float32", 512, 512, True),      # Solar's
     (8, 2, 8192, 128, "float32", 512, 512, True),      # ZAYA1's CCA layer
     (32, 8, 4096, 64, "float32", 512, 512, True),      # granite's
@@ -69,8 +70,10 @@ def one_chip():
 def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
                                                 dtype, block_q, block_k,
                                                 causal):
-    def spec(heads):
-        return jax.ShapeDtypeStruct((1, heads, s, d), jnp.dtype(dtype),
+    d_qk, d_v = d if isinstance(d, tuple) else (d, d)
+
+    def spec(heads, size):
+        return jax.ShapeDtypeStruct((1, heads, s, size), jnp.dtype(dtype),
                                     sharding=one_chip)
 
     def out_and_grads(q, k, v, do):
@@ -79,7 +82,8 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
         return (out,) + vjp(do)
 
     text = jax.jit(out_and_grads).lower(
-        spec(h), spec(h_kv), spec(h_kv), spec(h)).compile().as_text()
+        spec(h, d_qk), spec(h_kv, d_qk), spec(h_kv, d_v),
+        spec(h, d_v)).compile().as_text()
     for kernel in ("mx_flash_attention_fwd", "mx_flash_attention_bwd_dq",
                    "mx_flash_attention_bwd_dkv"):
         assert kernel in text

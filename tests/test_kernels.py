@@ -472,3 +472,43 @@ def test_kernel_fallback_alert_in_default_pack():
     rule = rules["kernel_fallback"]
     assert rule.family == "mxnet_kernel_fallback_total"
     assert rule.severity == "warn"
+
+
+# -- flash attention with a value head size of its own ------------------------
+@pytest.mark.parametrize("h,h_kv,s,mask", [
+    (2, 2, 200, "causal"),                          # a tail in the tiles
+    (2, 2, 272, ("block_diffusion", 4, 136)),
+    (4, 2, 200, "causal"),                          # grouped queries
+], ids=["causal", "block_diffusion", "grouped"])
+def test_flash_takes_a_value_head_of_its_own(h, h_kv, s, mask):
+    """Keys of 192 over values of 128, as latent attention has them: the
+    three kernels in the interpreter against ``reference_attention``, the
+    output and the three gradients; dV and the output take v's size."""
+    from mxnet_tpu.ops.pallas_attention import (Mask, flash_attention,
+                                                reference_attention)
+    mask = Mask(*mask) if isinstance(mask, tuple) else Mask(mask)
+    rng = np.random.default_rng(44)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((1, n, s, d)), jnp.float32)
+                   for n, d in ((h, 192), (h_kv, 192), (h_kv, 128), (h, 128)))
+    scale = 192 ** -0.5
+    got, vjp = jax.vjp(lambda *a: flash_attention(*a, mask, scale, 128, 128),
+                       q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want, ref_vjp = jax.vjp(
+            lambda *a: reference_attention(*a, mask, scale), q, k, v)
+        grads = ref_vjp(do)
+    assert got.shape == (1, h, s, 128)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for name, a, b in zip("qkv", vjp(do), grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5, err_msg=name)
+
+
+def test_flash_refuses_a_value_that_is_not_the_keys():
+    from mxnet_tpu.ops.pallas_attention import flash_attention
+    q = np.zeros((1, 4, 8, 24), np.float32)
+    with pytest.raises(ValueError, match="head count"):
+        flash_attention(q, q[:, :2], np.zeros((1, 4, 8, 16), np.float32))
+    with pytest.raises(ValueError, match="head size"):
+        flash_attention(q, q[:, :2, :, :16], np.zeros((1, 2, 8, 16),
+                                                      np.float32))
