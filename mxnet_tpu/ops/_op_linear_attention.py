@@ -23,6 +23,11 @@ per-channel difference ``G_t − G_s`` is taken directly inside sub-blocks of
 ``SUB`` steps, and between sub-blocks it is split at the later block's
 first step ``r`` into ``(G_t − G_r) + (G_r − G_s)``, both ≤ 0.  Gradients
 are ``jax.vjp`` through this form (registry default).
+
+The op ``_contrib_kda_scan`` runs the same mathematics as two Pallas
+kernels that walk the chunks on a grid axis (``pallas_kda``) wherever their
+shape rule admits the heads and the chunk (``pallas_kda.kernel_takes``), and
+this form elsewhere; ``kda_scan`` here is the plain reference.
 """
 from __future__ import annotations
 
@@ -30,8 +35,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.scipy.linalg import solve_triangular
 
+from . import pallas_kda
 from .registry import register
 from ..base import MXNetError
+from ..telemetry import record_kda_scan_lowered
 
 # steps whose per-channel decays are differenced directly, (SUB, SUB, d_k)
 # a sub-block; a chunk is a whole number of them
@@ -139,4 +146,9 @@ def kda_scan(q, k, v, g, beta, chunk_size):
 @register("_contrib_kda_scan", alias=("kda_scan",),
           input_names=("q", "k", "v", "g", "beta"))
 def _kda_scan(attrs, q, k, v, g, beta):
-    return kda_scan(q, k, v, g, beta, int(attrs.get("chunk_size", 64)))
+    chunk = int(attrs.get("chunk_size", 64))
+    if pallas_kda.kernel_takes(q.shape, v.shape, chunk, SUB):
+        record_kda_scan_lowered("pallas")
+        return pallas_kda.kda_scan(q, k, v, g, beta, chunk, SUB)
+    record_kda_scan_lowered("xla")
+    return kda_scan(q, k, v, g, beta, chunk)

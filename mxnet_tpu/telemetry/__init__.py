@@ -167,6 +167,14 @@ _FLASH_BWD_LOWERED = REGISTRY.counter(
     "traced, by the implementation it put in the program: impl=pallas "
     "(the mx_flash_attention_bwd_* kernels) or impl=xla; one per attention "
     "layer a traced train step, none when a cached program runs")
+_KDA_SCAN_LOWERED = REGISTRY.counter(
+    "mxnet_kda_scan_lowered_total",
+    "calls of the op _contrib_kda_scan traced into a program, by the "
+    "implementation it put there: impl=pallas (the kernels mx_kda_fwd and "
+    "mx_kda_bwd, ops.pallas_kda) or impl=xla (the chunked form of "
+    "ops._op_linear_attention.kda_scan); one per KDA layer each time a "
+    "program that holds the layer is traced, none when a cached program "
+    "runs")
 _FLASH_TILES = REGISTRY.gauge(
     "mxnet_flash_attention_tiles",
     "(query tile, key tile) pairs a head of the last traced "
@@ -337,6 +345,12 @@ def record_flash_attention_bwd_lowered(impl):
     """Account one trace of flash attention's backward rule; ``impl`` is
     ``pallas`` or ``xla``."""
     _FLASH_BWD_LOWERED.inc(1, labels={"impl": impl})
+
+
+def record_kda_scan_lowered(impl):
+    """Account one traced call of ``_contrib_kda_scan``; ``impl`` is
+    ``pallas`` or ``xla``."""
+    _KDA_SCAN_LOWERED.inc(1, labels={"impl": impl})
 
 
 def record_flash_attention_tiles(mask, counts):

@@ -7,6 +7,7 @@ All compiles live in this one file and the topology is described inside a
 fixture, so only the worker that is given the file loads the TPU's
 library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -87,3 +88,58 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, h, h_kv, s, d,
     for kernel in ("mx_flash_attention_fwd", "mx_flash_attention_bwd_dq",
                    "mx_flash_attention_bwd_dkv"):
         assert kernel in text
+
+
+# -- the KDA kernels (ops.pallas_kda) ------------------------------------------------
+def _kda_bodies(one_chip, monkeypatch, heads, t, compile_too):
+    """The Mosaic module of each KDA kernel (forward and backward of one
+    call of chunk 64) lowered for the described chip at ``heads`` heads of
+    128 and ``t`` positions, its numbers and value names masked: the grid's
+    extent and the shapes are written into it, the operations are what
+    must not grow."""
+    from jax._src.pallas.mosaic import lowering
+    from mxnet_tpu.ops import pallas_kda
+
+    bodies = {}
+    lower = lowering.lower_jaxpr_to_module
+
+    def spy(ctx, grid_mapping, jaxpr, **kw):
+        module = lower(ctx, grid_mapping, jaxpr, **kw)
+        # value names carry constants' values too (%c8_i32_3)
+        text = re.sub(r"%[\w.#]+", "%v", str(module))
+        bodies[jaxpr.debug_info.func_name] = re.sub(r"\d+", "N", text)
+        return module
+
+    monkeypatch.setattr(lowering, "lower_jaxpr_to_module", spy)
+    jax.clear_caches()
+
+    def out_and_grads(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(lambda *a: pallas_kda.kda_scan(*a, 64, 16),
+                           q, k, v, g, beta)
+        return (out,) + vjp(do)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    lowered = jax.jit(out_and_grads).lower(
+        *[spec(1, t, heads, 128)] * 4, spec(1, t, heads),
+        spec(1, t, heads, 128))
+    if compile_too:
+        text = lowered.compile().as_text()
+        assert "mx_kda_fwd" in text and "mx_kda_bwd" in text
+    monkeypatch.undo()
+    return bodies
+
+
+@pytest.mark.parametrize("heads,t", [
+    (8, 8192),      # Solar's KDA layer
+    (32, 8192),     # Kimi Linear's
+    (32, 1024),
+])
+def test_kda_kernels_compile_for_a_v5e_and_their_bodies_do_not_grow(
+        one_chip, monkeypatch, heads, t):
+    got = _kda_bodies(one_chip, monkeypatch, heads, t, True)
+    want = _kda_bodies(one_chip, monkeypatch, 8, 1024, False)
+    assert sorted(got) == sorted(want) == ["mx_kda_bwd", "mx_kda_fwd"]
+    for kernel in want:
+        assert got[kernel] == want[kernel], kernel
