@@ -29,7 +29,7 @@ from .. import random as _random
 from ..base import MXNetError, np_dtype
 from ..context import Context, cpu, current_context
 from ..ndarray import NDArray
-from ..ops.pallas_attention import FLASH_RESIDUALS, traced_calls
+from ..ops import pallas_attention, pallas_kda
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 from .utils import _indent
 
@@ -122,15 +122,30 @@ jax.tree_util.register_pytree_node(
 
 # thread-local: the layers a trace is to checkpoint one by one (remat_scope)
 _REMAT = threading.local()
-# what a boundary keeps of its layer besides the inputs: the flash kernel's
-# out and lse, by name (a layer with no flash call keeps nothing more)
-_KEEP_FLASH = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+# what a boundary keeps of its layer besides the inputs, by name: the flash
+# kernel's out and lse and the KDA kernel's o and entering states (a layer
+# with neither kernel keeps nothing more).  ``parallel.spmd.remat_wrap``'s
+# policy keeps the same names
+KERNEL_RESIDUALS = (pallas_attention.FLASH_RESIDUALS
+                    + pallas_kda.KDA_RESIDUALS)
+_KEEP_KERNEL_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
+    *KERNEL_RESIDUALS)
+
+
+def kernel_residuals_traced():
+    """The named ``KERNEL_RESIDUALS`` the kernel calls this thread has
+    traced so far emit: two a flash call, two a KDA call.  The difference
+    across a boundary is what its policy keeps."""
+    return (len(pallas_attention.FLASH_RESIDUALS)
+            * pallas_attention.traced_calls()
+            + len(pallas_kda.KDA_RESIDUALS) * pallas_kda.traced_calls())
 
 
 class _RematScope:
     """The layers to checkpoint while a forward is traced, how many of
     them were (``boundaries``) and how many named residuals those keep
-    (``saved_residuals``: ``FLASH_RESIDUALS`` of each flash call inside)."""
+    (``saved_residuals``: ``KERNEL_RESIDUALS`` of each kernel call
+    inside)."""
 
     def __init__(self, layers):
         self._layers = {id(b) for b in layers}
@@ -142,7 +157,7 @@ class _RematScope:
 
     def call(self, layer, args):
         """``layer(*args)`` under ``jax.checkpoint``: the layer's inputs
-        and parameters, and the ``out`` and ``lse`` of each flash kernel
+        and parameters, and the named outputs of each flash or KDA kernel
         call, are all that the backward keeps of it; everything else
         inside is computed again there.  The parameters go in as
         arguments, swapped into the layer for the call as ``_build_jit``
@@ -174,11 +189,11 @@ class _RematScope:
                 for d, a in zip(holders, saved):
                     d._data = a
 
-        calls = traced_calls()
-        outs = jax.checkpoint(pure, policy=_KEEP_FLASH)(
+        named = kernel_residuals_traced()
+        outs = jax.checkpoint(pure, policy=_KEEP_KERNEL_OUTPUTS)(
             tuple(d._data for d in holders), tuple(a._data for a in flat))
         self.boundaries += 1
-        self.saved_residuals += len(FLASH_RESIDUALS) * (traced_calls() - calls)
+        self.saved_residuals += kernel_residuals_traced() - named
         return _regroup([NDArray(o, ctx) for o in outs], out_fmt[0])[0]
 
 
@@ -186,8 +201,9 @@ class _RematScope:
 def remat_scope(layers):
     """While a forward is traced inside this scope, each call of one of
     ``layers`` is a rematerialisation boundary (``jax.checkpoint`` that
-    saves the flash kernel's ``out`` and ``lse`` by name and nothing else
-    inside, so the backward recomputes the layer but not that kernel).
+    saves the flash and KDA kernels' outputs by name, ``KERNEL_RESIDUALS``,
+    and nothing else inside, so the backward recomputes the layer but not
+    those kernels).
     Yields the scope; its ``boundaries`` counts the calls that were
     wrapped and ``saved_residuals`` the named residuals they keep.
     ``parallel.spmd.TrainStep(remat=True)`` opens it over the

@@ -41,15 +41,27 @@ passes with float32 sums, except the cumulative sums and the triangular
 inverse with the products through it, which take float32
 (``Precision.HIGHEST``, as XLA's triangular solve does).
 
+**Kept across a rematerialisation boundary**: the forward's ``o``,
+``(batch, T', heads, d_v)`` float32, and its entering states carry the
+names ``KDA_RESIDUALS``, which a boundary's policy keeps
+(``gluon.block.remat_scope``, ``parallel.spmd.remat_wrap``), so its
+backward recomputes q, k, v, g and β through the layer's projections,
+convolutions and gates but does not run ``mx_kda_fwd`` again.  They cost
+``4 · batch · T' · heads · d_v · (1 + d_k / Q)`` bytes a layer: 0.40 GB at
+8192 steps, 32 heads of 128 and chunks of 64 (0.13 GB of ``o``, 0.27 GB of
+states).
+
 Where the program is lowered for anything but a tpu the same kernels run
 under the Pallas interpreter (``_pallas_rows.per_platform``).
 """
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -60,6 +72,19 @@ _HI = jax.lax.Precision.HIGHEST
 _NN = ((1,), (0,))
 _NT = ((1,), (1,))
 _TN = ((0,), (0,))
+
+# the names of the forward rule's ``o`` and entering states: a
+# rematerialisation boundary keeps these two, so its backward reads them and
+# does not run the forward kernel again
+KDA_RESIDUALS = ("mx_kda_out", "mx_kda_entering")
+_TRACED = threading.local()
+
+
+def traced_calls():
+    """``kda_scan`` calls this thread has staged into a jaxpr so far: the
+    difference across a rematerialisation boundary is the calls inside it
+    (``pallas_attention.traced_calls`` counts the flash kernel's)."""
+    return getattr(_TRACED, "calls", 0)
 
 
 def kernel_takes(q_shape, v_shape, chunk, sub):
@@ -374,6 +399,11 @@ def _scan(q, k, v, g, beta, qn, sub):
 
 def _scan_fwd(q, k, v, g, beta, qn, sub):
     o, entering = _forward(q, k, v, g, beta, qn, sub)
+    # named for a boundary's policy (KDA_RESIDUALS), the identity outside
+    # one; the primal output is the saved ``o`` itself, so the output norm's
+    # pullback reads it too
+    o = checkpoint_name(o, KDA_RESIDUALS[0])
+    entering = checkpoint_name(entering, KDA_RESIDUALS[1])
     return o, (q, k, v, g, beta, entering)
 
 
@@ -403,6 +433,7 @@ def kda_scan(q, k, v, g, beta, chunk_size, sub):
     """``_op_linear_attention.kda_scan`` through the kernels: the same
     arguments, float32 inside, the tail padded with steps of β = 0, g = 0,
     the output in v's dtype.  ``kernel_takes`` must admit the shapes."""
+    _TRACED.calls = traced_calls() + 1
     bsz, t, h, _ = q.shape
     qn = int(chunk_size)
     pad = -t % qn

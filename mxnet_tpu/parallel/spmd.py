@@ -20,7 +20,8 @@ from .. import random as _random
 from ..base import MXNetError
 from ..ndarray import NDArray
 from ..ops import registry as _registry
-from ..ops.pallas_attention import FLASH_RESIDUALS, traced_calls
+from ..gluon.block import (KERNEL_RESIDUALS, kernel_residuals_traced,
+                           remat_scope)
 from .mesh import DeviceMesh
 
 
@@ -251,14 +252,15 @@ def _matmul_conv_saveable(prim, *_args, **_params):
 
 _MIRROR_POLICY = jax.checkpoint_policies.save_from_both_policies(
     _matmul_conv_saveable,
-    jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS))
+    jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUALS))
 
 
 def remat_wrap(fwd):
     """Wrap a forward fn with rematerialization (parity:
     MXNET_BACKWARD_DO_MIRROR, src/nnvm/gradient.cc mirror fn): activation
-    memory shrinks to the matmul/conv outputs and the flash kernel's
-    ``out`` and ``lse`` (so its forward is not run again); elementwise
+    memory shrinks to the matmul/conv outputs and the flash and KDA
+    kernels' named outputs (``gluon.block.KERNEL_RESIDUALS``, so their
+    forward kernels are not run again); elementwise
     intermediates are recomputed during backward."""
     return jax.checkpoint(fwd, policy=_MIRROR_POLICY)
 
@@ -391,7 +393,6 @@ class TrainStep:
         aux_idx = list(self._aux_idx)
 
         from .. import telemetry as _telemetry
-        from ..gluon.block import remat_scope
         # the layers the block declares as rematerialisation boundaries;
         # a block that declares none has its whole forward wrapped
         remat_layers = tuple(getattr(block, "remat_layers", ())) \
@@ -402,13 +403,13 @@ class TrainStep:
             def step(key, train_params, aux_params, opt_state, x, y, *w):
                 def fwd(tps, x_):
                     ps = merge_params(train_idx, aux_idx, tps, aux_params)
-                    calls = traced_calls()
+                    named = kernel_residuals_traced()
                     with _ag.train_mode(), remat_scope(remat_layers) as sc:
                         outs, mutated = apply_fn(key, ps, (x_,))
                     # facts about the program: taken as it is traced
                     self.remat_boundaries = sc.boundaries or int(whole_remat)
-                    saved = sc.saved_residuals or whole_remat * len(
-                        FLASH_RESIDUALS) * (traced_calls() - calls)
+                    saved = sc.saved_residuals or whole_remat * (
+                        kernel_residuals_traced() - named)
                     _telemetry.record_remat_boundaries(
                         self.remat_boundaries, saved)
                     return outs[0], mutated

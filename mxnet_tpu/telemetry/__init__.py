@@ -158,9 +158,12 @@ _REMAT_BOUNDARIES = REGISTRY.gauge(
 _REMAT_SAVED = REGISTRY.gauge(
     "mxnet_step_remat_saved_residuals",
     "named residuals the rematerialisation boundaries of the last traced "
-    "parallel.spmd.TrainStep program keep: the flash kernel's out and lse "
-    "(ops.pallas_attention.FLASH_RESIDUALS) of each call inside one, whose "
-    "forward kernel the backward then does not run again; 0 without remat")
+    "parallel.spmd.TrainStep program keep (gluon.block.KERNEL_RESIDUALS): "
+    "the flash kernel's out and lse (ops.pallas_attention.FLASH_RESIDUALS) "
+    "and the KDA kernel's o and entering states "
+    "(ops.pallas_kda.KDA_RESIDUALS), two for each call of either kernel "
+    "inside one, whose forward kernel the backward then does not run "
+    "again; 0 without remat")
 _FLASH_BWD_LOWERED = REGISTRY.counter(
     "mxnet_flash_attention_bwd_lowered_total",
     "times the backward rule of ops.pallas_attention.flash_attention was "

@@ -157,8 +157,10 @@ def test_the_shape_rule():
 
 
 def test_the_kernels_under_a_rematerialisation_boundary():
-    """A layer's boundary recomputes the forward kernel in its backward:
-    the gradients are those of the call without one."""
+    """A boundary with no policy (a bare ``jax.checkpoint``) recomputes
+    the forward kernel in its backward: the gradients are those of the call
+    without one.  A layer's boundary keeps the kernel's outputs by name
+    instead (``tests/test_remat_residuals.py``)."""
     case = CASES[1]
     args, got, _ = _outputs_and_grads(case)
     weight = np.random.default_rng(1).standard_normal(
